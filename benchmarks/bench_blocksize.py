@@ -30,7 +30,8 @@ def measured(devices: int = 8, reps: int = 5):
         import jax, jax.numpy as jnp, numpy as np
         from jax.sharding import PartitionSpec as P
         from repro.core.dptree import dptree_allreduce
-        from repro.compat import shard_map, make_mesh
+        from repro.launch.mesh import make_mesh
+        shard_map = jax.shard_map
         mesh = make_mesh(({devices},), ("data",))
         p = {devices}
         X = jnp.asarray(np.random.default_rng(0).standard_normal((p, {M_ELEMS})),
@@ -49,8 +50,11 @@ def measured(devices: int = 8, reps: int = 5):
             out.append((b, min(ts) * 1e6))
         print("RESULT " + json.dumps(out))
     """)
+    # the child's virtual devices are CPU ones: it must never contend for an
+    # accelerator that this (or another) process holds
     r = subprocess.run([sys.executable, "-c", script], capture_output=True,
-                       text=True, timeout=560)
+                       text=True, timeout=560,
+                       env={**os.environ, "JAX_PLATFORMS": "cpu"})
     if r.returncode != 0:
         raise RuntimeError(r.stderr[-2000:])
     line = [l for l in r.stdout.splitlines() if l.startswith("RESULT ")][0]

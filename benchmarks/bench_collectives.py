@@ -45,7 +45,8 @@ def measured_rows(devices: int = 8, reps: int = 5):
         sys.path.insert(0, {root + '/src'!r})
         import jax, jax.numpy as jnp, numpy as np
         from jax.sharding import PartitionSpec as P
-        from repro.compat import shard_map, make_mesh
+        from repro.launch.mesh import make_mesh
+        shard_map = jax.shard_map
         from repro.core.collectives import CollectiveConfig, all_reduce
         mesh = make_mesh(({devices},), ("data",))
         p = {devices}
@@ -68,8 +69,11 @@ def measured_rows(devices: int = 8, reps: int = 5):
                 out.append((m, method, min(ts) * 1e6))
         print("RESULT " + json.dumps(out))
     """)
+    # the child's virtual devices are CPU ones: it must never contend for an
+    # accelerator that this (or another) process holds
     r = subprocess.run([sys.executable, "-c", script], capture_output=True,
-                       text=True, timeout=560)
+                       text=True, timeout=560,
+                       env={**os.environ, "JAX_PLATFORMS": "cpu"})
     if r.returncode != 0:
         raise RuntimeError(r.stderr[-2000:])
     line = [l for l in r.stdout.splitlines() if l.startswith("RESULT ")][0]

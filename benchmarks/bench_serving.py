@@ -533,6 +533,7 @@ def run_tp(csv_out):
     root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
     env = dict(os.environ)
     env["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
+    env["JAX_PLATFORMS"] = "cpu"    # virtual CPU devices, never the chip
     env["PYTHONPATH"] = os.path.join(root, "src") + os.pathsep + \
         env.get("PYTHONPATH", "")
     r = subprocess.run(

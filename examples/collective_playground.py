@@ -29,7 +29,9 @@ from repro.core.cost_model import TPU_V5E, optimal_blocks  # noqa: E402
 
 def main():
     p = 8
-    from repro.compat import make_mesh, shard_map
+    from jax import shard_map
+
+    from repro.launch.mesh import make_mesh
     mesh = make_mesh((p,), ("data",))
     rng = np.random.default_rng(0)
     for m in (10_000, 1_000_000):

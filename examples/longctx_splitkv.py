@@ -29,7 +29,9 @@ from repro.models import layers as L  # noqa: E402
 
 def main():
     p = 8
-    from repro.compat import make_mesh, shard_map
+    from jax import shard_map
+
+    from repro.launch.mesh import make_mesh
     mesh = make_mesh((p,), ("data",))
     cfg = L.AttnConfig(d_model=64, n_heads=4, n_kv_heads=2, head_dim=16)
     params = L.attn_init(jax.random.PRNGKey(0), cfg)

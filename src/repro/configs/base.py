@@ -81,8 +81,7 @@ class ParallelConfig:
     # columns across a 'tp' mesh axis; every decode tick then ends in a tiny
     # per-token allreduce — the paper's latency-bound regime. method='auto'
     # lets the autotuner/cost model pick dptree vs ring per message size
-    # (docs/tensor_parallel.md); psum fallback preserved in partial-manual
-    # regions per repro/compat.py.
+    # (docs/tensor_parallel.md).
     tp_shards: int = 1
     tp_collective: CollectiveConfig = CollectiveConfig(method="auto")
 

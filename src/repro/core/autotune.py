@@ -88,6 +88,10 @@ class TuneResult:
     # None keys the legacy axis-less entry, which any lookup may fall back
     # to — existing cache files stay valid.
     axis: str | None = None
+    # ``(algorithm, num_blocks, error)`` of every candidate the tuning run
+    # could not time (never persisted): a candidate the backend refuses is
+    # reported, not silently dropped from the race.
+    skipped: tuple = dataclasses.field(default=(), compare=False)
 
 
 def _key(p: int, nbytes: int, dtype: str, topology: str,
@@ -301,7 +305,8 @@ def tune(runner: Callable[[str, int], float], p: int, nbytes: int,
     recorded :class:`TuneResult` normalizes it into ``compressed=True``. The
     best measured setting is recorded in the cache (and persisted when
     ``save``). ``runner`` failures (e.g. an algorithm unavailable on this
-    backend) are skipped, not fatal — unless every candidate fails.
+    backend) are skipped, not fatal — unless every candidate fails. Each
+    skip is printed and listed in the result's ``skipped``.
     """
     # `is None`, not truthiness: an empty caller-supplied cache has len 0
     # and must still receive the result (not the process-wide cache).
@@ -319,7 +324,10 @@ def tune(runner: Callable[[str, int], float], p: int, nbytes: int,
         try:
             t = float(runner(algo, b))
         except Exception as e:  # candidate unavailable — keep tuning
-            errors.append((algo, b, e))
+            err = f"{type(e).__name__}: {e}"
+            print(f"autotune: skipped {algo} b={b} at p={p} "
+                  f"{nbytes}B: {err}")
+            errors.append((algo, b, err))
             continue
         if best is None or t < best.time_s:
             base = algo.removesuffix(COMPRESSED_SUFFIX)
@@ -329,6 +337,7 @@ def tune(runner: Callable[[str, int], float], p: int, nbytes: int,
                               axis=axis)
     if best is None:
         raise RuntimeError(f"autotune: every candidate failed: {errors}")
+    best = dataclasses.replace(best, skipped=tuple(errors))
     cache.put(p, nbytes, dtype, topology, best)
     if save:
         cache.save()

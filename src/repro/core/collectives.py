@@ -247,19 +247,6 @@ def all_reduce(x: jax.Array, axis_name: str, p: int,
     new_algo = _degrade_for_op(algo, op, config.method)
     if new_algo != algo:
         algo, nb_measured = new_algo, None
-    if algo != "psum":
-        from repro import compat
-        if compat.partial_manual_trace():
-            # Old-jax partial-manual shard_map: XLA aborts on ppermute, so
-            # the schedule-based algorithms cannot lower — the primitive
-            # reductions are the only sound path there (numerically
-            # identical for the commutative ops they cover).
-            if op not in _PRIMITIVE_REDUCE:
-                raise ValueError(
-                    "old-jax partial-manual region: only jnp.add/maximum/"
-                    "minimum reductions are supported (ppermute cannot "
-                    "lower here); got an unmapped op")
-            algo = "psum"
     nb = (nb_measured if config.num_blocks is None and nb_measured is not None
           else _nblocks(config.num_blocks, p, nbytes, config.comm_model,
                         algo, hier_spec,
@@ -468,9 +455,8 @@ def bucket_sizes(tree: Any, bucket_bytes: int = 1 << 30,
 
 
 def _mesh_axis_size(name: str) -> int | None:
-    from repro import compat
-    env = compat.get_abstract_mesh()
-    if env is None or env.empty:
+    env = jax.sharding.get_abstract_mesh()
+    if env.empty:
         return None
     shape = dict(env.shape_tuple)
     return shape.get(name)

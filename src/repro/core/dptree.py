@@ -52,7 +52,6 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro import compat
 from repro.core.topology import (NO_NODE, HierarchicalTopology, TreeTopology,
                                  build_dual_tree, build_hierarchy,
                                  build_single_tree)
@@ -113,6 +112,16 @@ def _blockify(x: jax.Array, b: int) -> tuple:
 def _const(arr: np.ndarray, i: jax.Array) -> jax.Array:
     """Per-device lookup into a host-built topology constant."""
     return jnp.asarray(arr)[i]
+
+
+def _varying(x: jax.Array, axis_name: str) -> jax.Array:
+    """Mark a scan's initial carry as varying over ``axis_name``: the body's
+    ppermutes make the carry it returns device-varying, and ``lax.scan``
+    requires the carry to enter with the type it leaves with (a replicated
+    payload or an identity fill otherwise fails to trace)."""
+    if axis_name in jax.typeof(x).vma:
+        return x
+    return jax.lax.pcast(x, (axis_name,), to="varying")
 
 
 def _pin_lanes(x: jax.Array, spec=None) -> jax.Array:
@@ -191,7 +200,8 @@ def _ring_reduce_scatter(H, axis_name, idx, size, perm, sg, op,
 
     H, _ = jax.lax.scan(
         lambda hh, t: (_pin_lanes(rs_step(hh, t), carry_spec), ()),
-        _pin_lanes(H, carry_spec), jnp.arange(size - 1, dtype=jnp.int32))
+        _varying(_pin_lanes(H, carry_spec), axis_name),
+        jnp.arange(size - 1, dtype=jnp.int32))
     return H
 
 
@@ -207,7 +217,8 @@ def _ring_all_gather(H, axis_name, idx, size, perm, sg, carry_spec=None):
 
     H, _ = jax.lax.scan(
         lambda hh, t: (_pin_lanes(ag_step(hh, t), carry_spec), ()),
-        _pin_lanes(H, carry_spec), jnp.arange(size - 1, dtype=jnp.int32))
+        _varying(_pin_lanes(H, carry_spec), axis_name),
+        jnp.arange(size - 1, dtype=jnp.int32))
     return H
 
 
@@ -229,7 +240,7 @@ def _tree_allreduce(x: jax.Array, axis_name: str, topo: TreeTopology,
     # read-modify-write of the current value (two extra dynamic slices).
     Y = jnp.concatenate([Y, jnp.zeros((1,) + Y.shape[1:], Y.dtype)], axis=0)
 
-    i = compat.axis_index(axis_name)
+    i = jax.lax.axis_index(axis_name)
     phi = _const(topo.phi, i)
     dep = _const(topo.depth, i)
     has_c0 = _const(topo.child0 != NO_NODE, i)
@@ -341,7 +352,8 @@ def _tree_allreduce(x: jax.Array, axis_name: str, topo: TreeTopology,
             return (_pin_lanes(Y, carry_spec), _pin_lanes(pend, pend_spec)), ()
 
         (Y, _), _ = jax.lax.scan(
-            macro_round, (_pin_lanes(Y, carry_spec), ident),
+            macro_round, (_varying(_pin_lanes(Y, carry_spec), axis_name),
+                          _varying(ident, axis_name)),
             jnp.arange(R, dtype=jnp.int32))
     else:
         def macro_round(Y, r):
@@ -350,7 +362,8 @@ def _tree_allreduce(x: jax.Array, axis_name: str, topo: TreeTopology,
                 Y = step_general(Y, s0 + e, e)
             return _pin_lanes(Y, carry_spec), ()
 
-        Y, _ = jax.lax.scan(macro_round, _pin_lanes(Y, carry_spec),
+        Y, _ = jax.lax.scan(macro_round,
+                            _varying(_pin_lanes(Y, carry_spec), axis_name),
                             jnp.arange(R, dtype=jnp.int32))
     Y = Y[:b]  # drop the scratch row
     return Y.reshape((b * Y.shape[1],) + Y.shape[2:])[:m]
@@ -465,7 +478,7 @@ def hier_allreduce(x: jax.Array, axis_name: str, p: int, *,
         nb = max(1, min(int(num_blocks), x.shape[0]))
         return _tree_allreduce(x, axis_name, h.inter_topo, nb, op, None,
                                carry_spec)
-    i = compat.axis_index(axis_name)
+    i = jax.lax.axis_index(axis_name)
 
     # ---- stage down: per-level bidirectional ring reduce-scatter ---------
     # After level j each rank owns a fully-reduced (within its level-(<=j)
@@ -551,7 +564,7 @@ def redbcast_allreduce(x: jax.Array, axis_name: str, p: int, *,
     # scratch row for masked writes (same trick as the tree engine)
     Y = jnp.concatenate([Y, jnp.zeros((1,) + Y.shape[1:], Y.dtype)], axis=0)
 
-    i = compat.axis_index(axis_name)
+    i = jax.lax.axis_index(axis_name)
     dmax = topo.max_depth
 
     # ---------------- reduce phase (period 2, up-traffic only) -----------
@@ -596,7 +609,7 @@ def redbcast_allreduce(x: jax.Array, axis_name: str, p: int, *,
                 Y = rstep(Y, 2 * r + e, e)
         return _pin_lanes(Y), ()
 
-    Y, _ = jax.lax.scan(rround, _pin_lanes(Y),
+    Y, _ = jax.lax.scan(rround, _varying(_pin_lanes(Y), axis_name),
                         jnp.arange(R1, dtype=jnp.int32))
 
     # ---------------- broadcast phase (period 2, down-traffic only) ------
@@ -639,7 +652,7 @@ def redbcast_allreduce(x: jax.Array, axis_name: str, p: int, *,
                 Y = bstep(Y, 2 * r + e, e)
         return _pin_lanes(Y), ()
 
-    Y, _ = jax.lax.scan(bround, _pin_lanes(Y),
+    Y, _ = jax.lax.scan(bround, _varying(_pin_lanes(Y), axis_name),
                         jnp.arange(R2, dtype=jnp.int32))
     Y = Y[:b]
     return Y.reshape((b * Y.shape[1],) + Y.shape[2:])[:m]
@@ -659,7 +672,7 @@ def ring_allreduce(x: jax.Array, axis_name: str, p: int, *,
     if p == 1:
         return x
     halves, chunk, m, trail = _ring_layout(x, p, bidirectional)
-    i = compat.axis_index(axis_name)
+    i = jax.lax.axis_index(axis_name)
     fwd = tuple((k, (k + 1) % p) for k in range(p))
     bwd = tuple(((k + 1) % p, k) for k in range(p))
 

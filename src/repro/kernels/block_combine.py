@@ -69,9 +69,16 @@ def _run(kernel, args, rows: int, interpret: bool, op: str):
         mat, n_tiles = _pad_2d(a, rows)
         mats.append(mat)
     spec = pl.BlockSpec((rows, LANES), lambda i: (i, 0))
+    # inside a shard_map the operands and the output must all vary over
+    # every manual axis any operand varies over
+    vma = frozenset().union(*(jax.typeof(m).vma for m in mats))
+    mats = [m if jax.typeof(m).vma == vma else
+            jax.lax.pcast(m, tuple(vma - jax.typeof(m).vma), to="varying")
+            for m in mats]
     out = pl.pallas_call(
         functools.partial(kernel, op=op),
-        out_shape=jax.ShapeDtypeStruct(mats[0].shape, mats[0].dtype),
+        out_shape=jax.ShapeDtypeStruct(mats[0].shape, mats[0].dtype,
+                                       vma=vma),
         grid=(n_tiles,),
         in_specs=[spec] * len(mats),
         out_specs=spec,

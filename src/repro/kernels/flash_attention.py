@@ -6,7 +6,7 @@ GSPMD for the dry-run), while this kernel is the single-chip hot-loop form:
 one (bq x dh) query tile resident in VMEM, streaming (bk x dh) key/value
 tiles, carrying the running (max, denom, accumulator) in registers/VMEM
 scratch. Grid = (batch*heads, num_q_blocks); the kv loop is a fori_loop with
-``pl.dslice`` loads so the K/V stream never exceeds one tile of VMEM beyond
+``pl.ds`` ref slices so the K/V stream never exceeds one tile of VMEM beyond
 the block inputs.
 
 Masking supports causal, sliding-window and chunked (local) attention — the
@@ -39,12 +39,8 @@ def _kernel(q_ref, k_ref, v_ref, o_ref, *, bk: int, scale: float,
 
     def body(ki, carry):
         m, l, acc = carry
-        # slice(0, 1) + [0], not a bare int index: interpret mode's NDIndexer
-        # rejects raw python ints in mixed-index pl.load tuples.
-        k = pl.load(k_ref, (slice(0, 1), pl.dslice(ki * bk, bk),
-                            slice(None)))[0].astype(jnp.float32)
-        v = pl.load(v_ref, (slice(0, 1), pl.dslice(ki * bk, bk),
-                            slice(None)))[0].astype(jnp.float32)
+        k = k_ref[0, pl.ds(ki * bk, bk), :].astype(jnp.float32)
+        v = v_ref[0, pl.ds(ki * bk, bk), :].astype(jnp.float32)
         s = q @ k.T                                    # (bq, bk)
         qpos = qi * bq + jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 0)
         kpos = ki * bk + jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 1)

@@ -60,10 +60,12 @@ def quantize_int8(x: jax.Array, *, rows: int = DEFAULT_ROWS,
     x, n_tiles = _pad_rows(x, rows)
     spec = pl.BlockSpec((rows, LANES), lambda i: (i, 0))
     sspec = pl.BlockSpec((rows, 1), lambda i: (i, 0))
+    vma = jax.typeof(x).vma
     q, s = pl.pallas_call(
         _quant_kernel,
-        out_shape=(jax.ShapeDtypeStruct(x.shape, jnp.int8),
-                   jax.ShapeDtypeStruct((x.shape[0], 1), jnp.float32)),
+        out_shape=(jax.ShapeDtypeStruct(x.shape, jnp.int8, vma=vma),
+                   jax.ShapeDtypeStruct((x.shape[0], 1), jnp.float32,
+                                        vma=vma)),
         grid=(n_tiles,),
         in_specs=[spec],
         out_specs=(spec, sspec),
@@ -89,7 +91,8 @@ def _cast_1d(x: jax.Array, dtype, rows: int, interpret: bool) -> jax.Array:
     spec = pl.BlockSpec((rows, LANES), lambda i: (i, 0))
     out = pl.pallas_call(
         _cast_kernel,
-        out_shape=jax.ShapeDtypeStruct(mat.shape, dtype),
+        out_shape=jax.ShapeDtypeStruct(mat.shape, dtype,
+                                       vma=jax.typeof(mat).vma),
         grid=(n_tiles,),
         in_specs=[spec],
         out_specs=spec,
@@ -129,7 +132,9 @@ def dequantize_int8(q: jax.Array, scale: jax.Array, dtype=jnp.float32, *,
     sspec = pl.BlockSpec((rows, 1), lambda i: (i, 0))
     out = pl.pallas_call(
         functools.partial(_dequant_kernel, dtype_name=jnp.dtype(dtype).name),
-        out_shape=jax.ShapeDtypeStruct(q.shape, dtype),
+        out_shape=jax.ShapeDtypeStruct(
+            q.shape, dtype,
+            vma=jax.typeof(q).vma | jax.typeof(scale).vma),
         grid=(n_tiles,),
         in_specs=[spec, sspec],
         out_specs=spec,

@@ -19,6 +19,14 @@ def combine3_ref(a: jax.Array, b: jax.Array, c: jax.Array, *,
     return f(f(a, b), c)
 
 
+def compress_bf16_ref(x: jax.Array) -> jax.Array:
+    return x.astype(jnp.bfloat16)
+
+
+def decompress_bf16_ref(x: jax.Array, dtype=jnp.float32) -> jax.Array:
+    return x.astype(dtype)
+
+
 def quantize_int8_ref(x: jax.Array):
     x32 = x.astype(jnp.float32)
     absmax = jnp.max(jnp.abs(x32), axis=1, keepdims=True)

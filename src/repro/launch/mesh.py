@@ -24,26 +24,25 @@ def make_production_mesh(*, multi_pod: bool = False):
     if len(devs) < need:
         raise RuntimeError(f"mesh {shape} needs {need} devices, "
                            f"have {len(devs)} (set XLA_FLAGS host device count)")
-    from repro import compat
-    return compat.make_mesh(shape, axes, devices=devs[:need])
+    return make_mesh(shape, axes, devices=devs[:need])
 
 
-def make_mesh(shape, axes):
-    """Arbitrary mesh (tests / local runs), Auto axis types."""
-    from repro import compat
-    return compat.make_mesh(shape, axes)
+def make_mesh(shape, axes, devices=None):
+    """Arbitrary mesh (tests / local runs) with Auto axis types: GSPMD owns
+    every axis a ``shard_map`` does not make manual."""
+    return jax.make_mesh(tuple(shape), tuple(axes),
+                         axis_types=(jax.sharding.AxisType.Auto,) * len(axes),
+                         devices=devices)
 
 
 def make_tp_mesh(tp: int):
     """Tensor-parallel serving mesh: ``tp`` devices on a single ``'tp'`` axis.
 
     Deliberately one-axis: the TP step builders wrap the model body in a
-    shard_map manual over EVERY mesh axis, and on the container's old jax a
-    fully-manual region is the one place ``ppermute`` (hence the dptree /
-    sptree / ring schedule collectives) still lowers — any auto axis in the
-    mesh would force ``collectives.all_reduce`` down its psum fallback (see
-    ``repro/compat.py``). Replica parallelism composes at the process level
-    (``serving.fleet``), not as a second axis here.
+    shard_map manual over EVERY mesh axis, so the per-token partial sums
+    run the schedule collectives (dptree / sptree / ring) on the 'tp' axis
+    with no GSPMD axis left over. Replica parallelism composes at the
+    process level (``serving.fleet``), not as a second axis here.
     """
     if tp < 1:
         raise ValueError(f"tp must be >= 1, got {tp}")
@@ -51,8 +50,7 @@ def make_tp_mesh(tp: int):
     if len(devs) < tp:
         raise RuntimeError(f"tp={tp} needs {tp} devices, have {len(devs)} "
                            "(set XLA_FLAGS=--xla_force_host_platform_device_count)")
-    from repro import compat
-    return compat.make_mesh((tp,), ("tp",), devices=devs[:tp])
+    return make_mesh((tp,), ("tp",), devices=devs[:tp])
 
 
 def make_local_mesh():

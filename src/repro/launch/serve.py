@@ -39,6 +39,13 @@ undisturbed single-engine run bit-for-bit:
   PYTHONPATH=src python -m repro.launch.serve --arch minicpm_2b --reduced \
       --chaos-seed 7 --replicas 3 --heartbeat-timeout 2 --heartbeat-misses 2
 
+Published width on one 16 GB TPU v5e chip (weights are held in the compute
+dtype, bf16):
+
+  PYTHONPATH=src python -m repro.launch.serve --arch minicpm_2b \
+      --continuous --requests 6 --slots 4 --prompt-len 16 128 \
+      --max-new 16 16 --cache-len 256
+
 Legacy fixed-batch demo (every row decodes in lockstep from an empty cache):
 
   PYTHONPATH=src python -m repro.launch.serve --arch granite_3_8b --reduced \
@@ -48,6 +55,7 @@ Legacy fixed-batch demo (every row decodes in lockstep from an empty cache):
 from __future__ import annotations
 
 import argparse
+import dataclasses
 import time
 
 import jax
@@ -56,6 +64,7 @@ import numpy as np
 
 from repro.configs.base import ShapeSuite, get_config, get_parallel
 from repro.launch import step_fns
+from repro.launch.compile_cache import enable_compile_cache
 from repro.launch.mesh import make_mesh
 from repro.models import transformer as tf
 
@@ -81,8 +90,6 @@ def synthetic_workload(n: int, vocab: int, *, gap: int = 2, seed: int = 0,
     prompt" to every request — the workload shape ``--prefix-cache``
     exists for (i.i.d. prompts share no prefix by construction).
     """
-    import dataclasses as _dc
-
     from repro.serving import Request
     rng = np.random.default_rng(seed)
     common = tuple(int(t) for t in rng.integers(1, vocab, shared_prefix)) \
@@ -103,7 +110,8 @@ def synthetic_workload(n: int, vocab: int, *, gap: int = 2, seed: int = 0,
                 max_new_tokens=int(rng.integers(max_new[0], max_new[1] + 1)),
                 arrival=i * gap,
                 sampling=(None if sampling is None else
-                          _dc.replace(sampling, seed=sampling.seed + i)),
+                          dataclasses.replace(sampling,
+                                              seed=sampling.seed + i)),
                 spec=spec,
                 slo=slo)
         for i in range(n)
@@ -168,6 +176,16 @@ def _obs_finish(args, tracer, probe=None):
             print(f"[trace] {n} events -> {jl} (jsonl)")
 
 
+def serving_config(arch: str, reduced: bool = False):
+    """The model config a serving run builds: weights held in the compute
+    dtype. Every matmul casts its weight to that dtype, so f32 weights buy
+    no precision, and XLA hoists those casts out of the layer loop: f32
+    weights plus their bf16 copies do not fit a published-width
+    ``minicpm_2b`` on one 16 GB v5e chip."""
+    cfg = get_config(arch, reduced=reduced)
+    return dataclasses.replace(cfg, param_dtype=cfg.compute_dtype)
+
+
 def serve_continuous(args):
     """Drive the continuous-batching engine on a synthetic workload."""
     from repro.serving import (DraftModelDrafter, PriorityClass,
@@ -176,7 +194,7 @@ def serve_continuous(args):
     mesh_shape = tuple(int(x) for x in args.mesh.split("x"))
     axes = ("data", "model")[-len(mesh_shape):]
     mesh = make_mesh(mesh_shape, axes)
-    cfg = get_config(args.arch, reduced=args.reduced)
+    cfg = serving_config(args.arch, args.reduced)
     pcfg = get_parallel(args.arch)
     params = tf.init_params(jax.random.PRNGKey(args.seed), cfg)
     drafter = None
@@ -218,6 +236,7 @@ def serve_continuous(args):
     reqs = synthetic_workload(args.requests, cfg.vocab_size,
                               gap=args.arrival_gap, seed=args.seed + 1,
                               prompt_lens=tuple(args.prompt_len),
+                              max_new=tuple(args.max_new),
                               sampling=sampling, spec=spec,
                               repetitive=spec is not None
                               and not args.draft_model,
@@ -269,7 +288,7 @@ def serve_chaos(args):
     mesh_shape = tuple(int(x) for x in args.mesh.split("x"))
     axes = ("data", "model")[-len(mesh_shape):]
     mesh = make_mesh(mesh_shape, axes)
-    cfg = get_config(args.arch, reduced=args.reduced)
+    cfg = serving_config(args.arch, args.reduced)
     pcfg = get_parallel(args.arch)
     params = tf.init_params(jax.random.PRNGKey(args.seed), cfg)
     engine = ServingEngine(cfg, pcfg, mesh, params, n_slots=args.slots,
@@ -286,6 +305,7 @@ def serve_chaos(args):
         return synthetic_workload(args.requests, cfg.vocab_size,
                                   gap=args.arrival_gap, seed=args.seed + 1,
                                   prompt_lens=tuple(args.prompt_len),
+                                  max_new=tuple(args.max_new),
                                   sampling=sampling)
 
     base = engine.run(workload())
@@ -335,7 +355,7 @@ def serve_loop(args):
     mesh_shape = tuple(int(x) for x in args.mesh.split("x"))
     axes = ("data", "model")[-len(mesh_shape):]
     mesh = make_mesh(mesh_shape, axes)
-    cfg = get_config(args.arch, reduced=args.reduced)
+    cfg = serving_config(args.arch, args.reduced)
     pcfg = get_parallel(args.arch)
     suite = ShapeSuite("serve", args.cache_len, args.batch, "decode")
     step, sh = step_fns.make_serve_step(cfg, pcfg, mesh, suite)
@@ -368,7 +388,8 @@ def serve_loop(args):
     dt = time.time() - t0
     toks = args.batch * args.steps
     print(f"decoded {toks} tokens in {dt:.2f}s "
-          f"({toks/dt:.1f} tok/s on {mesh_shape} CPU mesh)")
+          f"({toks/dt:.1f} tok/s on {mesh_shape} "
+          f"{jax.devices()[0].platform} mesh)")
     out = np.stack(tokens_out, 1)
     # argmax over (B, V) logits must yield in-vocabulary token ids
     # (np.isfinite on an int array is vacuously true)
@@ -403,6 +424,10 @@ def main(argv=None):
                     help="continuous mode: synthetic prompt length range "
                          "(prompts longer than --prefill-chunk stream in "
                          "chunk per tick)")
+    ap.add_argument("--max-new", type=int, nargs=2, default=(4, 24),
+                    metavar=("MIN", "MAX"),
+                    help="continuous mode: synthetic range of tokens each "
+                         "request generates")
     ap.add_argument("--prefill-chunk", type=int, default=None,
                     help="continuous mode: max prompt tokens per prefill "
                          "call (default: the largest single call the cache "
@@ -532,6 +557,9 @@ def _validate_args(ap, args) -> None:
     lo, hi = args.prompt_len
     if lo < 1 or hi < lo:
         ap.error(f"--prompt-len needs 1 <= MIN <= MAX, got {lo} {hi}")
+    lo, hi = args.max_new
+    if lo < 1 or hi < lo:
+        ap.error(f"--max-new needs 1 <= MIN <= MAX, got {lo} {hi}")
     if not 1 <= args.draft_k <= MAX_DRAFT_K:
         ap.error(f"--draft-k must be in [1, {MAX_DRAFT_K}], "
                  f"got {args.draft_k}")
@@ -589,4 +617,7 @@ def _validate_args(ap, args) -> None:
 
 
 if __name__ == "__main__":
+    # the command line, not main(): tests drive main() in-process and keep
+    # the compile cache off
+    enable_compile_cache()
     main()

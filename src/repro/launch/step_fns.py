@@ -29,11 +29,10 @@ from typing import Any
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax import shard_map
 from jax.sharding import NamedSharding
 from jax.sharding import PartitionSpec as P
 
-from repro import compat
-from repro.compat import shard_map
 from repro.configs.base import ParallelConfig, ShapeSuite
 from repro.core.collectives import (CollectiveConfig, all_reduce,
                                     bucketed_all_reduce)
@@ -130,9 +129,7 @@ def _tp_setup(cfg, pcfg: ParallelConfig, mesh) -> _TPSetup | None:
     tp_param_specs` / ``tp_cache_specs`` and runs the unchanged model code
     under the per-rank :func:`~repro.models.transformer.tp_shard_config`,
     with the per-token partial-sum allreduce supplied by ``L.tp_ctx``. The
-    mesh must be fully covered (use :func:`repro.launch.mesh.make_tp_mesh`)
-    — a leftover auto axis would push ``collectives.all_reduce`` down the
-    old-jax psum fallback instead of the paper's tree (repro/compat.py).
+    mesh must be fully covered (use :func:`repro.launch.mesh.make_tp_mesh`).
     """
     tp = int(getattr(pcfg, "tp_shards", 1) or 1)
     if tp <= 1:
@@ -198,14 +195,6 @@ def make_train_step(cfg, pcfg: ParallelConfig, mesh,
     dp = _dp_axes(mesh)
     manual = dp if pcfg.dp_mode == "manual" else tuple(
         a for a in dp if a == "pod" and pcfg.pod_sync == "dptree")
-    if manual and not compat.HAS_AXIS_TYPE \
-            and set(mesh.axis_names) - set(manual):
-        # Old-jax XLA cannot compile a *partial*-manual shard_map over the
-        # full model body (ppermute / sort / top_k all hit manual-subgroup
-        # CHECK failures in the SPMD partitioner). Fall back to the pure
-        # GSPMD-auto regime: GSPMD emits the gradient reduction itself.
-        manual = ()
-        pcfg = dataclasses.replace(pcfg, dp_mode="fsdp")
     sizes = {a: mesh.shape[a] for a in mesh.axis_names}
     ptot = int(np.prod([sizes[a] for a in manual])) if manual else 1
     pspecs = (model_pspecs(cfg, mesh) if pcfg.dp_mode == "manual"

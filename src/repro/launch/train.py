@@ -32,6 +32,7 @@ from repro.checkpoint.checkpointing import (CheckpointManager, latest_step,
 from repro.configs.base import get_config, get_parallel
 from repro.data.pipeline import DataConfig, SyntheticLM
 from repro.launch import step_fns
+from repro.launch.compile_cache import enable_compile_cache
 from repro.launch.mesh import make_mesh
 from repro.models import transformer as tf
 from repro.optim.optimizers import adamw, cosine_schedule, wsd_schedule
@@ -71,7 +72,6 @@ def autotune_warmup(mesh, pcfg, params, leaf_specs=None, *, reps: int = 3,
 
     from jax.sharding import PartitionSpec as _P
 
-    from repro import compat
     from repro.core import autotune, collectives
     from repro.core.topology import resolve_levels
 
@@ -91,7 +91,7 @@ def autotune_warmup(mesh, pcfg, params, leaf_specs=None, *, reps: int = 3,
         sel = [0] * mesh.devices.ndim
         sel[pos] = slice(None)
         axis_devs = mesh.devices[tuple(sel)]
-        tune_mesh = compat.make_mesh((p,), (ax,), devices=axis_devs)
+        tune_mesh = make_mesh((p,), (ax,), devices=axis_devs)
         algorithms = autotune._ALGORITHMS
         if resolve_levels(p, cfg.hier_spec) is not None:
             algorithms = algorithms + ("hier",)
@@ -106,7 +106,7 @@ def autotune_warmup(mesh, pcfg, params, leaf_specs=None, *, reps: int = 3,
                 ccfg = dataclasses.replace(cfg, method=base,
                                            num_blocks=int(b),
                                            compress_inter_group=compress)
-                f = jax.jit(compat.shard_map(
+                f = jax.jit(jax.shard_map(
                     lambda x: collectives.all_reduce(x, _ax, _p, ccfg),
                     mesh=_mesh, in_specs=_P(), out_specs=_P(),
                     check_vma=False))
@@ -250,4 +250,7 @@ def main(argv=None):
 
 
 if __name__ == "__main__":
+    # the command line, not main(): tests drive main() in-process and keep
+    # the compile cache off
+    enable_compile_cache()
     main()

@@ -92,10 +92,9 @@ def tp_all_reduce(x: jax.Array) -> jax.Array:
     allreduce at the end of every sharded sublayer — a tiny (B*T*d_model)
     payload in the paper's latency-bound regime, routed through
     :func:`repro.core.collectives.all_reduce` so ``method="auto"`` picks the
-    dual-root dptree (or a measured autotune winner) per message size. In
-    old-jax partial-manual regions ``all_reduce`` itself degrades to psum
-    (see ``repro.compat``); the payload is flattened because 1-D vectors
-    pipeline directly regardless of batch divisibility."""
+    dual-root dptree (or a measured autotune winner) per message size. The
+    payload is flattened because 1-D vectors pipeline directly regardless
+    of batch divisibility."""
     info = tp_info()
     if info is None or info.size <= 1:
         return x
@@ -115,20 +114,15 @@ def maybe_shard(x: jax.Array, spec: P | None) -> jax.Array:
     why batch dims must be named here rather than left None."""
     if spec is None:
         return x
-    from repro import compat
-    if not compat.HAS_AXIS_TYPE and compat.in_manual_trace():
-        # Old-jax partial-manual shard_map: XLA cannot express a NamedSharding
-        # constraint inside the manual subgroup (hard CHECK failure). Layout
-        # pinning is a memory/perf hint, so dropping it is safe here.
-        return x
-    env = compat.get_abstract_mesh()
+    env = jax.sharding.get_abstract_mesh()
     concrete = None
-    if env is None or env.empty or not env.shape_tuple:
+    if env.empty or not env.shape_tuple:
         if not _MESH_STACK:
             return x
         concrete = _MESH_STACK[-1]
         env = concrete.abstract_mesh
-    auto = compat.auto_axes(env)
+    auto = {a for a, t in zip(env.axis_names, env.axis_types)
+            if t == jax.sharding.AxisType.Auto}
 
     def fix(entry):
         if entry is None:

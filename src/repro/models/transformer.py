@@ -173,15 +173,18 @@ def _sub_init(key, cfg: ModelConfig, s: SubSpec) -> Params:
 
 
 def _stack_layer_params(key, cfg: ModelConfig, pattern, n_periods) -> list:
-    """Per pattern position: params stacked over periods (leading axis)."""
+    """Per pattern position: params stacked over periods (leading axis).
+
+    One vmapped init per sublayer writes the stacked leaves directly: a
+    per-period list stacked afterwards would hold every leaf twice, which
+    does not fit one chip at published widths."""
     out = []
     for pos, layer in enumerate(pattern):
         subs = []
         for si, s in enumerate(layer):
             keys = jax.random.split(
                 jax.random.fold_in(key, pos * 31 + si), n_periods)
-            ps = [_sub_init(k, cfg, s) for k in keys]
-            subs.append(jax.tree.map(lambda *xs: jnp.stack(xs), *ps))
+            subs.append(jax.vmap(lambda k, s=s: _sub_init(k, cfg, s))(keys))
         out.append(tuple(subs))
     return out
 

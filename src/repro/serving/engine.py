@@ -165,6 +165,12 @@ class ServingEngine:
         # the speculative in-call wrap hazard only exists where a ring is
         # narrower than the absolute-position capacity _check enforces
         self._bounded_ring = s_min < max_len
+        # exact resume re-prefills prompt+journal under a new chunk plan.
+        # Bounded rings are chunk-PLAN-deterministic only, and the
+        # capacity-dispatch MoE drops whichever tokens overflow an expert
+        # in a chunk: both restart from the prompt instead
+        self._exact_resume = not self._bounded_ring and not (
+            cfg.moe is not None and cfg.moe.impl == "dispatch")
         if draft_headroom is None:
             draft_headroom = SpecParams().draft_k
         self.draft_headroom = max(0, int(draft_headroom))
@@ -345,10 +351,11 @@ class EngineSession:
     invariant), and decode resumes feeding ``tokens[-1]`` at sampler
     cursor ``len(tokens)`` — the merged stream is bit-identical to an
     undisturbed run for greedy and sampled requests alike. On
-    window/chunk-bounded rings (SWA) a resume falls back to the lossy
-    restart-from-prompt: those rings guarantee chunk-PLAN determinism
-    only, and a resume necessarily runs a different plan; the restart
-    replays the ORIGINAL plan, so streams still come out identical.
+    window/chunk-bounded rings (SWA), and for capacity-dispatch MoE models
+    (which drop whichever tokens overflow an expert in a chunk), a resume
+    falls back to the lossy restart-from-prompt: their results depend on
+    the chunk PLAN, and a resume necessarily runs a different plan; the
+    restart replays the ORIGINAL plan, so streams still come out identical.
 
     Speculative requests are engine-global state (one drafter slot table);
     run several concurrent sessions only with ``spec=None`` requests.
@@ -525,10 +532,9 @@ class EngineSession:
         admissions = sched.admit(now, batch_sync=self.static)
         for slot, req in admissions:
             history = req.prompt
-            if req.tokens and eng._bounded_ring:
-                # SWA/chunk-bounded rings are chunk-PLAN-deterministic
-                # only: replay the original plan instead (lossy restart —
-                # same stream, more recompute)
+            if req.tokens and not eng._exact_resume:
+                # replay the original plan instead (lossy restart — same
+                # stream, more recompute)
                 req.tokens = []
                 req.t_first = None
             if req.tokens:

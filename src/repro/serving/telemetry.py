@@ -124,9 +124,7 @@ def make_stats_reducer(mesh, axis: str = "data",
     import jax
     from jax.sharding import PartitionSpec as P
 
-    from repro import compat
-
-    fn = jax.jit(compat.shard_map(
+    fn = jax.jit(jax.shard_map(
         lambda v: all_reduce(v.reshape(-1), axis, p, collective),
         mesh=mesh, in_specs=P(axis), out_specs=P(),
         axis_names={axis}, check_vma=False))

@@ -82,6 +82,28 @@ def test_runner_failures_are_skipped(tmp_cache):
     res = at.tune(runner, 8, 4096, "float32", "cpu8", cm.TPU_V5E)
     assert res.algorithm == "sptree"
 
+
+def test_skipped_candidates_are_reported(tmp_cache, capsys):
+    """A candidate the backend refuses is printed and listed in the result,
+    never silently dropped from the race."""
+    def runner(algo, b):
+        if algo == "dptree":
+            raise RuntimeError("kernel refused")
+        return 1.0 + b * 1e-3
+
+    cands = at.candidate_settings(8, 4096, cm.TPU_V5E)
+    res = at.tune(runner, 8, 4096, "float32", "cpu8", cm.TPU_V5E)
+    want = [(a, b) for a, b in cands if a == "dptree"]
+    assert want and [(a, b) for a, b, _ in res.skipped] == want
+    assert all("kernel refused" in err for _, _, err in res.skipped)
+    out = capsys.readouterr().out
+    assert all(f"skipped dptree b={b}" in out for _, b in want)
+    # the skip list is run-local: the persisted entry carries none of it
+    assert at.AutotuneCache(tmp_cache).load().get(
+        8, 4096, "float32", "cpu8").skipped == ()
+
+
+def test_all_candidates_failing_raises(tmp_cache):
     def all_fail(algo, b):
         raise RuntimeError("nope")
 

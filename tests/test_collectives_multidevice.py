@@ -26,9 +26,9 @@ def run_sub(body: str, devices: int = 8, timeout: int = 560):
         sys.path.insert(0, {ROOT + '/src'!r})
         import jax, jax.numpy as jnp, numpy as np
         from jax.sharding import PartitionSpec as P
-        from repro import compat
-        shard_map = compat.shard_map
-        mesh = compat.make_mesh(({devices},), ("data",))
+        from repro.launch.mesh import make_mesh
+        shard_map = jax.shard_map
+        mesh = make_mesh(({devices},), ("data",))
         p = {devices}
     """) + textwrap.dedent(body)
     r = subprocess.run([sys.executable, "-c", script], capture_output=True,

@@ -638,11 +638,11 @@ def test_stats_reducer_probe_samples_and_row_guard():
         import sys
         sys.path.insert(0, {ROOT + '/src'!r})
         import numpy as np
-        from repro import compat
+        from repro.launch.mesh import make_mesh
         from repro.obs import probing
         from repro.serving import STATS_FIELDS, make_stats_reducer
 
-        mesh = compat.make_mesh((8,), ("data",))
+        mesh = make_mesh((8,), ("data",))
         reduce = make_stats_reducer(mesh)
         k = len(STATS_FIELDS) + 4        # stats row + a histogram tail
         rows = np.arange(8 * k, dtype=np.float32).reshape(8, k)

@@ -512,8 +512,8 @@ def test_stats_reducer_multireplica_tree_and_autotune_consult(tmp_path):
         sys.path.insert(0, {root + '/src'!r})
         import jax
         import numpy as np
-        from repro import compat
         from repro.core import autotune as at
+        from repro.launch.mesh import make_mesh
         from repro.serving import (Request, ServingEngine, STATS_FIELDS,
                                    make_stats_reducer)
 
@@ -523,7 +523,7 @@ def test_stats_reducer_multireplica_tree_and_autotune_consult(tmp_path):
         at.get_cache().put(8, rows[0].nbytes, "float32", "tpu_v5e_ici",
                            at.TuneResult("sptree", 4, 1e-6))
         at.get_cache().save()
-        mesh = compat.make_mesh((8, 1), ("data", "model"))
+        mesh = make_mesh((8, 1), ("data", "model"))
         red = make_stats_reducer(mesh)
         got = red(rows)
         assert np.allclose(got, rows.sum(0)), (got, rows.sum(0))

@@ -94,9 +94,10 @@ def test_preempt_resume_streams_bit_identical(arch, mode):
         f"{arch}/{mode}: the interloper must actually preempt"
     assert slo["tokens"] == base["tokens"], \
         f"{arch}/{mode}: preempt+resume changed a stream"
-    if not eng._bounded_ring:
-        # full-capacity rings resume through the journal (bounded rings
-        # fall back to the lossy restart — same stream, zero replay count)
+    if eng._exact_resume:
+        # full-capacity rings resume through the journal (bounded rings and
+        # capacity-dispatch MoE fall back to the lossy restart — same
+        # stream, zero replay count)
         assert slo["resumed_tokens"] > 0, \
             f"{arch}/{mode}: resume must replay the journal"
 

@@ -1,7 +1,8 @@
 """Tensor-parallel decode correctness testbed (ISSUE 8).
 
 Fast lane (no marker): TP config validation, shard-config math, the
-PartitionSpec tables, the property-based projection invariants (satellite 1),
+PartitionSpec tables, the projection invariants (satellite 1; the
+column-parallel one on a virtual-device ``tp`` mesh in a subprocess),
 the autotune axis-scoped cache key (satellite 2), and the cost-model TP term.
 
 Slow lane (``slow`` marker, 8 virtual devices in a subprocess — the
@@ -161,23 +162,60 @@ def test_tp_row_parallel_projection_within_ulp_bound(heads, dh, d_model,
         (np.max(np.abs(sharded - ref) / bound), K, tp)
 
 
-@settings(max_examples=20, deadline=None)
-@given(heads=st.integers(1, 6), dh=st.integers(1, 16),
-       d_model=st.integers(1, 24), tp_log2=st.integers(1, 3),
-       seed=st.integers(0, 2**16))
-def test_tp_column_parallel_projection_bit_exact(heads, dh, d_model,
-                                                 tp_log2, seed):
-    """Column-parallel projections (wq/wk/wv/w_in shape) are BIT-exact under
-    sharding: each output column is the same unsharded-d_model dot product,
-    merely computed on one rank."""
-    tp = 2 ** tp_log2
-    N = heads * dh * tp                     # output width, tp-divisible
-    rng = np.random.default_rng(seed)
-    x = rng.standard_normal((3, d_model)).astype(np.float32)
-    w = rng.standard_normal((d_model, N)).astype(np.float32)
-    ref = x @ w
-    shards = [x @ w[:, i * N // tp:(i + 1) * N // tp] for i in range(tp)]
-    assert np.array_equal(np.concatenate(shards, axis=1), ref)
+def test_tp_column_parallel_projection_bit_exact():
+    """Column-parallel projections (wq/wk/wv/w_in/w_gate) are BIT-exact
+    under sharding: each output column is the same unsharded-d_model dot
+    product, merely computed on one rank. Checked on a ``tp`` mesh of
+    virtual devices, with every column-split weight that
+    ``tp_param_specs`` names, over random head/width/tp shapes."""
+    script = textwrap.dedent(f"""
+        import os
+        os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
+        import sys
+        sys.path.insert(0, {os.path.join(ROOT, "src")!r})
+        import numpy as np
+        import jax
+        from jax.sharding import PartitionSpec as P
+        from repro.launch.mesh import make_tp_mesh
+        from repro.models import transformer as tf
+
+        rng = np.random.default_rng(0)
+        shapes = [(1, 1, 1, 2), (6, 16, 24, 8), (1, 1, 24, 8)] + [
+            (int(rng.integers(1, 7)), int(rng.integers(1, 17)),
+             int(rng.integers(1, 25)), 2 ** int(rng.integers(1, 4)))
+            for _ in range(17)]
+        checked = set()
+        for i, (heads, dh, d_model, tp) in enumerate(shapes):
+            cfg = tf.ModelConfig(name="tp-col", n_layers=1, d_model=d_model,
+                                 n_heads=heads * tp, n_kv_heads=heads * tp,
+                                 head_dim=dh, d_ff=heads * dh * tp,
+                                 vocab_size=16, remat=False)
+            params = tf.init_params(jax.random.PRNGKey(i), cfg)
+            specs = tf.tp_param_specs(cfg)
+            mesh = make_tp_mesh(tp)
+            x = jax.random.normal(jax.random.PRNGKey(-i), (3, d_model),
+                                  cfg.compute_dtype)
+            proj = lambda x, w: x @ w.astype(x.dtype)    # as layers.py
+            for sub, sub_specs in zip(params["layers"][0],
+                                      specs["layers"][0]):
+                for name, spec in sub_specs.items():
+                    # drop the leading period axis
+                    spec = P(*spec[1:]) if isinstance(spec, P) else None
+                    if spec != P(None, "tp"):
+                        continue
+                    w = sub[name][0]
+                    want = jax.jit(proj)(x, w)
+                    got = jax.jit(jax.shard_map(
+                        proj, mesh=mesh, in_specs=(P(), spec),
+                        out_specs=spec))(x, w)
+                    assert np.array_equal(np.asarray(got, np.float32),
+                                          np.asarray(want, np.float32)), \
+                        (name, heads, dh, d_model, tp)
+                    checked.add(name)
+        assert checked == {{"wq", "wk", "wv", "w_in", "w_gate"}}, checked
+        print("COLUMNS OK")
+    """)
+    assert "COLUMNS OK" in _run_sub(script, timeout=300)
 
 
 @settings(max_examples=16, deadline=None)

@@ -1,0 +1,77 @@
+"""Ahead-of-time compiles for a described TPU v5e, with no chip attached.
+
+The TPU compiler refuses what interpret mode accepts (tile alignment,
+on-chip memory, a kernel output without the ``vma`` a ``check_vma``
+shard_map requires), so the allreduce's kernels are compiled here at real
+sizes for the chip. Nothing runs: these tests say nothing about results or
+times. The topology is described inside a fixture, never at import.
+"""
+
+import os
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+from jax.sharding import Mesh, NamedSharding, SingleDeviceSharding
+from jax.sharding import PartitionSpec as P
+
+from repro.core.dptree import dptree_allreduce
+from repro.kernels import block_combine, quantize
+
+# one pipeline block of a 64 MiB f32 gradient bucket cut into 16 blocks
+BLOCK = 1 << 20
+
+
+@pytest.fixture(scope="module")
+def topo():
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    from jax.experimental import topologies
+    try:
+        return topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
+
+
+def _compiled_text(fn, *args) -> str:
+    return jax.jit(fn).lower(*args).compile().as_text()
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+def test_combine3_compiles_for_v5e(one_chip, dtype):
+    x = jax.ShapeDtypeStruct((BLOCK,), dtype, sharding=one_chip)
+    text = _compiled_text(
+        lambda a, b, c: block_combine.combine3(a, b, c, op="add"), x, x, x)
+    assert "tpu_custom_call" in text
+
+
+def test_bf16_wire_kernels_compile_for_v5e(one_chip):
+    x = jax.ShapeDtypeStruct((BLOCK,), jnp.float32, sharding=one_chip)
+    wire = jax.ShapeDtypeStruct((BLOCK,), jnp.bfloat16, sharding=one_chip)
+    assert "tpu_custom_call" in _compiled_text(quantize.compress_bf16, x)
+    assert "tpu_custom_call" in _compiled_text(quantize.decompress_bf16, wire)
+
+
+def test_dptree_kernel_branch_compiles_in_checked_shard_map(topo,
+                                                           monkeypatch):
+    """The fused engine's TPU branch (Pallas combine3 inside the scan)
+    compiles inside a 4-chip shard_map that checks varying manual axes."""
+    p = 4
+    mesh = Mesh(np.array(topo.devices[:p]), ("data",),
+                axis_types=(jax.sharding.AxisType.Auto,))
+    # the engine picks its kernel from the backend; steer it to the chip's
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    fn = jax.shard_map(
+        lambda x: dptree_allreduce(x[0], "data", p, num_blocks=16)[None],
+        mesh=mesh, in_specs=P("data"), out_specs=P("data"), check_vma=True)
+    x = jax.ShapeDtypeStruct((p, 16 * BLOCK), jnp.float32,
+                             sharding=NamedSharding(mesh, P("data")))
+    text = _compiled_text(fn, x)
+    assert "tpu_custom_call" in text
+    assert "collective-permute" in text
