@@ -62,7 +62,7 @@ def _pad_2d(x: jax.Array, rows: int):
     return x.reshape(n_tiles * rows, LANES), n_tiles
 
 
-def _run(kernel, args, rows: int, interpret: bool, op: str):
+def _run(kernel, args, rows: int, interpret: bool, op: str, name: str):
     mats = []
     n_tiles = None
     for a in args:
@@ -83,6 +83,7 @@ def _run(kernel, args, rows: int, interpret: bool, op: str):
         in_specs=[spec] * len(mats),
         out_specs=spec,
         interpret=interpret,
+        name=name,            # the kernel's name in the device trace
     )(*mats)
     return out.reshape(-1)[: args[0].shape[0]]
 
@@ -91,11 +92,12 @@ def combine2(a: jax.Array, b: jax.Array, *, op: str = "add",
              rows: int = DEFAULT_ROWS, interpret: bool = False) -> jax.Array:
     """``op(a, b)`` elementwise over 1-D blocks via a VMEM-tiled Pallas kernel."""
     assert a.shape == b.shape and a.ndim == 1
-    return _run(_combine2_kernel, (a, b), rows, interpret, op)
+    return _run(_combine2_kernel, (a, b), rows, interpret, op, "combine2")
 
 
 def combine3(a: jax.Array, b: jax.Array, c: jax.Array, *, op: str = "add",
              rows: int = DEFAULT_ROWS, interpret: bool = False) -> jax.Array:
     """Fused ``op(op(a, b), c)`` — one HBM pass instead of two."""
     assert a.shape == b.shape == c.shape and a.ndim == 1
-    return _run(_combine3_kernel, (a, b, c), rows, interpret, op)
+    return _run(_combine3_kernel, (a, b, c), rows, interpret, op,
+                "combine3")

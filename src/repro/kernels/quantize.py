@@ -70,6 +70,7 @@ def quantize_int8(x: jax.Array, *, rows: int = DEFAULT_ROWS,
         in_specs=[spec],
         out_specs=(spec, sspec),
         interpret=interpret,
+        name="quantize_int8",     # the kernel's name in the device trace
     )(x)
     return q[:r0], s[:r0]
 
@@ -78,7 +79,8 @@ def _cast_kernel(x_ref, o_ref):
     o_ref[...] = x_ref[...].astype(o_ref.dtype)
 
 
-def _cast_1d(x: jax.Array, dtype, rows: int, interpret: bool) -> jax.Array:
+def _cast_1d(x: jax.Array, dtype, rows: int, interpret: bool,
+             name: str) -> jax.Array:
     """Tiled elementwise cast of a 1-D vector: pad to (ROWS x 128) tiles,
     stream one tile per grid step. One HBM read + one write, no gather."""
     (m,) = x.shape
@@ -97,6 +99,7 @@ def _cast_1d(x: jax.Array, dtype, rows: int, interpret: bool) -> jax.Array:
         in_specs=[spec],
         out_specs=spec,
         interpret=interpret,
+        name=name,                # the kernel's name in the device trace
     )(mat)
     return out.reshape(-1)[:m]
 
@@ -110,7 +113,7 @@ def compress_bf16(x: jax.Array, *, rows: int = DEFAULT_ROWS,
     the tiled single-pass HBM schedule on real TPUs.
     """
     assert x.ndim == 1
-    return _cast_1d(x, jnp.bfloat16, rows, interpret)
+    return _cast_1d(x, jnp.bfloat16, rows, interpret, "compress_bf16")
 
 
 def decompress_bf16(x: jax.Array, dtype=jnp.float32, *,
@@ -118,7 +121,7 @@ def decompress_bf16(x: jax.Array, dtype=jnp.float32, *,
                     interpret: bool = False) -> jax.Array:
     """bf16 -> f32 wire decompression; exact (bf16 embeds into f32)."""
     assert x.ndim == 1
-    return _cast_1d(x, dtype, rows, interpret)
+    return _cast_1d(x, dtype, rows, interpret, "decompress_bf16")
 
 
 def dequantize_int8(q: jax.Array, scale: jax.Array, dtype=jnp.float32, *,
@@ -139,5 +142,6 @@ def dequantize_int8(q: jax.Array, scale: jax.Array, dtype=jnp.float32, *,
         in_specs=[spec, sspec],
         out_specs=spec,
         interpret=interpret,
+        name="dequantize_int8",   # the kernel's name in the device trace
     )(q, scale)
     return out[:r0]

@@ -337,13 +337,15 @@ def make_prefill_step(cfg, pcfg: ParallelConfig, mesh, suite: ShapeSuite,
                     length.reshape(1), jnp.ones((1,), bool), resume=resume)
             return logits, tf.adopt_prefix(caches, row, slot)
 
-        def greedy_body(params, tokens, caches, slot, length, resume):
+        # the bodies' names are their programs' names on the device
+        # (``jit_prefill_step``, ``jit_prefill_step_sampled``)
+        def prefill_step(params, tokens, caches, slot, length, resume):
             logits, out = _prefill_fwd(params, tokens, caches, slot, length,
                                        resume)
             return jnp.argmax(logits).astype(jnp.int32), out
 
-        def sampled_body(params, tokens, caches, slot, length, sampling_row,
-                         resume):
+        def prefill_step_sampled(params, tokens, caches, slot, length,
+                                 sampling_row, resume):
             logits, out = _prefill_fwd(params, tokens, caches, slot, length,
                                        resume)
             tok = sample_tokens(
@@ -375,17 +377,24 @@ def make_prefill_step(cfg, pcfg: ParallelConfig, mesh, suite: ShapeSuite,
                              axis_names=set(mesh.axis_names),
                              check_vma=False)
 
+        def _resumed(body, resume):
+            # a partial has no name of its own, and its program would
+            # show on the device as ``jit__unknown``
+            fn = functools.partial(body, resume=resume)
+            fn.__name__ = body.__name__
+            return fn
+
         jitted = {}
         for resume in (False, True):
             jitted[resume, False] = jax.jit(
-                _mk(functools.partial(greedy_body, resume=resume), 5),
+                _mk(_resumed(prefill_step, resume), 5),
                 in_shardings=(_named(mesh, pspecs), None,
                               _named(mesh, cspecs), None, None),
                 out_shardings=(NamedSharding(mesh, P()),
                                _named(mesh, cspecs)),
                 donate_argnums=dn)
             jitted[resume, True] = jax.jit(
-                _mk(functools.partial(sampled_body, resume=resume), 6),
+                _mk(_resumed(prefill_step_sampled, resume), 6),
                 in_shardings=(_named(mesh, pspecs), None,
                               _named(mesh, cspecs), None, None, None),
                 out_shardings=(NamedSharding(mesh, P()),
@@ -500,14 +509,16 @@ def make_serve_step(cfg, pcfg: ParallelConfig, mesh, suite: ShapeSuite,
             ok = jnp.isfinite(logits).all(axis=-1)
             return jnp.where(ok, tokens, jnp.int32(-1))
 
-        def greedy_body(params, inputs, caches, active):
+        # the bodies' names are their programs' names on the device
+        # (``jit_decode_step``, ``jit_decode_step_sampled``)
+        def decode_step(params, inputs, caches, active):
             with _tp_model_ctx(tps, mesh):
                 logits, new_caches = tf.decode_step(params, mcfg, inputs,
                                                     caches, active=active)
             tok = jnp.argmax(logits, axis=-1).astype(jnp.int32)
             return _guard(logits, tok), new_caches
 
-        def sampled_body(params, inputs, caches, active, sampling):
+        def decode_step_sampled(params, inputs, caches, active, sampling):
             with _tp_model_ctx(tps, mesh):
                 logits, new_caches = tf.decode_step(params, mcfg, inputs,
                                                     caches, active=active)
@@ -537,12 +548,12 @@ def make_serve_step(cfg, pcfg: ParallelConfig, mesh, suite: ShapeSuite,
         out_sh = (NamedSharding(mesh, bspec), _named(mesh, cspecs))
         dn = (2,) if donate else ()       # see make_prefill_step on donate
         greedy_step = jax.jit(
-            _mk(greedy_body, 4),
+            _mk(decode_step, 4),
             in_shardings=(_named(mesh, pspecs), None, _named(mesh, cspecs),
                           None),
             out_shardings=out_sh, donate_argnums=dn)
         sampled_step = jax.jit(
-            _mk(sampled_body, 5),
+            _mk(decode_step_sampled, 5),
             in_shardings=(_named(mesh, pspecs), None, _named(mesh, cspecs),
                           None, None),
             out_shardings=out_sh, donate_argnums=dn)
@@ -645,12 +656,15 @@ def make_verify_step(cfg, pcfg: ParallelConfig, mesh, suite: ShapeSuite,
         # -1 rather than committing a token argmaxed out of NaNs
         return jnp.where(jnp.isfinite(lg).all(axis=-1), pred, jnp.int32(-1))
 
-    def greedy_body(params, tokens, caches, active, n_draft):
+    # the bodies' names are their programs' names on the device
+    # (``jit_verify_step``, ``jit_verify_step_sampled``)
+    def verify_step(params, tokens, caches, active, n_draft):
         return _verify(params, tokens, caches, active, n_draft,
                        lambda lg: _vguard(lg, jnp.argmax(lg, axis=-1)
                                           .astype(jnp.int32)))
 
-    def sampled_body(params, tokens, caches, active, n_draft, sampling):
+    def verify_step_sampled(params, tokens, caches, active, n_draft,
+                            sampling):
         def pred_fn(lg):
             pred = sample_tokens_block(lg, sampling["key"], sampling["step"],
                                        sampling["temperature"],
@@ -673,12 +687,12 @@ def make_verify_step(cfg, pcfg: ParallelConfig, mesh, suite: ShapeSuite,
     out_sh = (NamedSharding(mesh, P()), NamedSharding(mesh, P()),
               _named(mesh, cspecs))
     greedy_step = jax.jit(
-        _mk(greedy_body, 5),
+        _mk(verify_step, 5),
         in_shardings=(_named(mesh, pspecs), None, _named(mesh, cspecs),
                       None, None),
         out_shardings=out_sh, donate_argnums=(2,))
     sampled_step = jax.jit(
-        _mk(sampled_body, 6),
+        _mk(verify_step_sampled, 6),
         in_shardings=(_named(mesh, pspecs), None, _named(mesh, cspecs),
                       None, None, None),
         out_shardings=out_sh, donate_argnums=(2,))

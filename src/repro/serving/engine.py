@@ -56,6 +56,7 @@ import numpy as np
 from repro.configs.base import ParallelConfig, ShapeSuite
 from repro.launch import step_fns
 from repro.models import transformer as tf
+from repro.obs.trace import span
 from repro.serving import sampling
 from repro.serving.prefix import PrefixCache
 from repro.serving.request import Request, RequestState
@@ -65,6 +66,17 @@ from repro.serving.speculative import (AdaptiveDraftController, NgramDrafter,
                                        SpecParams, drafter_label)
 from repro.serving.telemetry import (STATS_FIELDS, TelemetryLog,
                                      stats_vector)
+
+
+# The engine's own device programs take their names from these functions:
+# the device trace shows ``jit_reset_slots``, ``jit_extract_row`` and (from
+# ``tf.adopt_prefix`` itself) ``jit_adopt_prefix``.
+def reset_slots(caches, free_mask):
+    return tf.reset_cache_slots(caches, free_mask)
+
+
+def extract_row(caches, slot):
+    return tf.extract_cache_row(caches, slot)
 
 
 def _pow2_at_least(n: int, floor: int) -> int:
@@ -196,7 +208,7 @@ class ServingEngine:
         # out_shardings pinned to the cache specs: on multi-device meshes a
         # free-layout reset would let GSPMD re-shard a leaf and the next
         # prefill/decode call would reject its own cache
-        self._reset = jax.jit(tf.reset_cache_slots,
+        self._reset = jax.jit(reset_slots,
                               out_shardings=self._cache_sharding)
         self.caches = None            # allocated per run
         self.stats_reducer = stats_reducer
@@ -229,7 +241,7 @@ class ServingEngine:
             if self.prefix_cache_nodes < 1:
                 raise ValueError(f"prefix_cache_nodes must be >= 1, got "
                                  f"{prefix_cache_nodes}")
-            self._extract = jax.jit(tf.extract_cache_row)
+            self._extract = jax.jit(extract_row)
             self._adopt = jax.jit(tf.adopt_prefix,
                                   out_shardings=self._cache_sharding)
 
@@ -414,26 +426,29 @@ class EngineSession:
     def submit(self, req) -> None:
         """Queue one more request (initial workload or failover orphan)."""
         eng = self.engine
-        eng._check(req)
-        if req.spec is not None:
-            if eng.drafter is None:
-                eng.drafter = NgramDrafter()
-                if self.prefix is not None:
-                    # trie doubles as the shared n-gram drafter corpus:
-                    # cached sequences from OTHER requests seed proposals
-                    # before a request's own history has any n-grams
-                    eng.drafter.corpus = self.prefix
-            if getattr(eng.drafter, "n_slots", eng.n_slots) != eng.n_slots:
-                raise ValueError(
-                    "drafter slot table does not match the engine "
-                    f"({eng.drafter.n_slots} != {eng.n_slots})")
-            # one compiled verify width per session: the largest requested
-            # draft budget (per-request k varies within it via n_draft),
-            # bounded so a verify call never exceeds the per-call ring
-            # limit (T <= S — same rule as prefill chunks)
-            self.k_run = min(max(self.k_run, req.spec.draft_k),
-                             eng.max_prompt_len - 1)
-        self.sched.submit(req)
+        with span(eng.tracer, "submit", self.now, rid=req.rid,
+                  replica=self.trace_replica):
+            eng._check(req)
+            req.t_submit_ns = time.perf_counter_ns()
+            if req.spec is not None:
+                if eng.drafter is None:
+                    eng.drafter = NgramDrafter()
+                    if self.prefix is not None:
+                        # trie doubles as the shared n-gram drafter corpus:
+                        # cached sequences from OTHER requests seed proposals
+                        # before a request's own history has any n-grams
+                        eng.drafter.corpus = self.prefix
+                if getattr(eng.drafter, "n_slots", eng.n_slots) != eng.n_slots:
+                    raise ValueError(
+                        "drafter slot table does not match the engine "
+                        f"({eng.drafter.n_slots} != {eng.n_slots})")
+                # one compiled verify width per session: the largest requested
+                # draft budget (per-request k varies within it via n_draft),
+                # bounded so a verify call never exceeds the per-call ring
+                # limit (T <= S — same rule as prefill chunks)
+                self.k_run = min(max(self.k_run, req.spec.draft_k),
+                                 eng.max_prompt_len - 1)
+            self.sched.submit(req)
 
     @property
     def running(self) -> bool:
@@ -475,14 +490,32 @@ class EngineSession:
         """Run one engine iteration; returns (and logs) this tick's local
         stats vector (see ``telemetry.STATS_FIELDS``). Raises
         :class:`PoisonedLogits` — committing nothing from the tick — if
-        the decode/verify guard flagged non-finite logits."""
+        the decode/verify guard flagged non-finite logits.
+
+        Each phase of the tick runs inside a span
+        (:func:`repro.obs.trace.span`): ``tick`` around them all, then
+        ``schedule``, ``prefill_chunk``, ``decode``/``verify``, ``fetch``,
+        ``commit``, ``reset`` and ``stats``. A running JAX profiler sees
+        them as ``repro.serve.<name>``; an attached tracer records them."""
+        # observability: read the mutable sinks ONCE per tick (late attach
+        # is the supported idiom — see ServingEngine). Pure observation:
+        # every hook records values the tick computed anyway and feeds
+        # nothing back.
+        tr = self.engine.tracer
+        with span(tr, "tick", self.now, replica=self.trace_replica,
+                  tick=self.now):
+            return self._tick(tr)
+
+    def _tick(self, tr) -> list:
         eng = self.engine
         sched = self.sched
         samp = self.samp
         now = self.now
+        rep = self.trace_replica
         new_tokens = 0
         sampled_tokens = 0
         chunks_fed = 0
+        prompt_tokens = 0
         drafted = 0
         accepted = 0
         resumed = 0
@@ -490,101 +523,106 @@ class EngineSession:
         prefix_hits = 0
         prefix_reused = 0
         freed = np.zeros(eng.n_slots, bool)
-        # observability: read the mutable sinks ONCE per tick (late attach
-        # is the supported idiom — see ServingEngine), and hand the
-        # scheduler the tracer so shed/preempt events are emitted at the
-        # decision site. Pure observation: every hook below records values
-        # the tick computed anyway and feeds nothing back.
-        tr = eng.tracer
+        # the scheduler emits shed/preempt events at the decision site
         sched.tracer = tr
-        sched.trace_replica = self.trace_replica
+        sched.trace_replica = rep
         self._tick_ttfts = []
         self._tick_lats = []
 
-        # --- SLO hooks: shed hopeless queued work, then evict slots the
-        # policy wants for waiting higher-priority requests. Both are
-        # no-ops under the FIFO reference policy. Eviction happens BEFORE
-        # admission so a freed slot is re-granted in the same tick, and
-        # the evicted rows are reset immediately (not at end-of-tick with
-        # ``freed``) so the incoming request prefills into a clean slot.
-        shed_now = sched.shed(now)
-        for req in shed_now:
-            if req.deadline is not None and not req.deadline_counted:
-                req.deadline_counted = True
-                deadline_misses += 1
-        preempt_slots = sched.plan_preemptions(now)
-        if preempt_slots:
-            mask = np.zeros(eng.n_slots, bool)
-            for slot in preempt_slots:
-                req = sched.active[slot]
-                self.pending_chunks.pop(slot, None)
-                self._resume_last.pop(slot, None)
-                self._unpin(slot)
-                sampling.set_slot(samp, slot, None)
-                if req.spec is not None:
-                    eng.drafter.release(slot)
-                    self._ctrls.pop(req.rid, None)
-                sched.preempt(slot, now)
-                mask[slot] = True
-            self.caches = eng._reset(self.caches, jnp.asarray(mask))
+        with span(tr, "schedule", now, replica=rep):
+            # --- SLO hooks: shed hopeless queued work, then evict slots
+            # the policy wants for waiting higher-priority requests. Both
+            # are no-ops under the FIFO reference policy. Eviction happens
+            # BEFORE admission so a freed slot is re-granted in the same
+            # tick, and the evicted rows are reset immediately (not at
+            # end-of-tick with ``freed``) so the incoming request prefills
+            # into a clean slot.
+            shed_now = sched.shed(now)
+            for req in shed_now:
+                if req.deadline is not None and not req.deadline_counted:
+                    req.deadline_counted = True
+                    deadline_misses += 1
+            preempt_slots = sched.plan_preemptions(now)
+            if preempt_slots:
+                mask = np.zeros(eng.n_slots, bool)
+                for slot in preempt_slots:
+                    req = sched.active[slot]
+                    self.pending_chunks.pop(slot, None)
+                    self._resume_last.pop(slot, None)
+                    self._unpin(slot)
+                    sampling.set_slot(samp, slot, None)
+                    if req.spec is not None:
+                        eng.drafter.release(slot)
+                        self._ctrls.pop(req.rid, None)
+                    sched.preempt(slot, now)
+                    mask[slot] = True
+                with span(tr, "reset", now, replica=rep,
+                          n_slots=len(preempt_slots)):
+                    self.caches = eng._reset(self.caches, jnp.asarray(mask))
 
-        # --- admission: grant free slots, stage the chunk plans --------
-        admissions = sched.admit(now, batch_sync=self.static)
-        for slot, req in admissions:
-            history = req.prompt
-            if req.tokens and not eng._exact_resume:
-                # replay the original plan instead (lossy restart — same
-                # stream, more recompute)
-                req.tokens = []
-                req.t_first = None
-            if req.tokens:
-                # exact resume: rebuild the cache over the journal; the
-                # last committed token is re-fed by decode, not re-derived
-                history = req.prompt + tuple(req.tokens[:-1])
-                self._resume_last[slot] = int(req.tokens[-1])
-                resumed += len(req.tokens)
-                req.resumed_tokens += len(req.tokens)
-                if tr is not None:
-                    tr.event("resume", now, rid=req.rid,
-                             replica=self.trace_replica, slot=int(slot),
-                             journal_tokens=len(req.tokens),
-                             preemptions=req.preemptions,
-                             failovers=req.failovers)
-            start = 0
-            if self.prefix is not None:
-                # prefix adoption AFTER history normalization: a resumed
-                # request matches against its journal-extended history,
-                # so a preempted request re-adopts its own boundaries.
-                # lookup() caps the match at len(history)-1 — at least one
-                # chunk always runs so the final chunk emits first-token
-                # logits through the ordinary prefill path.
-                p, node = self.prefix.lookup(history)
-                if node is not None:
-                    self.caches = eng._adopt(
-                        self.caches, node.row, jnp.asarray(slot, jnp.int32))
-                    self.prefix.acquire(node.key)
-                    self._prefix_pins[slot] = node.key
-                    start = p
-                    req.prefilled = p    # resume=True from the first chunk
-                    req.prefix_reused += p
-                    prefix_hits += 1
-                    prefix_reused += p
+            # --- admission: grant free slots, stage the chunk plans ----
+            admissions = sched.admit(now, batch_sync=self.static)
+            for slot, req in admissions:
+                history = req.prompt
+                if req.tokens and not eng._exact_resume:
+                    # replay the original plan instead (lossy restart —
+                    # same stream, more recompute)
+                    req.tokens = []
+                    req.t_first = None
+                if req.tokens:
+                    # exact resume: rebuild the cache over the journal;
+                    # the last committed token is re-fed by decode, not
+                    # re-derived
+                    history = req.prompt + tuple(req.tokens[:-1])
+                    self._resume_last[slot] = int(req.tokens[-1])
+                    resumed += len(req.tokens)
+                    req.resumed_tokens += len(req.tokens)
                     if tr is not None:
-                        tr.event("prefix_adopt", now, rid=req.rid,
-                                 replica=self.trace_replica,
-                                 slot=int(slot), tokens_reused=p)
-                self._prefix_hist[slot] = tuple(history)
-            self.pending_chunks[slot] = eng._chunk_plan(history, start=start)
-            if tr is not None:
-                tr.event("admit", now, rid=req.rid,
-                         replica=self.trace_replica, slot=int(slot),
-                         prompt_len=len(req.prompt),
-                         chunks=len(self.pending_chunks[slot]),
-                         resumed=bool(req.tokens))
-            sampling.set_slot(samp, slot, req.sampling)
-            if req.spec is not None:
-                self._ctrls[req.rid] = AdaptiveDraftController(req.spec)
-                eng.drafter.admit(slot, req)
+                        tr.event("resume", now, rid=req.rid, replica=rep,
+                                 slot=int(slot),
+                                 journal_tokens=len(req.tokens),
+                                 preemptions=req.preemptions,
+                                 failovers=req.failovers)
+                start = 0
+                if self.prefix is not None:
+                    # prefix adoption AFTER history normalization: a
+                    # resumed request matches against its journal-extended
+                    # history, so a preempted request re-adopts its own
+                    # boundaries. lookup() caps the match at
+                    # len(history)-1 — at least one chunk always runs so
+                    # the final chunk emits first-token logits through the
+                    # ordinary prefill path.
+                    p, node = self.prefix.lookup(history)
+                    if node is not None:
+                        self.caches = eng._adopt(
+                            self.caches, node.row,
+                            jnp.asarray(slot, jnp.int32))
+                        self.prefix.acquire(node.key)
+                        self._prefix_pins[slot] = node.key
+                        start = p
+                        req.prefilled = p  # resume=True from the 1st chunk
+                        req.prefix_reused += p
+                        prefix_hits += 1
+                        prefix_reused += p
+                        if tr is not None:
+                            tr.event("prefix_adopt", now, rid=req.rid,
+                                     replica=rep, slot=int(slot),
+                                     tokens_reused=p)
+                    self._prefix_hist[slot] = tuple(history)
+                self.pending_chunks[slot] = eng._chunk_plan(history,
+                                                            start=start)
+                if tr is not None:
+                    tr.event("admit", now, rid=req.rid, replica=rep,
+                             slot=int(slot), prompt_len=len(req.prompt),
+                             chunks=len(self.pending_chunks[slot]),
+                             resumed=bool(req.tokens),
+                             wait_ms=(None if req.t_submit_ns is None else
+                                      (time.perf_counter_ns()
+                                       - req.t_submit_ns) / 1e6))
+                sampling.set_slot(samp, slot, req.sampling)
+                if req.spec is not None:
+                    self._ctrls[req.rid] = AdaptiveDraftController(req.spec)
+                    eng.drafter.admit(slot, req)
 
         # --- prefill: one chunk per admitting slot per tick ------------
         # one single-row call per chunk (cost follows the admitted
@@ -597,26 +635,25 @@ class EngineSession:
             chunk = self.pending_chunks[slot].pop(0)
             final = not self.pending_chunks[slot]
             tc = eng._bucket(len(chunk))
-            buf = np.zeros((1, tc), np.int32)
-            buf[0, :len(chunk)] = chunk
-            sampled_req = (req.sampling is not None
-                           and not req.sampling.greedy
-                           and slot not in self._resume_last)
-            tok, self.caches = eng._prefill(
-                eng.params, jnp.asarray(buf), self.caches,
-                jnp.asarray(slot, jnp.int32),
-                jnp.asarray(len(chunk), jnp.int32),
-                resume=req.prefilled > 0,
-                sampling_row=({k: jnp.asarray(v[slot])
-                               for k, v in samp.items()}
-                              if sampled_req else None))
+            with span(tr, "prefill_chunk", now, rid=req.rid, replica=rep,
+                      slot=int(slot), bucket=tc, tokens=len(chunk),
+                      final=final):
+                buf = np.zeros((1, tc), np.int32)
+                buf[0, :len(chunk)] = chunk
+                sampled_req = (req.sampling is not None
+                               and not req.sampling.greedy
+                               and slot not in self._resume_last)
+                tok, self.caches = eng._prefill(
+                    eng.params, jnp.asarray(buf), self.caches,
+                    jnp.asarray(slot, jnp.int32),
+                    jnp.asarray(len(chunk), jnp.int32),
+                    resume=req.prefilled > 0,
+                    sampling_row=({k: jnp.asarray(v[slot])
+                                   for k, v in samp.items()}
+                                  if sampled_req else None))
             req.prefilled += len(chunk)
             chunks_fed += 1
-            if tr is not None:
-                tr.event("prefill_chunk", now, rid=req.rid,
-                         replica=self.trace_replica, slot=int(slot),
-                         chunk_tokens=len(chunk), final=final,
-                         prefilled=req.prefilled)
+            prompt_tokens += len(chunk)
             if self.prefix is not None:
                 # snapshot the slot row at every chunk-grid boundary: the
                 # row there is a pure function of history[:p] + the grid
@@ -640,18 +677,20 @@ class EngineSession:
                     # journal is authoritative: discard the re-derived
                     # token, resume decode from the committed tail
                     self.last[slot] = self._resume_last.pop(slot)
-                else:
+                    continue
+                with span(tr, "fetch", now, replica=rep, of="prefill"):
                     tok = int(np.asarray(tok))
+                with span(tr, "commit", now, replica=rep):
                     req.tokens.append(tok)
                     req.t_first = now
                     self._tick_ttfts.append(req.ttft)
                     if tr is not None:
-                        tr.event("commit", now, rid=req.rid,
-                                 replica=self.trace_replica, slot=int(slot),
-                                 n_tokens=1, first_token=True,
-                                 ttft_ticks=req.ttft)
+                        tr.event("commit", now, rid=req.rid, replica=rep,
+                                 slot=int(slot), n_tokens=1,
+                                 first_token=True, ttft_ticks=req.ttft)
                     if req.deadline is not None \
-                            and not req.deadline_counted and now > req.deadline:
+                            and not req.deadline_counted \
+                            and now > req.deadline:
                         req.deadline_counted = True
                         deadline_misses += 1
                     self.last[slot] = tok
@@ -680,133 +719,147 @@ class EngineSession:
                 if d:
                     drafts[slot] = [int(t) for t in d]
                 if tr is not None:
-                    tr.event("draft", now, rid=req.rid,
-                             replica=self.trace_replica, slot=int(slot),
-                             k_eff=int(k_eff), proposed=len(d),
+                    tr.event("draft", now, rid=req.rid, replica=rep,
+                             slot=int(slot), k_eff=int(k_eff),
+                             proposed=len(d),
                              drafter=drafter_label(eng.drafter))
 
         if decodable:
-            active = np.zeros(eng.n_slots, bool)
-            steps = np.zeros(eng.n_slots, np.int32)
-            any_sampled = False
-            for slot, req in decodable.items():
-                active[slot] = True
-                steps[slot] = len(req.tokens)
-                any_sampled |= (req.sampling is not None
-                                and not req.sampling.greedy)
-            # all-greedy ticks take the argmax-only jitted variant;
-            # the sampled variant's greedy rows are the same argmax,
-            # so mixing never changes a greedy request's stream
-            samp_in = ({"key": jnp.asarray(samp["key"]),
-                        "step": jnp.asarray(steps),
-                        "temperature": jnp.asarray(samp["temperature"]),
-                        "top_k": jnp.asarray(samp["top_k"]),
-                        "top_p": jnp.asarray(samp["top_p"])}
-                       if any_sampled else None)
+            step = "verify" if drafts else "decode"
+            with span(tr, step, now, replica=rep, n_active=len(decodable)):
+                active = np.zeros(eng.n_slots, bool)
+                steps = np.zeros(eng.n_slots, np.int32)
+                any_sampled = False
+                for slot, req in decodable.items():
+                    active[slot] = True
+                    steps[slot] = len(req.tokens)
+                    any_sampled |= (req.sampling is not None
+                                    and not req.sampling.greedy)
+                # all-greedy ticks take the argmax-only jitted variant;
+                # the sampled variant's greedy rows are the same argmax,
+                # so mixing never changes a greedy request's stream
+                samp_in = ({"key": jnp.asarray(samp["key"]),
+                            "step": jnp.asarray(steps),
+                            "temperature": jnp.asarray(samp["temperature"]),
+                            "top_k": jnp.asarray(samp["top_k"]),
+                            "top_p": jnp.asarray(samp["top_p"])}
+                           if any_sampled else None)
+                if drafts:
+                    # --- verify: score k+1 positions per slot in one
+                    # pass, emit the longest committed-stream-matching
+                    # prefix
+                    buf = np.zeros((eng.n_slots, self.k_run + 1), np.int32)
+                    buf[:, 0] = self.last
+                    n_draft = np.zeros(eng.n_slots, np.int32)
+                    for slot, d in drafts.items():
+                        buf[slot, 1:1 + len(d)] = d
+                        n_draft[slot] = len(d)
+                    out, acc, self.caches = eng._get_verify(self.k_run)(
+                        eng.params, jnp.asarray(buf), self.caches,
+                        jnp.asarray(active), jnp.asarray(n_draft), samp_in)
+                else:
+                    # --- decode: one token per busy slot (no proposals)
+                    toks, self.caches = eng._decode(
+                        eng.params,
+                        {"tokens": jnp.asarray(self.last[:, None])},
+                        self.caches, jnp.asarray(active), samp_in)
             if drafts:
-                # --- verify: score k+1 positions per slot in one pass,
-                # emit the longest committed-stream-matching prefix ----
-                buf = np.zeros((eng.n_slots, self.k_run + 1), np.int32)
-                buf[:, 0] = self.last
-                n_draft = np.zeros(eng.n_slots, np.int32)
-                for slot, d in drafts.items():
-                    buf[slot, 1:1 + len(d)] = d
-                    n_draft[slot] = len(d)
-                out, acc, self.caches = eng._get_verify(self.k_run)(
-                    eng.params, jnp.asarray(buf), self.caches,
-                    jnp.asarray(active), jnp.asarray(n_draft), samp_in)
-                out = np.asarray(out).astype(np.int32)
-                acc = np.asarray(acc).astype(np.int32)
-                self._guard(decodable, [out[s, :acc[s]].min(initial=0)
-                                        for s in decodable])
-                for slot, req in decodable.items():
-                    n = int(acc[slot])
-                    emit = [int(t) for t in out[slot, :n]]
-                    req.tokens.extend(emit)
-                    self.last[slot] = emit[-1]
-                    new_tokens += len(emit)
-                    if req.sampling is not None \
-                            and not req.sampling.greedy:
-                        sampled_tokens += len(emit)
-                    nd = int(n_draft[slot])
-                    drafted += nd
-                    accepted += n - 1
-                    if req.spec is not None:
-                        self._ctrls[req.rid].update(nd, n - 1)
-                    if tr is not None:
-                        tr.event("verify", now, rid=req.rid,
-                                 replica=self.trace_replica, slot=int(slot),
-                                 n_draft=nd, accepted=n - 1,
-                                 committed=len(emit))
-                    if req.done:
-                        self._release(slot, req, freed)
+                with span(tr, "fetch", now, replica=rep, of="verify"):
+                    out = np.asarray(out).astype(np.int32)
+                    acc = np.asarray(acc).astype(np.int32)
+                with span(tr, "commit", now, replica=rep):
+                    self._guard(decodable, [out[s, :acc[s]].min(initial=0)
+                                            for s in decodable])
+                    for slot, req in decodable.items():
+                        n = int(acc[slot])
+                        emit = [int(t) for t in out[slot, :n]]
+                        req.tokens.extend(emit)
+                        self.last[slot] = emit[-1]
+                        new_tokens += len(emit)
+                        if req.sampling is not None \
+                                and not req.sampling.greedy:
+                            sampled_tokens += len(emit)
+                        nd = int(n_draft[slot])
+                        drafted += nd
+                        accepted += n - 1
+                        if req.spec is not None:
+                            self._ctrls[req.rid].update(nd, n - 1)
+                        if tr is not None:
+                            tr.event("commit", now, rid=req.rid,
+                                     replica=rep, slot=int(slot),
+                                     n_tokens=len(emit), n_draft=nd,
+                                     accepted=n - 1)
+                        if req.done:
+                            self._release(slot, req, freed)
             else:
-                # --- decode: one token per busy slot (no proposals) ----
-                toks, self.caches = eng._decode(
-                    eng.params, {"tokens": jnp.asarray(self.last[:, None])},
-                    self.caches, jnp.asarray(active), samp_in)
-                toks = np.asarray(toks).astype(np.int32)
-                self._guard(decodable, [toks[s] for s in decodable])
-                if tr is not None:
-                    tr.event("decode", now, replica=self.trace_replica,
-                             n_active=len(decodable))
-                for slot, req in decodable.items():
-                    req.tokens.append(int(toks[slot]))
-                    self.last[slot] = toks[slot]
-                    new_tokens += 1
-                    if req.sampling is not None \
-                            and not req.sampling.greedy:
-                        sampled_tokens += 1
-                    if tr is not None:
-                        tr.event("commit", now, rid=req.rid,
-                                 replica=self.trace_replica, slot=int(slot),
-                                 n_tokens=1)
-                    if req.done:
-                        self._release(slot, req, freed)
+                with span(tr, "fetch", now, replica=rep, of="decode"):
+                    toks = np.asarray(toks).astype(np.int32)
+                with span(tr, "commit", now, replica=rep):
+                    self._guard(decodable, [toks[s] for s in decodable])
+                    for slot, req in decodable.items():
+                        req.tokens.append(int(toks[slot]))
+                        self.last[slot] = toks[slot]
+                        new_tokens += 1
+                        if req.sampling is not None \
+                                and not req.sampling.greedy:
+                            sampled_tokens += 1
+                        if tr is not None:
+                            tr.event("commit", now, rid=req.rid,
+                                     replica=rep, slot=int(slot),
+                                     n_tokens=1)
+                        if req.done:
+                            self._release(slot, req, freed)
 
         if freed.any():
-            self.caches = eng._reset(self.caches, jnp.asarray(freed))
-            for slot in np.flatnonzero(freed):
-                sampling.set_slot(samp, int(slot), None)
-        # build the stats row BY NAME through the drift guard: a counter
-        # added here but not to STATS_FIELDS (or vice versa) fails on the
-        # first tick instead of silently skewing the b=1 fleet reduction
-        vec = stats_vector({
-            "queue_depth": sched.arrived_depth(now),
-            "active_slots": len(sched.active),
-            "new_tokens": new_tokens,
-            "prefills": len(admissions),
-            "prefill_chunks": chunks_fed,
-            "sampled_tokens": sampled_tokens,
-            "drafted_tokens": drafted,
-            "accepted_tokens": accepted,
-            "failovers": 0,       # control-plane: counted by the fleet
-            "resumed_tokens": resumed,
-            "quarantines": 0,     # control-plane: counted by the fleet
-            "preemptions": len(preempt_slots),
-            "shed_requests": len(shed_now),
-            "deadline_misses": deadline_misses,
-            "prefix_hits": prefix_hits,
-            "prefix_tokens_reused": prefix_reused,
-        })
-        metrics = eng.metrics
-        if metrics is not None:
-            # histogram increments ride the SAME b=1 stats reduction — the
-            # row just gets a fixed-width tail (the reducer is width-
-            # agnostic); counts land in the histograms only via the
-            # reduced vector, so single-engine and fleet runs agree.
-            vec = vec + metrics.row(self._tick_ttfts, self._tick_lats)
-        self.log.step(now, vec)
-        if metrics is not None:
-            metrics.absorb(self.log.last_reduced[len(STATS_FIELDS):])
-            every = eng.metrics_every
-            if every > 0 and (now + 1) % every == 0:
-                snap = metrics.snapshot()
-                if eng.metrics_sink is not None:
-                    eng.metrics_sink(now, snap)
-                if tr is not None:
-                    tr.event("metrics", now, replica=self.trace_replica,
-                             **snap)
+            with span(tr, "reset", now, replica=rep,
+                      n_slots=int(freed.sum())):
+                self.caches = eng._reset(self.caches, jnp.asarray(freed))
+                for slot in np.flatnonzero(freed):
+                    sampling.set_slot(samp, int(slot), None)
+        queue_depth = sched.arrived_depth(now)
+        with span(tr, "stats", now, replica=rep, queue_depth=queue_depth,
+                  active_slots=len(sched.active), prefills=len(admissions),
+                  prompt_tokens=prompt_tokens, new_tokens=new_tokens):
+            # build the stats row BY NAME through the drift guard: a
+            # counter added here but not to STATS_FIELDS (or vice versa)
+            # fails on the first tick instead of silently skewing the b=1
+            # fleet reduction
+            vec = stats_vector({
+                "queue_depth": queue_depth,
+                "active_slots": len(sched.active),
+                "new_tokens": new_tokens,
+                "prefills": len(admissions),
+                "prefill_chunks": chunks_fed,
+                "sampled_tokens": sampled_tokens,
+                "drafted_tokens": drafted,
+                "accepted_tokens": accepted,
+                "failovers": 0,       # control-plane: counted by the fleet
+                "resumed_tokens": resumed,
+                "quarantines": 0,     # control-plane: counted by the fleet
+                "preemptions": len(preempt_slots),
+                "shed_requests": len(shed_now),
+                "deadline_misses": deadline_misses,
+                "prefix_hits": prefix_hits,
+                "prefix_tokens_reused": prefix_reused,
+            })
+            metrics = eng.metrics
+            if metrics is not None:
+                # histogram increments ride the SAME b=1 stats reduction —
+                # the row just gets a fixed-width tail (the reducer is
+                # width-agnostic); counts land in the histograms only via
+                # the reduced vector, so single-engine and fleet runs
+                # agree.
+                vec = vec + metrics.row(self._tick_ttfts, self._tick_lats)
+            self.log.step(now, vec)
+            if metrics is not None:
+                metrics.absorb(self.log.last_reduced[len(STATS_FIELDS):])
+                every = eng.metrics_every
+                if every > 0 and (now + 1) % every == 0:
+                    snap = metrics.snapshot()
+                    if eng.metrics_sink is not None:
+                        eng.metrics_sink(now, snap)
+                    if tr is not None:
+                        tr.event("metrics", now, replica=rep, **snap)
         self.now += 1
         return vec
 

@@ -82,6 +82,10 @@ class Request:
     t_admit: int | None = None       # tick the slot was granted
     t_first: int | None = None       # tick the first token was emitted
     t_done: int | None = None        # tick generation completed
+    # perf_counter_ns at the last EngineSession.submit (the admit event's
+    # wait_ms); a wall-clock stamp, so it takes no part in equality
+    t_submit_ns: int | None = dataclasses.field(default=None, repr=False,
+                                                 compare=False)
     failovers: int = 0               # times re-queued off a dead replica
     resumed_tokens: int = 0          # journal tokens replayed across resumes
     preemptions: int = 0             # times evicted mid-flight for priority

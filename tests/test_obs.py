@@ -23,16 +23,17 @@ import os
 import subprocess
 import sys
 import textwrap
+import time
 
 import numpy as np
 import pytest
 
 from repro.core import cost_model as cm
-from repro.obs import (DEFAULT_EDGES, SPAN_NAMES, TICK_US, CollectiveProbe,
-                       ProbeSample, StreamingMetrics, TickHistogram,
-                       TraceEvent, Tracer, export_residuals, fit_alpha_beta,
-                       fit_hier, flat_coeffs, predict_time, probing,
-                       residual_report)
+from repro.obs import (DEFAULT_EDGES, PROFILER_PREFIX, SPAN_NAMES,
+                       CollectiveProbe, ProbeSample, StreamingMetrics,
+                       TickHistogram, TraceEvent, Tracer, export_residuals,
+                       fit_alpha_beta, fit_hier, flat_coeffs, predict_time,
+                       probing, residual_report, span)
 from repro.obs import probe as probe_mod
 from repro.runtime.chaos import Fault, FaultPlan
 from repro.serving import (STATS_FIELDS, FleetRunner, PriorityClass, Request,
@@ -79,20 +80,23 @@ def test_tracer_jsonl_roundtrip(tmp_path):
     path = tmp_path / "trace.jsonl"
     assert tr.to_jsonl(str(path)) == 1
     rows = [json.loads(line) for line in path.read_text().splitlines()]
+    t_ns = tr.events[0].t_ns
+    assert t_ns > 0
     assert rows == [{"name": "commit", "tick": 4, "seq": 1, "replica": 0,
+                     "t_ns": t_ns, "dur_ns": 0, "parent": None,
                      "rid": 0,
                      "attrs": {"n_tokens": 1, "ttft_ticks": 2.0}}]
 
 
 def test_chrome_trace_layout(tmp_path):
     """pid = replica, tid = rid + 1 (0 = engine lane), one metadata pair
-    per lane, one lifetime span per request, one slice per event — all on
-    the tick clock scaled by TICK_US."""
+    per lane, one lifetime span per request, one slice per event — all in
+    microseconds of real time from the first event, ticks in ``args``."""
     tr = Tracer()
     tr.event("admit", 0, rid=0, replica=0)
+    with span(tr, "decode", 1, replica=2, n_active=1):
+        tr.event("admit", 1, rid=1, replica=2)
     tr.event("commit", 3, rid=0, replica=0)
-    tr.event("admit", 1, rid=1, replica=2)
-    tr.event("decode", 1, replica=2)
     path = tmp_path / "trace.json"
     doc = tr.to_chrome(str(path))
     assert json.loads(path.read_text()) == doc          # file == return
@@ -106,22 +110,32 @@ def test_chrome_trace_layout(tmp_path):
     spans = [e for e in evs if e.get("cat") == "request"]
     by_req = {(s["pid"], s["args"]["rid"]): s for s in spans}
     assert set(by_req) == {(0, 0), (2, 1)}
+    t0 = tr.events[0].t_ns                              # the first event
+    first, last = tr.by_name("admit")[0], tr.by_name("commit")[0]
     assert by_req[(0, 0)]["ts"] == 0
-    assert by_req[(0, 0)]["dur"] == 4 * TICK_US         # ticks 0..3
+    assert by_req[(0, 0)]["dur"] == (last.t_ns - first.t_ns) / 1e3
+    assert doc["otherData"]["t0_ns"] == t0
     slices = [e for e in evs if e.get("cat") == "serving"]
     assert len(slices) == 4
-    for s in slices:
-        assert s["ph"] == "X" and s["dur"] == TICK_US
-        assert s["ts"] % TICK_US == 0
+    by_seq = {e.seq: e for e in tr.events}
+    for s, e in zip(slices, tr.events):
+        assert s["ph"] == "X" and s["name"] == e.name
+        assert s["ts"] == (e.t_ns - t0) / 1e3 and s["dur"] == e.dur_ns / 1e3
+        assert s["args"]["tick"] == e.tick
     eng = [s for s in slices if s["name"] == "decode"][0]
     assert (eng["pid"], eng["tid"]) == (2, 0)           # engine lane
+    assert eng["dur"] > 0 and eng["args"]["n_active"] == 1
+    inner = by_seq[tr.by_name("decode")[0].seq + 1]     # the nested admit
+    assert inner.rid == 1 and inner.parent == tr.by_name("decode")[0].seq
 
 
 def test_span_taxonomy_is_pinned():
     """docs/observability.md documents exactly these producer names."""
-    assert set(SPAN_NAMES) == {"admit", "prefill_chunk", "decode", "draft",
-                               "verify", "commit", "preempt", "resume",
-                               "failover", "prefix_adopt", "shed"}
+    assert set(SPAN_NAMES) == {"tick", "schedule", "submit", "admit",
+                               "prefill_chunk", "decode", "draft", "verify",
+                               "fetch", "commit", "reset", "stats",
+                               "preempt", "resume", "failover",
+                               "prefix_adopt", "shed"}
 
 
 # ==========================================================================
@@ -518,8 +532,10 @@ def test_trace_event_payloads_are_faithful():
     assert pre[0].attrs["journal_tokens"] >= 1
     resumes = tr.by_name("resume")
     assert resumes and resumes[0].attrs["preemptions"] >= 1
-    # verify accounting sums to the telemetry counters
-    vs = tr.by_name("verify")
+    # verify accounting, on each request's commit after a verify step,
+    # sums to the telemetry counters
+    vs = [e for e in tr.by_name("commit") if "n_draft" in e.attrs]
+    assert vs and all(tr.events[e.parent - 1].name == "commit" for e in vs)
     assert sum(e.attrs["n_draft"] for e in vs) == rep["drafted_tokens"]
     assert sum(e.attrs["accepted"] for e in vs) == rep["accepted_tokens"]
     # final commits carry the stream length
@@ -571,14 +587,20 @@ def test_fleet_failover_traced_and_chrome_loadable(tmp_path):
     path = tmp_path / "acceptance.json"
     doc = tr.to_chrome(str(path))
     loaded = json.loads(path.read_text())
-    assert loaded["traceEvents"] and loaded["otherData"]["tick_us"] == \
-        TICK_US
+    assert loaded["traceEvents"] and loaded["otherData"]["clock"] == \
+        "perf_counter_ns"
     pids = {e["pid"] for e in loaded["traceEvents"]}
     assert pids >= {0, 1}
     spans = [e for e in loaded["traceEvents"] if e.get("cat") == "request"]
     assert {s["args"]["rid"] for s in spans} >= {r.rid for r in reqs()}
+    t0 = min(e.t_ns for e in tr.events)
     for s in spans:                                     # Perfetto invariants
-        assert s["ph"] == "X" and s["dur"] >= TICK_US and s["ts"] >= 0
+        assert s["ph"] == "X" and s["dur"] >= 0 and s["ts"] >= 0
+        mine = [e for e in tr.events if e.rid == s["args"]["rid"]
+                and e.replica == s["pid"]]
+        assert s["ts"] == (min(e.t_ns for e in mine) - t0) / 1e3
+        assert s["dur"] == (max(e.t_ns + e.dur_ns for e in mine)
+                            - min(e.t_ns for e in mine)) / 1e3
 
 
 def test_prefix_trie_events_ride_the_trace():
@@ -620,6 +642,162 @@ def test_shed_events_from_overload():
     shed = tr.by_name("shed")
     assert len(shed) == 1 and shed[0].rid == 1
     assert shed[0].attrs["deadline"] is not None
+
+
+def test_spans_nest_and_run_on_the_wall_clock():
+    """A span records its start, duration and parent; events inside it
+    name it as their parent; the stack unwinds through an exception."""
+    tr = Tracer()
+    with span(tr, "tick", 5, tick=5):
+        with span(tr, "fetch", 5, of="decode"):
+            time.sleep(0.002)
+        tr.event("commit", 5, rid=1)
+    tick, fetch, commit = tr.events
+    assert [e.seq for e in tr.events] == [1, 2, 3]     # in order of start
+    assert (tick.parent, fetch.parent, commit.parent) == (None, 1, 1)
+    assert tick.attrs == {"tick": 5} and fetch.attrs == {"of": "decode"}
+    assert fetch.dur_ns >= 2_000_000 and tick.dur_ns >= fetch.dur_ns
+    assert tick.t_ns <= fetch.t_ns <= commit.t_ns \
+        <= tick.t_ns + tick.dur_ns
+    assert commit.dur_ns == 0                           # a point event
+    with pytest.raises(RuntimeError):
+        with span(tr, "stats", 6):
+            raise RuntimeError("in the body")
+    tr.event("admit", 7)
+    assert tr.by_name("stats")[0].parent is None
+    assert tr.events[-1].parent is None                 # stack unwound
+    with span(None, "tick", 8, tick=8):                 # no tracer: no-op
+        pass
+    assert len(tr) == 5
+    capped = Tracer(max_events=1)
+    with span(capped, "tick", 0):
+        with span(capped, "fetch", 0):
+            capped.event("commit", 0)
+    assert len(capped) == 1 and capped.dropped == 2
+    assert capped.events[0].dur_ns > 0
+
+
+def _host_spans(trace_dir) -> list:
+    """The ``repro.serve.*`` events of a profile on the host, as
+    ``(name, start_ns, end_ns, stats)``."""
+    import glob
+    import warnings
+
+    from jax.profiler import ProfileData
+    path, = glob.glob(os.path.join(str(trace_dir), "plugins", "profile",
+                                   "*", "*.xplane.pb"))
+    out = []
+    with warnings.catch_warnings():
+        warnings.simplefilter("ignore", DeprecationWarning)
+        for plane in ProfileData.from_file(path).planes:
+            for line in plane.lines:
+                out += [(e.name[len(PROFILER_PREFIX):], e.start_ns,
+                         e.start_ns + e.duration_ns, dict(e.stats))
+                        for e in line.events
+                        if e.name.startswith(PROFILER_PREFIX)]
+    return sorted(out, key=lambda e: e[1])
+
+
+def test_tick_phases_reach_the_profiler(tmp_path):
+    """With a JAX profiler running and no tracer attached, every tick
+    leaves a ``repro.serve.tick`` span with its phases nested inside, and
+    each prefill a span carrying the prompt's bucket and real tokens."""
+    import jax
+    cfg, eng = make_engine(n_slots=2, max_len=32)
+    reqs = make_requests(3, cfg, seed=5, plen=(3, 12))
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        rep = eng.run(reqs)
+    finally:
+        jax.profiler.stop_trace()
+    spans = _host_spans(tmp_path)
+    ticks = [s for s in spans if s[0] == "tick"]
+    assert [s[3]["tick"] for s in ticks] == list(range(rep["ticks"]))
+
+    def inside(name, tick):
+        return [s for s in spans if s[0] == name
+                and tick[1] <= s[1] and s[2] <= tick[2]]
+    decoded = [t for t in ticks if inside("decode", t)]
+    assert decoded
+    for t in decoded:
+        assert len(inside("decode", t)) == 1
+        assert [s[3]["of"] for s in inside("fetch", t)][-1] == "decode"
+        assert len(inside("stats", t)) == 1 and inside("schedule", t)
+    stats = [s[3] for s in spans if s[0] == "stats"]
+    assert sum(s["new_tokens"] for s in stats) == \
+        sum(r.max_new_tokens for r in reqs)
+    assert sum(s["prompt_tokens"] for s in stats) == \
+        sum(len(r.prompt) for r in reqs)
+    chunks = {s[3]["rid"]: s[3] for s in spans if s[0] == "prefill_chunk"}
+    assert sorted(chunks) == [r.rid for r in reqs]
+    for r in reqs:
+        assert chunks[r.rid]["tokens"] == len(r.prompt)
+        assert chunks[r.rid]["bucket"] == eng._bucket(len(r.prompt))
+        assert chunks[r.rid]["final"]
+    assert sorted(s[3]["rid"] for s in spans if s[0] == "submit") == \
+        [r.rid for r in reqs]
+
+
+def _programs(step) -> dict:
+    """The jitted programs behind one of the engine's step functions, by
+    the name they lower under."""
+    out = {}
+    for cell in step.__closure__:
+        v = cell.cell_contents
+        for f in (v.values() if isinstance(v, dict) else [v]):
+            if hasattr(f, "lower"):
+                out[f.__name__] = f
+    return out
+
+
+def test_step_programs_carry_their_role_names():
+    """The device trace finds each program by name: the decode and prefill
+    steps lower to ``jit_decode_step`` and ``jit_prefill_step`` (with
+    their sampled twins), the slot reset and row copy to theirs."""
+    import jax.numpy as jnp
+    cfg, eng = make_engine(n_slots=2, max_len=32, prefix_cache=True)
+    session = eng.start()
+    caches, slot = session.caches, jnp.asarray(0, jnp.int32)
+    dec = _programs(eng._decode)
+    assert set(dec) == {"decode_step", "decode_step_sampled"}
+    text = dec["decode_step"].lower(
+        eng.params, {"tokens": jnp.zeros((2, 1), jnp.int32)}, caches,
+        jnp.ones((2,), bool)).as_text()
+    assert text.startswith("module @jit_decode_step ")
+    pre = _programs(eng._prefill)
+    assert set(pre) == {"prefill_step", "prefill_step_sampled"}
+    text = pre["prefill_step"].lower(
+        eng.params, jnp.zeros((1, 8), jnp.int32), caches, slot,
+        jnp.asarray(3, jnp.int32)).as_text()
+    assert text.startswith("module @jit_prefill_step ")
+    assert eng._reset.lower(caches, jnp.zeros((2,), bool)).as_text() \
+        .startswith("module @jit_reset_slots ")
+    assert eng._extract.lower(caches, slot).as_text() \
+        .startswith("module @jit_extract_row ")
+    assert set(_programs(eng._get_verify(2))) == {"verify_step",
+                                                  "verify_step_sampled"}
+
+
+def test_admit_carries_the_wait_since_submit():
+    """``submit`` stamps the request on the wall clock; its ``admit``
+    event carries the milliseconds since, re-admissions included."""
+    cfg, eng = _obs_engine("attn-tiny")
+    tr = Tracer()
+    eng.tracer = tr
+    try:
+        reqs = _obs_matrix_reqs(False)
+        eng.run(reqs, policy=SLOPolicy(age_ticks=100))
+    finally:
+        eng.tracer = None
+    submitted = {e.rid: e for e in tr.by_name("submit")}
+    assert set(submitted) == {r.rid for r in reqs}
+    assert all(r.t_submit_ns is not None for r in reqs)
+    admits = tr.by_name("admit")
+    assert len(admits) > len(reqs)                      # a re-admission
+    for e in admits:
+        s = submitted[e.rid]
+        assert s.t_ns <= reqs[e.rid].t_submit_ns <= s.t_ns + s.dur_ns
+        assert 0 < e.attrs["wait_ms"] * 1e6 <= e.t_ns - s.t_ns
 
 
 # ==========================================================================
