@@ -473,7 +473,7 @@ def make_serve_step(cfg, pcfg: ParallelConfig, mesh, suite: ShapeSuite,
     (tokens (B,), new_caches) against per-slot caches (``pos`` per batch
     row; SSM rows carry their recurrent state). ``active`` (B,) bool marks
     rows holding in-flight requests; inactive rows compute but neither
-    advance nor mutate their cache rows (the decode step merges them back),
+    advance nor mutate their cache rows (see ``tf.decode_step``),
     so one compiled step serves any mix of busy/free/prefilling slots — the
     continuous-batching engine's decode tick. ``sampling`` threads the
     per-request seeded sampler through the jitted step: {key (B,2) u32,

@@ -428,22 +428,77 @@ def quantize_kv_rows(x: jax.Array):
     return q.astype(jnp.int8), scale.astype(jnp.float32)
 
 
+def _flat_kv(x: jax.Array) -> jax.Array:
+    """(..., KV, d) -> (..., KV * d): a token's K or V, or its int8 scales,
+    as the ring stores them."""
+    return x.reshape(x.shape[:-2] + (-1,))
+
+
 def _cache_write(cache_arr, scale_arr, val, slot):
     """Write one token's K or V into a (possibly int8-quantized) ring cache."""
     if cache_arr.dtype == jnp.int8:
         q, s = quantize_kv_rows(val)
-        cache_arr = jax.lax.dynamic_update_slice(cache_arr, q, (0, slot, 0, 0))
-        scale_arr = jax.lax.dynamic_update_slice(scale_arr, s, (0, slot, 0, 0))
+        cache_arr = jax.lax.dynamic_update_slice(cache_arr, _flat_kv(q),
+                                                 (0, slot, 0))
+        scale_arr = jax.lax.dynamic_update_slice(scale_arr, _flat_kv(s),
+                                                 (0, slot, 0))
     else:
         cache_arr = jax.lax.dynamic_update_slice(
-            cache_arr, val.astype(cache_arr.dtype), (0, slot, 0, 0))
+            cache_arr, _flat_kv(val).astype(cache_arr.dtype), (0, slot, 0))
     return cache_arr, scale_arr
 
 
 def _cache_read(cache_arr, scale_arr, dtype):
     if cache_arr.dtype == jnp.int8:
-        return (cache_arr.astype(jnp.float32) * scale_arr).astype(dtype)
+        dh = cache_arr.shape[-1] // scale_arr.shape[-1]
+        return (cache_arr.astype(jnp.float32)
+                * jnp.repeat(scale_arr, dh, axis=-1)).astype(dtype)
     return cache_arr.astype(dtype)
+
+
+def _ring_attend(q: jax.Array, ring_k: jax.Array, ring_v: jax.Array,
+                 valid: jax.Array, n_kv: int) -> jax.Array:
+    """Attention of queries ``q`` (B, T, H, dh) over rings stored as
+    ``(B, S, KV * dh)`` (a token's heads side by side); ``valid``
+    broadcasts to (B, T, S). Returns (B, T, H * dh).
+
+    One query per row (decode) contracts the rings as they are stored: the
+    query enters block-diagonal over the KV heads (zero outside its own
+    head's ``dh`` columns), and each head's output is the diagonal block of
+    the product with V. Both products then read each ring once in the
+    layout it is stored in. Splitting the ring's last axis into heads
+    instead makes the compiler relay the ring out, a copy of the whole
+    cache every step. Several queries per row (prefill, verify) take that
+    per-head view: there the extra work of the block-diagonal form would
+    grow with the queries."""
+    B, T, H, dh = q.shape
+    S = ring_k.shape[1]
+    rep = H // n_kv
+    qg = q.reshape(B, T, n_kv, rep, dh)
+    if T == 1:
+        # own[j, g]: column j of a token's K/V belongs to KV head g
+        own = (jnp.arange(n_kv * dh)[:, None] // dh
+               == jnp.arange(n_kv)[None]).astype(q.dtype)
+        # (B, KV * dh, KV, rep): the query, block-diagonal over the heads
+        qbd = (jnp.swapaxes(qg[:, 0], 2, 3).reshape(B, n_kv * dh, 1, rep)
+               * own[None, :, :, None])
+        logits = jnp.einsum("bsj,bjgr->bgrs", ring_k, qbd)[:, :, :, None]
+    else:
+        logits = jnp.einsum("btgrd,bsgd->bgrts", qg,
+                            ring_k.reshape(B, S, n_kv, dh))
+    logits = (logits / np.sqrt(dh)).astype(jnp.float32)
+    logits = jnp.where(jnp.broadcast_to(valid, (B, T, S))[:, None, None],
+                       logits, -1e30)
+    w = jax.nn.softmax(logits, axis=-1).astype(q.dtype)
+    if T == 1:
+        full = jnp.einsum("bgrs,bsj->bgrj", w[:, :, :, 0], ring_v)
+        out = jnp.einsum("bgrhd,gh->bgrd",
+                         full.reshape(B, n_kv, rep, n_kv, dh),
+                         jnp.eye(n_kv, dtype=full.dtype))[:, None]
+    else:
+        out = jnp.einsum("bgrts,bsgd->btgrd", w,
+                         ring_v.reshape(B, S, n_kv, dh))
+    return out.reshape(B, T, H * dh)
 
 
 def row_slice(leaf: jax.Array, slot) -> jax.Array:
@@ -470,8 +525,9 @@ def attention_decode(p: Params, cfg: AttnConfig, x: jax.Array,
                      cache: Params, cache_pos: jax.Array):
     """One-token decode against a ring KV cache.
 
-    x: (B, 1, D); cache = {"k","v"[,"ks","vs"]} with k/v (B, S, KV, dh)
-    (bf16 or int8+scales); cache_pos: () int32 — tokens already cached.
+    x: (B, 1, D); cache = {"k","v"[,"ks","vs"]} with k/v (B, S, KV * dh)
+    (bf16, or int8 with (B, S, KV) scales); cache_pos: () int32 — tokens
+    already cached.
     Returns (out, new_cache_dict).
     """
     B, _, _ = x.shape
@@ -484,8 +540,6 @@ def attention_decode(p: Params, cfg: AttnConfig, x: jax.Array,
     new_cache = {"k": ck, "v": cv}
     if ks is not None:
         new_cache["ks"], new_cache["vs"] = ks, vs
-    cache_k = _cache_read(ck, ks, q.dtype)
-    cache_v = _cache_read(cv, vs, q.dtype)
     # ring cache: slot s currently holds absolute position
     # pos - ((pos - s) mod S) (negative -> not yet written)
     ki = cache_pos - jnp.mod(cache_pos - jnp.arange(S), S)
@@ -494,21 +548,16 @@ def attention_decode(p: Params, cfg: AttnConfig, x: jax.Array,
         valid &= ki > cache_pos - cfg.sliding_window
     if cfg.chunk_size is not None:
         valid &= (ki // cfg.chunk_size) == (cache_pos // cfg.chunk_size)
-    H, KV, dh = cfg.n_heads, cfg.n_kv_heads, cfg.head_dim
-    rep = H // KV
-    qg = q.reshape(B, 1, KV, rep, dh)
-    logits = jnp.einsum("btgrd,bsgd->bgrts", qg, cache_k) / np.sqrt(dh)
-    logits = logits.astype(jnp.float32)
-    logits = jnp.where(valid[None, None, None, None, :], logits, -1e30)
-    w = jax.nn.softmax(logits, axis=-1).astype(q.dtype)
-    out = jnp.einsum("bgrts,bsgd->btgrd", w, cache_v)
-    out = out.reshape(B, 1, H * dh) @ p["wo"].astype(x.dtype)
-    return out, new_cache
+    out = _ring_attend(q, _cache_read(ck, ks, q.dtype),
+                       _cache_read(cv, vs, q.dtype), valid, cfg.n_kv_heads)
+    return out @ p["wo"].astype(x.dtype), new_cache
 
 
 def attention_decode_slots(p: Params, cfg: AttnConfig, x: jax.Array,
                            cache: Params, cache_pos: jax.Array,
-                           lengths: jax.Array | None = None):
+                           lengths: jax.Array | None = None,
+                           active: jax.Array | None = None,
+                           layer: jax.Array | None = None):
     """Decode/prefill against a ring KV cache with PER-ROW positions.
 
     The continuous-batching serving engine packs independent requests into
@@ -517,7 +566,8 @@ def attention_decode_slots(p: Params, cfg: AttnConfig, x: jax.Array,
     serves any mix of in-flight requests.
 
     x: (B, T, D) — T == 1 for a decode tick, T == the prompt bucket length
-    for slot prefill; cache_pos: (B,) int32 — tokens already cached per row.
+    for slot prefill; cache as in :func:`attention_decode`; cache_pos: (B,)
+    int32 — tokens already cached per row.
     Token t of row b is written at ring slot ``(cache_pos[b] + t) % S`` and
     attends causally to absolute positions ``<= cache_pos[b] + t``. Requires
     ``T <= S`` per CALL (otherwise one call would write a ring slot twice) —
@@ -532,38 +582,50 @@ def attention_decode_slots(p: Params, cfg: AttnConfig, x: jax.Array,
     never-valid slots, but a RESUMED chunk's bucket can wrap the ring past
     the row's earliest live position — an unsuppressed pad write there
     would clobber real prompt K/V that position arithmetic still reads as
-    valid.
+    valid. ``active`` (B,) bool suppresses every write of the rows where it
+    is False the same way.
+
+    ``layer`` (one-token decode): ``cache``'s leaves are the whole stacked
+    ``(n_periods, B, S, ...)`` rings and this call is layer ``layer`` of
+    them. Each row's new token is written in place at ``[layer, b,
+    slot_b]`` and the ring is read at ``layer``, so no layer's ring is
+    copied out or stacked again; the returned leaves are the whole stacked
+    rings.
     Returns (out (B, T, d_model), new_cache_dict).
     """
     B, T, _ = x.shape
-    S = cache["k"].shape[1]
+    S = cache["k"].shape[1 if layer is None else 2]
     positions = cache_pos[:, None] + jnp.arange(T, dtype=jnp.int32)[None]
     q, k, v = _qkv(p, cfg, x, positions)
     slots = jnp.mod(positions, S)                         # (B, T)
+    keep = (None if lengths is None else
+            (jnp.arange(T, dtype=jnp.int32)[None] < lengths[:, None]))
+    if active is not None:
+        keep = active[:, None] if keep is None else keep & active[:, None]
+
     brow = jnp.arange(B)[:, None]
-    tok_real = (None if lengths is None else
-                (jnp.arange(T, dtype=jnp.int32)[None] < lengths[:, None]))
+    at = (brow, slots) if layer is None else (layer, brow, slots)
+
+    def put(arr, val):
+        if keep is not None:
+            val = jnp.where(keep[..., None], val, arr[at])
+        return arr.at[at].set(val)
 
     def write(arr, scale, val):
         if arr.dtype == jnp.int8:
             qv, sv = quantize_kv_rows(val)
-            if tok_real is not None:
-                m = tok_real[..., None, None]
-                qv = jnp.where(m, qv, arr[brow, slots])
-                sv = jnp.where(m, sv, scale[brow, slots])
-            return arr.at[brow, slots].set(qv), scale.at[brow, slots].set(sv)
-        val = val.astype(arr.dtype)
-        if tok_real is not None:
-            val = jnp.where(tok_real[..., None, None], val, arr[brow, slots])
-        return arr.at[brow, slots].set(val), scale
+            return put(arr, _flat_kv(qv)), put(scale, _flat_kv(sv))
+        return put(arr, _flat_kv(val).astype(arr.dtype)), scale
 
     ck, ks = write(cache["k"], cache.get("ks"), k)
     cv, vs = write(cache["v"], cache.get("vs"), v)
     new_cache = {"k": ck, "v": cv}
     if ks is not None:
         new_cache["ks"], new_cache["vs"] = ks, vs
-    cache_k = _cache_read(ck, ks, q.dtype)
-    cache_v = _cache_read(cv, vs, q.dtype)
+    ring = (new_cache if layer is None else
+            jax.tree.map(lambda a: a[layer], new_cache))
+    cache_k = _cache_read(ring["k"], ring.get("ks"), q.dtype)
+    cache_v = _cache_read(ring["v"], ring.get("vs"), q.dtype)
     # ring cache: after this call's writes the newest absolute position in
     # row b is cache_pos[b] + T - 1 — or + lengths[b] - 1 when pad writes
     # are suppressed; slot s holds last - ((last - s) mod S)
@@ -577,16 +639,8 @@ def attention_decode_slots(p: Params, cfg: AttnConfig, x: jax.Array,
         valid &= ki[:, None, :] > qpos - cfg.sliding_window
     if cfg.chunk_size is not None:
         valid &= (ki[:, None, :] // cfg.chunk_size) == (qpos // cfg.chunk_size)
-    H, KV, dh = cfg.n_heads, cfg.n_kv_heads, cfg.head_dim
-    rep = H // KV
-    qg = q.reshape(B, T, KV, rep, dh)
-    logits = jnp.einsum("btgrd,bsgd->bgrts", qg, cache_k) / np.sqrt(dh)
-    logits = logits.astype(jnp.float32)
-    logits = jnp.where(valid[:, None, None], logits, -1e30)
-    w = jax.nn.softmax(logits, axis=-1).astype(q.dtype)
-    out = jnp.einsum("bgrts,bsgd->btgrd", w, cache_v)
-    out = out.reshape(B, T, H * dh) @ p["wo"].astype(x.dtype)
-    return out, new_cache
+    out = _ring_attend(q, cache_k, cache_v, valid, cfg.n_kv_heads)
+    return out @ p["wo"].astype(x.dtype), new_cache
 
 
 def ring_restore_mask(cache_pos: jax.Array, S: int, n_call: int,

@@ -348,7 +348,8 @@ def tp_param_specs(cfg: ModelConfig, axis: str = "tp") -> Params:
 def tp_cache_specs(cfg: ModelConfig, axis: str = "tp"):
     """PartitionSpec pytree matching :func:`init_cache`: attention K/V
     rings (and their int8 scales) shard the KV-head dim — dim 3 of the
-    stacked ``(n_periods, batch, S, KV, dh)`` layout — across the TP axis;
+    stacked ``(n_periods, batch, S, KV * dh)`` layout, whose heads lie in
+    contiguous blocks of ``dh`` — across the TP axis;
     position counters and recurrent-state caches are replicated (specs
     shorter than rank mean 'remaining dims replicated')."""
     kv = P(None, None, None, axis)
@@ -472,7 +473,8 @@ def _moe_dispatch(p, x, cfg: ModelConfig):
 # --------------------------------------------------------------------------
 
 def _apply_sub(sp: Params, s: SubSpec, cfg: ModelConfig, x, positions,
-               memory, cache, lengths=None, collect_states=False):
+               memory, cache, lengths=None, collect_states=False,
+               active=None, layer=None):
     """One sublayer; returns (x, aux_loss, new_cache).
 
     ``lengths`` (B,) activates the serving-prefill contract: the
@@ -488,6 +490,11 @@ def _apply_sub(sp: Params, s: SubSpec, cfg: ModelConfig, x, positions,
     final carry, so the verify step can commit the accepted length's state
     after scoring (see :func:`verify_step`). Attention is unaffected here —
     its rollback is a post-hoc ring restore (:func:`commit_verify_caches`).
+
+    ``active`` / ``layer`` (one-token slot decode): ``cache`` is the whole
+    stacked ring and this is its layer ``layer``; the new token is written
+    in place, and not at all on rows where ``active`` is False (see
+    :func:`repro.models.layers.attention_decode_slots`).
     """
     aux = jnp.zeros((), jnp.float32)
     if s.kind == "rwkv":
@@ -500,12 +507,14 @@ def _apply_sub(sp: Params, s: SubSpec, cfg: ModelConfig, x, positions,
     if s.kind == "attn":
         acfg = cfg.attn_cfg(s)
         if cache is not None:
-            # per-slot caches (pos is (B,), serving engine) take the
-            # scatter-write path; scalar pos keeps the original decode op
+            # per-slot caches (pos is (B,), serving engine) take the slot
+            # path (a decode tick writes into the whole stacked ring in
+            # place); scalar pos keeps the original decode op
             if cache["pos"].ndim:
-                o, kv = L.attention_decode_slots(sp, acfg, h, cache,
-                                                 cache["pos"],
-                                                 lengths=lengths)
+                pos = cache["pos"] if layer is None else cache["pos"][layer]
+                o, kv = L.attention_decode_slots(sp, acfg, h, cache, pos,
+                                                 lengths=lengths,
+                                                 active=active, layer=layer)
             else:
                 o, kv = L.attention_decode(sp, acfg, h, cache, cache["pos"])
             new_cache = {**kv, "pos": cache["pos"]}
@@ -534,9 +543,21 @@ def _apply_sub(sp: Params, s: SubSpec, cfg: ModelConfig, x, positions,
 
 
 def _run_stack(layer_params, pattern, cfg: ModelConfig, x, positions,
-               memory=None, caches=None, lengths=None, collect_states=False):
-    """Scan over periods; returns (x, aux_sum, new_caches)."""
+               memory=None, caches=None, lengths=None, collect_states=False,
+               active=None):
+    """Scan over periods; returns (x, aux_sum, new_caches).
+
+    ``active`` (B,) bool marks a one-token slot decode. Then every per-row
+    attention ring (:func:`_is_slot_ring`) rides the scan's carry whole,
+    and each layer writes its rows' one new token into it in place, rows
+    where ``active`` is False keeping theirs bit-unchanged: no layer's ring
+    is sliced out, copied, or stacked again as the scan's output. The other caches
+    (recurrent state: small, no sequence axis) still pass through as the
+    scan's xs/ys.
+    """
     decode = caches is not None
+    carried = tuple(active is not None and _is_slot_ring(c)
+                    for c in caches) if decode else ()
 
     # Per-SUBLAYER remat: a multi-layer pattern period (jamba's is 8 layers)
     # would otherwise keep every sublayer's backward intermediates live at
@@ -551,30 +572,49 @@ def _run_stack(layer_params, pattern, cfg: ModelConfig, x, positions,
                                 static_argnums=(1, 2))
 
     def body(carry, xs):
-        h, aux = carry
-        params_slice, cache_slice = xs
+        h, aux, rings = carry
+        params_slice, cache_slice, i = xs
+        rings = list(rings)
         new_cs = []
         ci = 0
         for pos, layer in enumerate(pattern):
             for si, s in enumerate(layer):
                 has_cache = decode and s.kind in ("attn", "mamba", "rwkv")
-                c = cache_slice[ci] if has_cache else None
+                c, kw = None, {}
+                if has_cache and carried[ci]:
+                    c, kw = rings[ci], {"active": active, "layer": i}
+                elif has_cache:
+                    c = cache_slice[ci]
                 h, a, nc = sub_fn(params_slice[pos][si], s, cfg, h,
-                                  positions, memory, c, lengths)
+                                  positions, memory, c, lengths, **kw)
                 aux = aux + a
                 if has_cache:
-                    new_cs.append(nc)
+                    if carried[ci]:
+                        rings[ci] = nc
+                    new_cs.append(None if carried[ci] else nc)
                     ci += 1
-        return (h, aux), tuple(new_cs) if decode else ()
+        return (h, aux, tuple(rings)), tuple(new_cs) if decode else ()
 
+    zero = jnp.zeros((), jnp.float32)
     if not decode:
-        fwd_body = lambda c, lp: body(c, (lp, None))
-        (x, aux), _ = jax.lax.scan(
-            fwd_body, (x, jnp.zeros((), jnp.float32)), layer_params)
+        fwd_body = lambda c, lp: body(c, (lp, None, None))
+        (x, aux, _), _ = jax.lax.scan(fwd_body, (x, zero, ()), layer_params)
         return x, aux, None
-    (x, aux), new_caches = jax.lax.scan(
-        body, (x, jnp.zeros((), jnp.float32)), (layer_params, caches))
+    rings = tuple(c if r else None for c, r in zip(caches, carried))
+    through = tuple(None if r else c for c, r in zip(caches, carried))
+    n = jax.tree.leaves(layer_params)[0].shape[0]
+    (x, aux, rings), ys = jax.lax.scan(
+        body, (x, zero, rings),
+        (layer_params, through, jnp.arange(n, dtype=jnp.int32)))
+    new_caches = tuple(r if c else y for r, y, c in zip(rings, ys, carried))
     return x, aux, new_caches
+
+
+def _is_slot_ring(cache) -> bool:
+    """A stacked attention ring with a position per batch row: the serving
+    engine's slots, as :func:`init_cache` builds them with ``per_slot``."""
+    return isinstance(cache, dict) and "pos" in cache \
+        and cache["pos"].ndim == 2
 
 
 def embed_inputs(params, cfg: ModelConfig, inputs) -> tuple:
@@ -665,7 +705,7 @@ def init_cache(cfg: ModelConfig, batch: int, max_len: int,
     With ``per_slot=True`` the attention position counters are per batch row
     (shape ``(batch,)`` instead of scalar): each row is an independently
     paced KV-cache *slot* for the continuous-batching serving engine, and
-    decode dispatches to the scatter-write slot path.
+    decode dispatches to the slot path.
 
     ``ring_slack`` widens window/chunk-BOUNDED rings (never full-attention
     ones) by that many slots. One-token decode never needs it: writing
@@ -691,14 +731,16 @@ def init_cache(cfg: ModelConfig, batch: int, max_len: int,
                 S = min(S, s.sliding_window + ring_slack)
             if s.chunk_size is not None:
                 S = min(S, s.chunk_size + ring_slack)
+            # a token's heads side by side: (S, KV * dh) is a layout the
+            # compiler stores unpadded and writes one token into in place
             if cfg.kv_quant:
-                return {"k": mk((batch, S, KV, dh), jnp.int8),
-                        "v": mk((batch, S, KV, dh), jnp.int8),
-                        "ks": mk((batch, S, KV, 1), jnp.float32),
-                        "vs": mk((batch, S, KV, 1), jnp.float32),
+                return {"k": mk((batch, S, KV * dh), jnp.int8),
+                        "v": mk((batch, S, KV * dh), jnp.int8),
+                        "ks": mk((batch, S, KV), jnp.float32),
+                        "vs": mk((batch, S, KV), jnp.float32),
                         "pos": mk(pos_shape, jnp.int32)}
-            return {"k": mk((batch, S, KV, dh), kv_dtype),
-                    "v": mk((batch, S, KV, dh), kv_dtype),
+            return {"k": mk((batch, S, KV * dh), kv_dtype),
+                    "v": mk((batch, S, KV * dh), kv_dtype),
                     "pos": mk(pos_shape, jnp.int32)}
         if s.kind == "mamba":
             spec = ssm.mamba_cache_spec(cfg.mamba_cfg(), batch,
@@ -750,14 +792,19 @@ def merge_cache_rows(new_caches, old_caches, active):
     what lets one jitted step serve any busy/free slot mix: inactive rows
     may compute garbage, but none of it survives the merge.
     """
+    return tuple(_merge_rows(nc, oc, active)
+                 for nc, oc in zip(new_caches, old_caches))
+
+
+def _merge_rows(new_cache, old_cache, active):
+    """:func:`merge_cache_rows` for one entry of the caches tuple."""
     def merge(new, old):
         if new.ndim < 2:
             return new
         m = active.reshape((1, -1) + (1,) * (new.ndim - 2))
         return jnp.where(m, new, old)
 
-    return tuple(jax.tree.map(merge, nc, oc)
-                 for nc, oc in zip(new_caches, old_caches))
+    return jax.tree.map(merge, new_cache, old_cache)
 
 
 def decode_step(params, cfg: ModelConfig, inputs, caches, memory=None,
@@ -767,18 +814,23 @@ def decode_step(params, cfg: ModelConfig, inputs, caches, memory=None,
 
     ``active`` (per-slot caches only): (B,) bool — rows whose slot currently
     holds an in-flight request. Inactive rows still compute (one jitted step
-    serves any slot mix) but their cache rows are merged back to their old
-    values and their position does NOT advance — for attention that merely
-    un-scribbles the write slot, for recurrent-state mixers it is what keeps
-    a free/prefilling slot's carried state intact across decode ticks.
+    serves any slot mix) but their cache rows keep their old values and
+    their position does NOT advance. Attention rings take each active row's
+    one new token in place and nothing on inactive rows (see
+    :func:`_run_stack`); recurrent-state rows are merged back to their old
+    values, which is what keeps a free/prefilling slot's carried state
+    intact across decode ticks.
     """
     x, _ = embed_inputs(params, cfg, inputs)
     x, _, new_caches = _run_stack(params["layers"], cfg.pattern, cfg, x,
-                                  None, memory, caches)
+                                  None, memory, caches, active=active)
     x = L.rmsnorm(params["final_norm"], x)
     logits = unembed(params, cfg, x)[:, -1]
     if active is not None:
-        new_caches = merge_cache_rows(new_caches, caches, active)
+        # the rings took only the active rows' writes inside the stack
+        new_caches = tuple(
+            nc if _is_slot_ring(oc) else _merge_rows(nc, oc, active)
+            for nc, oc in zip(new_caches, caches))
     return logits.astype(jnp.float32), advance_pos_stacked(new_caches, active)
 
 

@@ -75,3 +75,45 @@ def test_dptree_kernel_branch_compiles_in_checked_shard_map(topo,
     text = _compiled_text(fn, x)
     assert "tpu_custom_call" in text
     assert "collective-permute" in text
+
+
+def test_slot_decode_step_updates_the_cache_in_place(topo):
+    """The serving decode step, its caches donated, writes each row's new
+    token into the stacked K/V ring in place: its temporaries hold no
+    second cache (a whole-cache select or a restacked scan output would)."""
+    from repro.configs.base import ParallelConfig, ShapeSuite
+    from repro.launch import step_fns
+    from repro.launch.mesh import make_mesh
+    from repro.models import transformer as tf
+    # deep enough that the compiler keeps the layer loop a loop
+    cfg = tf.ModelConfig(name="inplace-aot", n_layers=40, d_model=256,
+                         n_heads=4, n_kv_heads=4, d_ff=512, vocab_size=512,
+                         compute_dtype=jnp.bfloat16,
+                         param_dtype=jnp.bfloat16, remat=False)
+    slots, max_len = 8, 512
+    mesh = make_mesh((1, 1), ("data", "model"), devices=topo.devices[:1])
+    step, sh = step_fns.make_serve_step(
+        cfg, ParallelConfig(), mesh, ShapeSuite("serve", max_len, slots,
+                                                "decode"), slots=True)
+
+    def shaped(tree, specs):
+        return jax.tree.map(lambda x, s: jax.ShapeDtypeStruct(
+            x.shape, x.dtype, sharding=NamedSharding(mesh, s)), tree, specs)
+
+    params = shaped(jax.eval_shape(
+        lambda: tf.init_params(jax.random.PRNGKey(0), cfg)), sh["params"])
+    caches = shaped(tf.init_cache(cfg, slots, max_len, abstract=True,
+                                  per_slot=True), sh["cache"])
+    one = NamedSharding(mesh, P())
+    compiled = jax.jit(
+        lambda p, t, c, a: step(p, {"tokens": t}, c, a),
+        donate_argnums=(2,)).lower(
+        params, jax.ShapeDtypeStruct((slots, 1), jnp.int32, sharding=one),
+        caches, jax.ShapeDtypeStruct((slots,), jnp.bool_,
+                                     sharding=one)).compile()
+    cache_bytes = sum(int(np.prod(c.shape)) * c.dtype.itemsize
+                      for c in jax.tree.leaves(caches))
+    mem = compiled.memory_analysis()
+    assert mem.alias_size_in_bytes >= cache_bytes
+    assert mem.temp_size_in_bytes < cache_bytes / 8, (
+        mem.temp_size_in_bytes, cache_bytes)
