@@ -303,7 +303,9 @@ def make_prefill_step(cfg, pcfg: ParallelConfig, mesh, suite: ShapeSuite,
       the engine consumes it (greedy rows are a bit-exact argmax; the
       sampled path derives its key from the request seed at step 0, see
       repro.serving.sampling). One compilation per (bucket Tc, resume)
-      pair; ``slot`` is traced, so slot churn never re-jits.
+      pair; ``slot`` is traced, so slot churn never re-jits. A model
+      with held-experts MoE layers gets (first-token, counts, caches):
+      their counts (``tf.MOE_COUNTS``) ride out with the token.
     """
     tps = _tp_setup(cfg, pcfg, mesh)
     pspecs = (tps.pspecs if tps is not None
@@ -311,6 +313,7 @@ def make_prefill_step(cfg, pcfg: ParallelConfig, mesh, suite: ShapeSuite,
               else model_pspecs(cfg, mesh))
     mcfg = tps.scfg if tps is not None else cfg
     dp = _dp_axes(mesh)
+    moe_counts = tf.moe_counted_layers(cfg) > 0
 
     if into_slots:
         from repro.serving.sampling import sample_tokens
@@ -332,29 +335,30 @@ def make_prefill_step(cfg, pcfg: ParallelConfig, mesh, suite: ShapeSuite,
                     row_in = tf.init_cache(mcfg, 1, suite.seq_len,
                                            per_slot=True,
                                            ring_slack=ring_slack)
-                logits, row = tf.prefill_step(
+                logits, row, *n = tf.prefill_step(
                     params, mcfg, {"tokens": tokens}, row_in,
-                    length.reshape(1), jnp.ones((1,), bool), resume=resume)
-            return logits, tf.adopt_prefix(caches, row, slot)
+                    length.reshape(1), jnp.ones((1,), bool), resume=resume,
+                    counts=moe_counts)
+            return logits, tuple(n), tf.adopt_prefix(caches, row, slot)
 
         # the bodies' names are their programs' names on the device
         # (``jit_prefill_step``, ``jit_prefill_step_sampled``)
         def prefill_step(params, tokens, caches, slot, length, resume):
-            logits, out = _prefill_fwd(params, tokens, caches, slot, length,
-                                       resume)
-            return jnp.argmax(logits).astype(jnp.int32), out
+            logits, n, out = _prefill_fwd(params, tokens, caches, slot,
+                                          length, resume)
+            return (jnp.argmax(logits).astype(jnp.int32),) + n + (out,)
 
         def prefill_step_sampled(params, tokens, caches, slot, length,
                                  sampling_row, resume):
-            logits, out = _prefill_fwd(params, tokens, caches, slot, length,
-                                       resume)
+            logits, n, out = _prefill_fwd(params, tokens, caches, slot,
+                                          length, resume)
             tok = sample_tokens(
                 logits.reshape(1, -1), sampling_row["key"][None],
                 jnp.zeros((1,), jnp.int32),            # first token: step 0
                 sampling_row["temperature"].reshape(1),
                 sampling_row["top_k"].reshape(1),
                 sampling_row["top_p"].reshape(1))[0]
-            return tok, out
+            return (tok,) + n + (out,)
 
         # greedy (the default) compiles without the sampler pipeline;
         # sampled variants compile lazily on first sampled admission.
@@ -362,6 +366,7 @@ def make_prefill_step(cfg, pcfg: ParallelConfig, mesh, suite: ShapeSuite,
         # draft-model drafter snapshots its caches before proposing and
         # restores them on rejection, which donation would invalidate.
         dn = (2,) if donate else ()
+        n_small = 1 + moe_counts          # the token (and the counts)
 
         def _mk(body, n_args):
             # TP: the whole cache-writing prefill (row slice/init, the
@@ -373,7 +378,7 @@ def make_prefill_step(cfg, pcfg: ParallelConfig, mesh, suite: ShapeSuite,
                 return body
             ins = (tps.pspecs, P(), tps.cspecs) + (P(),) * (n_args - 3)
             return shard_map(body, mesh=mesh, in_specs=ins,
-                             out_specs=(P(), tps.cspecs),
+                             out_specs=(P(),) * n_small + (tps.cspecs,),
                              axis_names=set(mesh.axis_names),
                              check_vma=False)
 
@@ -384,22 +389,20 @@ def make_prefill_step(cfg, pcfg: ParallelConfig, mesh, suite: ShapeSuite,
             fn.__name__ = body.__name__
             return fn
 
+        out_sh = ((NamedSharding(mesh, P()),) * n_small
+                  + (_named(mesh, cspecs),))
         jitted = {}
         for resume in (False, True):
             jitted[resume, False] = jax.jit(
                 _mk(_resumed(prefill_step, resume), 5),
                 in_shardings=(_named(mesh, pspecs), None,
                               _named(mesh, cspecs), None, None),
-                out_shardings=(NamedSharding(mesh, P()),
-                               _named(mesh, cspecs)),
-                donate_argnums=dn)
+                out_shardings=out_sh, donate_argnums=dn)
             jitted[resume, True] = jax.jit(
                 _mk(_resumed(prefill_step_sampled, resume), 6),
                 in_shardings=(_named(mesh, pspecs), None,
                               _named(mesh, cspecs), None, None, None),
-                out_shardings=(NamedSharding(mesh, P()),
-                               _named(mesh, cspecs)),
-                donate_argnums=dn)
+                out_shardings=out_sh, donate_argnums=dn)
 
         def step(params, tokens, caches, slot, length, *, resume=False,
                  sampling_row=None):
@@ -479,7 +482,9 @@ def make_serve_step(cfg, pcfg: ParallelConfig, mesh, suite: ShapeSuite,
     per-request seeded sampler through the jitted step: {key (B,2) u32,
     step (B,) i32, temperature (B,), top_k (B,), top_p (B,)}; rows with
     temperature 0 take the bit-exact greedy argmax
-    (see repro.serving.sampling).
+    (see repro.serving.sampling). For a model with held-experts MoE layers
+    the slot step returns (tokens, counts, new_caches): their counts
+    (``tf.MOE_COUNTS``) ride out with the tokens.
     """
     tps = _tp_setup(cfg, pcfg, mesh)
     mcfg = tps.scfg if tps is not None else cfg
@@ -495,6 +500,7 @@ def make_serve_step(cfg, pcfg: ParallelConfig, mesh, suite: ShapeSuite,
         and suite.global_batch >= n_dp
     bspec = P(dp if len(dp) > 1 else (dp[0] if dp else None)) \
         if shard_batch else P(None)
+    moe_counts = tf.moe_counted_layers(cfg) > 0
 
     if slots:
         from repro.serving.sampling import sample_tokens
@@ -511,21 +517,24 @@ def make_serve_step(cfg, pcfg: ParallelConfig, mesh, suite: ShapeSuite,
 
         # the bodies' names are their programs' names on the device
         # (``jit_decode_step``, ``jit_decode_step_sampled``)
-        def decode_step(params, inputs, caches, active):
+        def _forward(params, inputs, caches, active):
             with _tp_model_ctx(tps, mesh):
-                logits, new_caches = tf.decode_step(params, mcfg, inputs,
-                                                    caches, active=active)
+                logits, new_caches, *n = tf.decode_step(
+                    params, mcfg, inputs, caches, active=active,
+                    counts=moe_counts)
+            return logits, tuple(n), new_caches
+
+        def decode_step(params, inputs, caches, active):
+            logits, n, new_caches = _forward(params, inputs, caches, active)
             tok = jnp.argmax(logits, axis=-1).astype(jnp.int32)
-            return _guard(logits, tok), new_caches
+            return (_guard(logits, tok),) + n + (new_caches,)
 
         def decode_step_sampled(params, inputs, caches, active, sampling):
-            with _tp_model_ctx(tps, mesh):
-                logits, new_caches = tf.decode_step(params, mcfg, inputs,
-                                                    caches, active=active)
+            logits, n, new_caches = _forward(params, inputs, caches, active)
             tokens = sample_tokens(logits, sampling["key"], sampling["step"],
                                    sampling["temperature"],
                                    sampling["top_k"], sampling["top_p"])
-            return _guard(logits, tokens), new_caches
+            return (_guard(logits, tokens),) + n + (new_caches,)
 
         def _mk(body, n_args):
             # TP decode tick: one fully-manual shard_map per body — each
@@ -536,7 +545,8 @@ def make_serve_step(cfg, pcfg: ParallelConfig, mesh, suite: ShapeSuite,
                 return body
             ins = (tps.pspecs, P(), tps.cspecs) + (P(),) * (n_args - 3)
             return shard_map(body, mesh=mesh, in_specs=ins,
-                             out_specs=(P(), tps.cspecs),
+                             out_specs=(P(),) * (1 + moe_counts)
+                             + (tps.cspecs,),
                              axis_names=set(mesh.axis_names),
                              check_vma=False)
 
@@ -545,7 +555,9 @@ def make_serve_step(cfg, pcfg: ParallelConfig, mesh, suite: ShapeSuite,
         # the sampler pipeline compiles only into the sampled variant,
         # whose greedy rows still take the identical argmax inside
         # sample_tokens, so mixing policies never changes greedy streams
-        out_sh = (NamedSharding(mesh, bspec), _named(mesh, cspecs))
+        out_sh = ((NamedSharding(mesh, bspec),)
+                  + (NamedSharding(mesh, P()),) * moe_counts
+                  + (_named(mesh, cspecs),))
         dn = (2,) if donate else ()       # see make_prefill_step on donate
         greedy_step = jax.jit(
             _mk(decode_step, 4),
@@ -622,7 +634,8 @@ def make_verify_step(cfg, pcfg: ParallelConfig, mesh, suite: ShapeSuite,
     Compiled once per draft budget K = ``draft_k`` (the adaptive controller
     varies the per-request k *within* K via ``n_draft``, never re-jitting).
     ``ring_slack`` must match the caches' (window/chunk-bounded rings need
-    ``ring_slack >= draft_k`` — see ``init_cache``).
+    ``ring_slack >= draft_k`` — see ``init_cache``). A model with
+    held-experts MoE layers gets (emitted, accept, counts, new_caches).
     """
     from repro.serving.sampling import sample_tokens_block
     tps = _tp_setup(cfg, pcfg, mesh)
@@ -634,6 +647,7 @@ def make_verify_step(cfg, pcfg: ParallelConfig, mesh, suite: ShapeSuite,
               else cache_pspecs(cfg, mesh, suite.global_batch, suite.seq_len,
                                 per_slot=True, ring_slack=ring_slack))
     T = draft_k + 1
+    moe_counts = tf.moe_counted_layers(cfg) > 0
 
     def _verify(params, tokens, caches, active, n_draft, pred_fn):
         with _tp_model_ctx(tps, mesh):
@@ -641,13 +655,14 @@ def make_verify_step(cfg, pcfg: ParallelConfig, mesh, suite: ShapeSuite,
             # lengths= machinery keeps their ring writes suppressed (a pad
             # write can wrap over live K/V near ring capacity)
             lengths = jnp.clip(n_draft, 0, T - 1).astype(jnp.int32) + 1
-            logits, raw = tf.verify_forward(params, mcfg, {"tokens": tokens},
-                                            caches, lengths=lengths)
+            logits, raw, *n = tf.verify_forward(
+                params, mcfg, {"tokens": tokens}, caches, lengths=lengths,
+                counts=moe_counts)
             pred = pred_fn(logits)                             # (B, T) int32
             emitted, accept = tf.verify_accept(pred, tokens, n_draft)
             new_caches = tf.commit_verify_caches(raw, caches, T, accept,
                                                  active)
-        return emitted, accept, new_caches
+        return (emitted, accept) + tuple(n) + (new_caches,)
 
     def _vguard(lg, pred):
         # same non-finite-logits sentinel as the decode step: a poisoned
@@ -679,13 +694,14 @@ def make_verify_step(cfg, pcfg: ParallelConfig, mesh, suite: ShapeSuite,
             return body
         ins = (tps.pspecs, P(), tps.cspecs) + (P(),) * (n_args - 3)
         return shard_map(body, mesh=mesh, in_specs=ins,
-                         out_specs=(P(), P(), tps.cspecs),
+                         out_specs=(P(),) * (2 + moe_counts)
+                         + (tps.cspecs,),
                          axis_names=set(mesh.axis_names), check_vma=False)
 
     # the same greedy/sampled split as make_serve_step: the default path
     # never compiles the sampler's full-vocab sorts
-    out_sh = (NamedSharding(mesh, P()), NamedSharding(mesh, P()),
-              _named(mesh, cspecs))
+    out_sh = ((NamedSharding(mesh, P()),) * (2 + moe_counts)
+              + (_named(mesh, cspecs),))
     greedy_step = jax.jit(
         _mk(verify_step, 5),
         in_shardings=(_named(mesh, pspecs), None, _named(mesh, cspecs),

@@ -456,6 +456,9 @@ def _cache_read(cache_arr, scale_arr, dtype):
     return cache_arr.astype(dtype)
 
 
+RING_QUERY_BLOCK = 512
+
+
 def _ring_attend(q: jax.Array, ring_k: jax.Array, ring_v: jax.Array,
                  valid: jax.Array, n_kv: int) -> jax.Array:
     """Attention of queries ``q`` (B, T, H, dh) over rings stored as
@@ -470,9 +473,21 @@ def _ring_attend(q: jax.Array, ring_k: jax.Array, ring_v: jax.Array,
     instead makes the compiler relay the ring out, a copy of the whole
     cache every step. Several queries per row (prefill, verify) take that
     per-head view: there the extra work of the block-diagonal form would
-    grow with the queries."""
+    grow with the queries. More than ``RING_QUERY_BLOCK * 2`` queries (a
+    long prompt's prefill) go through in blocks of ``RING_QUERY_BLOCK``,
+    so that the scores of one block at a time are held: a 4096-token
+    prompt's whole (H, T, S) float32 scores would take 2 GB."""
     B, T, H, dh = q.shape
     S = ring_k.shape[1]
+    if T > 2 * RING_QUERY_BLOCK and T % RING_QUERY_BLOCK == 0:
+        n = T // RING_QUERY_BLOCK
+        blocks = (q.reshape(B, n, RING_QUERY_BLOCK, H, dh),
+                  jnp.broadcast_to(valid, (B, T, S)).reshape(
+                      B, n, RING_QUERY_BLOCK, S))
+        out = jax.lax.map(
+            lambda qv: _ring_attend(qv[0], ring_k, ring_v, qv[1], n_kv),
+            jax.tree.map(lambda a: jnp.swapaxes(a, 0, 1), blocks))
+        return jnp.swapaxes(out, 0, 1).reshape(B, T, H * dh)
     rep = H // n_kv
     qg = q.reshape(B, T, n_kv, rep, dh)
     if T == 1:
@@ -753,20 +768,23 @@ def mlp(p: Params, x: jax.Array, activation: str) -> jax.Array:
 
 
 # --------------------------------------------------------------------------
-# Mixture of Experts (dense dispatch, top-k routing)
+# Mixture of Experts parameters (the layers are in models/transformer.py)
 # --------------------------------------------------------------------------
 
 def moe_init(key, d_model: int, d_ff: int, n_experts: int, gated: bool,
-             dtype=jnp.float32) -> Params:
+             dtype=jnp.float32, n_held: int | None = None) -> Params:
+    """Router over all ``n_experts``; expert weights for the ``n_held`` this
+    chip holds (all of them when None)."""
     ks = jax.random.split(key, 4)
+    E = n_experts if n_held is None else n_held
     p = {
         "router": dense_init(ks[0], (d_model, n_experts), scale=0.02,
                              dtype=jnp.float32),
-        "w_in": dense_init(ks[1], (n_experts, d_model, d_ff), dtype=dtype),
-        "w_out": dense_init(ks[2], (n_experts, d_ff, d_model), dtype=dtype),
+        "w_in": dense_init(ks[1], (E, d_model, d_ff), dtype=dtype),
+        "w_out": dense_init(ks[2], (E, d_ff, d_model), dtype=dtype),
     }
     if gated:
-        p["w_gate"] = dense_init(ks[3], (n_experts, d_model, d_ff), dtype=dtype)
+        p["w_gate"] = dense_init(ks[3], (E, d_model, d_ff), dtype=dtype)
     return p
 
 # Experts shard d_ff over 'model' (always divisible — expert counts like
@@ -775,33 +793,3 @@ def moe_init(key, d_model: int, d_ff: int, n_experts: int, gated: bool,
 MOE_SPECS = {"router": P(None, None),
              "w_in": P(None, None, "model"), "w_out": P(None, "model", None),
              "w_gate": P(None, None, "model")}
-
-
-def moe(p: Params, x: jax.Array, top_k: int, activation: str) -> jax.Array:
-    """Dense-dispatch MoE (Mesh-TensorFlow style): every expert sees every
-    token with a (possibly zero) combine weight. MXU-friendly, shards experts
-    over the 'model' axis, and avoids dynamic shapes on TPU.
-    """
-    B, T, D = x.shape
-    E = p["router"].shape[1]
-    logits = (x.astype(jnp.float32) @ p["router"])           # (B,T,E)
-    topv, topi = jax.lax.top_k(logits, top_k)
-    gates = jax.nn.softmax(topv, axis=-1)                    # (B,T,k)
-    # scatter the k gates back to a dense (B,T,E) combine matrix
-    comb = jnp.zeros((B, T, E), jnp.float32)
-    onehot = jax.nn.one_hot(topi, E, dtype=jnp.float32)      # (B,T,k,E)
-    comb = jnp.einsum("btk,btke->bte", gates, onehot).astype(x.dtype)
-    h = jnp.einsum("btd,edf->btef", x, p["w_in"].astype(x.dtype))
-    if "w_gate" in p:
-        g = jnp.einsum("btd,edf->btef", x, p["w_gate"].astype(x.dtype))
-        h = act_fn(activation)(g) * h
-    else:
-        h = act_fn(activation)(h)
-    h = maybe_shard(h, P(None, None, "model", None))
-    y = jnp.einsum("btef,efd->bted", h, p["w_out"].astype(x.dtype))
-    out = jnp.einsum("bted,bte->btd", y, comb)
-    # auxiliary load-balancing loss (Switch-style), returned via side channel
-    me = jnp.mean(jax.nn.softmax(logits, -1), axis=(0, 1))
-    ce = jnp.mean(jnp.sum(onehot, axis=2), axis=(0, 1))
-    aux = E * jnp.sum(me * ce)
-    return out, aux

@@ -333,6 +333,10 @@ def mamba_init(key, cfg: MambaConfig, dtype=jnp.float32) -> Params:
         "A_log": jnp.log(A),
         "D_skip": jnp.ones((Di,), jnp.float32),
         "out_proj": dense_init(ks[4], (Di, D), dtype=dtype),
+        # Jamba's RMS norms of dt, B and C ahead of dt_proj and the scan
+        "dt_norm": rmsnorm_init(R),
+        "b_norm": rmsnorm_init(N),
+        "c_norm": rmsnorm_init(N),
     }
 
 MAMBA_SPECS = {"in_proj": P(None, "model"), "conv_w": P(None, "model"),
@@ -345,10 +349,15 @@ def _mamba_inner(p, xin, cfg: MambaConfig):
 
     Returns the COMPACT selective-SSM inputs (dt, dt*x, B, C) — the rank-4
     ``a_log``/``bx`` tensors are (d_state x) larger and are built per-chunk
-    inside the scan instead of being materialized over the whole sequence."""
+    inside the scan instead of being materialized over the whole sequence.
+    As in Jamba, the x_proj outputs dt (``dt_rank`` wide), B and C
+    (``d_state`` each) are RMS-normed (eps 1e-6) before ``dt_proj`` and
+    the scan."""
     R, N = cfg.dt_rank, cfg.d_state
     proj = xin @ p["x_proj"].astype(xin.dtype)
     dt, Bc, Cc = jnp.split(proj.astype(jnp.float32), [R, R + N], axis=-1)
+    dt = rmsnorm(p["dt_norm"], dt)
+    Bc, Cc = rmsnorm(p["b_norm"], Bc), rmsnorm(p["c_norm"], Cc)
     dt = jax.nn.softplus(dt @ p["dt_proj"].astype(jnp.float32) + p["dt_bias"])
     return dt, dt * xin.astype(jnp.float32), Bc, Cc
 
@@ -423,6 +432,9 @@ def mamba_scan_recurrent(dt, dtx, Bc, C, A, state, valid=None,
     return jnp.moveaxis(ys, 0, 1), state
 
 
+MAMBA_TIME_BLOCK = 1024
+
+
 def mamba_block(p: Params, x: jax.Array, cfg: MambaConfig,
                 cache: Params | None = None,
                 lengths: jax.Array | None = None,
@@ -442,9 +454,29 @@ def mamba_block(p: Params, x: jax.Array, cfg: MambaConfig,
     ending at token ``t``; ``ssm``: (B,T,Di,N) — so the caller can commit
     the accepted length's state after scoring. Composes with ``lengths``
     (verify-buffer padding) as in :func:`rwkv_block`.
+
+    A prompt longer than ``2 * MAMBA_TIME_BLOCK`` goes through in blocks
+    of ``MAMBA_TIME_BLOCK`` tokens, the state carried from block to block
+    exactly as chunked admission carries it from call to call: one block's
+    float32 scan inputs are held at a time, not the whole prompt's.
     """
     B, T, D = x.shape
     Di, N, Kc = cfg.d_inner, cfg.d_state, cfg.d_conv
+    if (lengths is not None and not collect_states
+            and T > 2 * MAMBA_TIME_BLOCK and T % MAMBA_TIME_BLOCK == 0):
+        n, tb = T // MAMBA_TIME_BLOCK, MAMBA_TIME_BLOCK
+        if cache is None:
+            cache = jax.tree.map(
+                lambda sd: jnp.zeros(sd.shape, sd.dtype),
+                mamba_cache_spec(cfg, B, x.dtype))
+        def block(c, i):
+            xb = jax.lax.dynamic_slice_in_dim(x, i * tb, tb, axis=1)
+            lb = jnp.clip(lengths - i * tb, 0, tb).astype(lengths.dtype)
+            out, c = mamba_block(p, xb, cfg, c, lengths=lb)
+            return c, out
+
+        cache, outs = jax.lax.scan(block, cache, jnp.arange(n))
+        return jnp.swapaxes(outs, 0, 1).reshape(B, T, D), cache
     zx = x @ p["in_proj"].astype(x.dtype)
     z, xin = jnp.split(zx, 2, axis=-1)
     # depthwise causal conv1d

@@ -37,10 +37,48 @@ from repro.models.layers import Params, maybe_shard
 
 @dataclasses.dataclass(frozen=True)
 class MoESettings:
+    """Routing and layout of a sparse expert layer.
+
+    ``impl``: ``'dispatch'`` (sort-based, capacity ``capacity_factor``:
+    tokens that overflow an expert are dropped), ``'masked'`` (every
+    expert sees every token) or ``'grouped'`` (the held-experts layer: each
+    (token, expert) pair routed to a held expert goes through one grouped
+    matmul, none dropped; see :func:`_moe_grouped`).
+
+    ``gate``: ``'topk_softmax'`` takes a softmax over the top-k router
+    logits (Mixtral's renormalised rule); ``'softmax_topk'`` takes a
+    softmax over all ``n_experts`` logits, then the top k of those
+    probabilities, not renormalised (Jamba's).
+
+    ``held``: ``(first, count)``, the experts this chip holds
+    (``'grouped'`` only): the router keeps all ``n_experts`` outputs, the
+    expert weights are ``(count, ...)``, and the layer returns only those
+    experts' share of the result. None holds them all.
+    """
     n_experts: int
     top_k: int
     capacity_factor: float = 1.25
-    impl: str = "dispatch"          # 'dispatch' (sort-based) | 'masked'
+    impl: str = "dispatch"
+    gate: str = "topk_softmax"
+    held: tuple | None = None
+
+    def __post_init__(self):
+        if self.impl not in ("dispatch", "masked", "grouped"):
+            raise ValueError(f"unknown MoE impl {self.impl!r}")
+        if self.gate not in ("topk_softmax", "softmax_topk"):
+            raise ValueError(f"unknown MoE gate rule {self.gate!r}")
+        if self.held is not None:
+            first, count = self.held
+            if self.impl != "grouped":
+                raise ValueError("held experts need impl='grouped'")
+            if not (0 <= first and count >= 1
+                    and first + count <= self.n_experts):
+                raise ValueError(f"held {self.held} outside the "
+                                 f"{self.n_experts} experts")
+
+    @property
+    def held_range(self) -> tuple:
+        return self.held if self.held is not None else (0, self.n_experts)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -163,7 +201,7 @@ def _sub_init(key, cfg: ModelConfig, s: SubSpec) -> Params:
         m = cfg.moe
         return {"norm": L.rmsnorm_init(cfg.d_model),
                 **L.moe_init(key, cfg.d_model, cfg.d_ff, m.n_experts,
-                             cfg.gated_mlp, dt)}
+                             cfg.gated_mlp, dt, n_held=m.held_range[1])}
     if s.kind == "mamba":
         return {"norm": L.rmsnorm_init(cfg.d_model),
                 **ssm.mamba_init(key, cfg.mamba_cfg(), dt)}
@@ -370,15 +408,30 @@ def tp_cache_specs(cfg: ModelConfig, axis: str = "tp"):
 # MoE implementations
 # --------------------------------------------------------------------------
 
+def moe_route(logits, m: MoESettings) -> tuple:
+    """``(gates, experts)``, each ``(..., top_k)``, from router logits by
+    the configuration's gate rule (see :class:`MoESettings`)."""
+    if m.gate == "softmax_topk":
+        return jax.lax.top_k(jax.nn.softmax(logits, axis=-1), m.top_k)
+    topv, topi = jax.lax.top_k(logits, m.top_k)
+    return jax.nn.softmax(topv, axis=-1), topi
+
+
+def _balance_loss(logits, topi, E: int):
+    """Switch-style auxiliary load-balancing loss."""
+    onehot = jax.nn.one_hot(topi, E, dtype=jnp.float32)
+    me = jnp.mean(jax.nn.softmax(logits, -1), axis=(0, 1))
+    ce_frac = jnp.mean(jnp.sum(onehot, axis=2), axis=(0, 1))
+    return E * jnp.sum(me * ce_frac)
+
+
 def _moe_masked(p, x, cfg: ModelConfig):
     """Loop-over-experts with combine masking: simple, compile-safe, E/k x
     FLOP overhead (the §Perf baseline)."""
     m = cfg.moe
-    B, T, D = x.shape
     E = m.n_experts
     logits = x.astype(jnp.float32) @ p["router"].astype(jnp.float32)
-    topv, topi = jax.lax.top_k(logits, m.top_k)
-    gates = jax.nn.softmax(topv, axis=-1)
+    gates, topi = moe_route(logits, m)
     onehot = jax.nn.one_hot(topi, E, dtype=jnp.float32)
     comb = jnp.einsum("btk,btke->bte", gates, onehot)
 
@@ -402,10 +455,7 @@ def _moe_masked(p, x, cfg: ModelConfig):
             lambda c, s: expert(c, (s[0], s[1], None, s[3])), jnp.zeros_like(x), xs)
     else:
         acc, _ = jax.lax.scan(lambda c, s: expert(c, s), jnp.zeros_like(x), xs)
-    me = jnp.mean(jax.nn.softmax(logits, -1), axis=(0, 1))
-    ce_frac = jnp.mean(jnp.sum(onehot, axis=2), axis=(0, 1))
-    aux = E * jnp.sum(me * ce_frac)
-    return acc, aux
+    return acc, _balance_loss(logits, topi, E)
 
 
 def _moe_dispatch(p, x, cfg: ModelConfig):
@@ -420,8 +470,7 @@ def _moe_dispatch(p, x, cfg: ModelConfig):
     E, k = m.n_experts, m.top_k
     C = int(np.ceil(T * k * m.capacity_factor / E))
     logits = x.astype(jnp.float32) @ p["router"].astype(jnp.float32)
-    topv, topi = jax.lax.top_k(logits, k)               # (B,T,k)
-    gates = jax.nn.softmax(topv, axis=-1)
+    gates, topi = moe_route(logits, m)                  # (B,T,k)
 
     eid = topi.reshape(B, T * k)
     gat = gates.reshape(B, T * k)
@@ -460,12 +509,85 @@ def _moe_dispatch(p, x, cfg: ModelConfig):
     sg = jnp.take_along_axis(gat, order, axis=1)
     contrib = yg * (sg * keep)[..., None].astype(y.dtype)
     out = jnp.zeros_like(x).at[jnp.arange(B)[:, None], tok].add(contrib)
+    return out, _balance_loss(logits, topi, E)
 
-    onehot = jax.nn.one_hot(topi, E, dtype=jnp.float32)
-    me = jnp.mean(jax.nn.softmax(logits, -1), axis=(0, 1))
-    ce_frac = jnp.mean(jnp.sum(onehot, axis=2), axis=(0, 1))
-    aux = E * jnp.sum(me * ce_frac)
-    return out, aux
+
+# Per held-experts layer and step, the counts the layer reports (see
+# :func:`_moe_grouped`), in this order.
+MOE_COUNTS = ("experts_touched", "expert_tokens", "expert_tokens_max")
+
+# Rows of (token, expert) pairs the held-experts layer runs at once: a
+# 4096-token prompt's 8192 pairs at once hold three (8192, d_ff) bf16
+# intermediates, 0.7 GB at Jamba's widths.
+MOE_ROW_BLOCK = 2048
+
+
+def _moe_grouped(p, x, cfg: ModelConfig, valid=None):
+    """The held-experts layer: drop-free, and only the held experts' share.
+
+    The router scores all ``n_experts`` and the gate rule picks each
+    token's top k. Every (token, expert) pair whose expert this chip holds
+    (``cfg.moe.held``) is sorted by expert, and the rows go through one
+    grouped matmul per weight (``jax.lax.ragged_dot``; on a TPU XLA's
+    ``ragged-dot`` kernels, which name the ops in a device trace): work
+    follows the routed pairs, with no capacity, no padding and no token
+    dropped. A pair of an expert held elsewhere, or of a token that
+    ``valid`` (B, T) marks as not real (a pad, an idle slot), joins no
+    group and adds nothing. Each token's result is the gate-weighted sum
+    of its held experts' outputs, so the shares of all chips add up to the
+    whole layer.
+
+    Returns ``(out, aux, counts)``: ``counts`` int32 ``(3,)`` as
+    :data:`MOE_COUNTS` names them: held experts that received a pair,
+    pairs routed to held experts, and the most any held expert received.
+    """
+    m = cfg.moe
+    B, T, D = x.shape
+    first, C = m.held_range
+    k, N = m.top_k, B * T
+    logits = x.astype(jnp.float32) @ p["router"].astype(jnp.float32)
+    gates, experts = moe_route(logits, m)               # (B, T, k)
+    local = experts - first
+    mine = (local >= 0) & (local < C)
+    if valid is not None:
+        mine = mine & valid[..., None]
+    group = jnp.where(mine, local, C).reshape(N * k)    # C: no group
+    order = jnp.argsort(group, stable=True)
+    sizes = jnp.bincount(group, length=C + 1)[:C].astype(jnp.int32)
+    rows = x.reshape(N, D)[order // k]                  # (N k, D)
+    act = L.act_fn(cfg.activation)
+
+    def experts_ffn(rows, sizes):
+        h = jax.lax.ragged_dot(rows, p["w_in"].astype(x.dtype), sizes)
+        if "w_gate" in p:
+            h = act(jax.lax.ragged_dot(rows, p["w_gate"].astype(x.dtype),
+                                       sizes)) * h
+        else:
+            h = act(h)
+        return jax.lax.ragged_dot(h, p["w_out"].astype(x.dtype), sizes)
+
+    M, R = N * k, MOE_ROW_BLOCK
+    if M > R and M % R == 0:
+        # a long prompt's rows in blocks, each with its share of every
+        # group: the rows are sorted, so a block touches few experts and
+        # each expert's weights are read about once in all
+        ends = jnp.cumsum(sizes)
+        lo = jnp.arange(0, M, R, dtype=jnp.int32)[:, None]
+        per = (jnp.clip(ends[None] - lo, 0, R)
+               - jnp.clip(ends[None] - sizes[None] - lo, 0, R))
+        y = jax.lax.map(lambda a: experts_ffn(*a),
+                        (rows.reshape(M // R, R, D), per)).reshape(M, D)
+    else:
+        y = experts_ffn(rows, sizes)
+    # rows past the last group belong to none: the kernel leaves them be
+    y = jnp.where((jnp.arange(M) < sizes.sum())[:, None], y, 0)
+    y = jnp.zeros_like(y).at[order].set(y).reshape(N, k, D)
+    w = jnp.where(mine, gates, 0.0).reshape(N, k, 1)
+    out = jnp.sum(y.astype(jnp.float32) * w, axis=1).astype(x.dtype)
+    counts = jnp.stack([jnp.sum(sizes > 0), sizes.sum(),
+                        sizes.max()]).astype(jnp.int32)
+    return (out.reshape(B, T, D), _balance_loss(logits, experts,
+                                                m.n_experts), counts)
 
 
 # --------------------------------------------------------------------------
@@ -494,14 +616,20 @@ def _apply_sub(sp: Params, s: SubSpec, cfg: ModelConfig, x, positions,
     ``active`` / ``layer`` (one-token slot decode): ``cache`` is the whole
     stacked ring and this is its layer ``layer``; the new token is written
     in place, and not at all on rows where ``active`` is False (see
-    :func:`repro.models.layers.attention_decode_slots`).
+    :func:`repro.models.layers.attention_decode_slots`). The held-experts
+    MoE routes no pair of a row where ``active`` is False, nor of a token
+    at or past ``lengths``.
+
+    Returns ``(x, aux, new_cache, counts)``; ``counts`` is the held-experts
+    layer's (see :data:`MOE_COUNTS`), None for every other sublayer.
     """
     aux = jnp.zeros((), jnp.float32)
+    counts = None
     if s.kind == "rwkv":
         x, new_cache = ssm.rwkv_block(sp, x, cfg.rwkv_cfg(), cache,
                                       lengths=lengths,
                                       collect_states=collect_states)
-        return x, aux, new_cache
+        return x, aux, new_cache, counts
     h = L.rmsnorm(sp["norm"], x)
     new_cache = cache
     if s.kind == "attn":
@@ -524,6 +652,14 @@ def _apply_sub(sp: Params, s: SubSpec, cfg: ModelConfig, x, positions,
         o = L.cross_attention(sp, cfg.attn_cfg(s), h, memory)
     elif s.kind == "mlp":
         o = L.mlp(sp, h, cfg.activation)
+    elif s.kind == "moe" and cfg.moe.impl == "grouped":
+        valid = None
+        if active is not None:
+            valid = jnp.broadcast_to(active[:, None], h.shape[:2])
+        elif lengths is not None:
+            valid = (jnp.arange(h.shape[1], dtype=jnp.int32)[None]
+                     < lengths[:, None])
+        o, aux, counts = _moe_grouped(sp, h, cfg, valid)
     elif s.kind == "moe":
         fn = _moe_dispatch if cfg.moe.impl == "dispatch" else _moe_masked
         o, aux = fn(sp, h, cfg)
@@ -539,13 +675,17 @@ def _apply_sub(sp: Params, s: SubSpec, cfg: ModelConfig, x, positions,
         # the per-token allreduce. No-op outside a ``L.tp_ctx``. The
         # recurrent mixers (mamba/rwkv) are replicated per rank and skip it.
         o = L.tp_all_reduce(o)
-    return x + o, aux, new_cache
+    return x + o, aux, new_cache, counts
 
 
 def _run_stack(layer_params, pattern, cfg: ModelConfig, x, positions,
                memory=None, caches=None, lengths=None, collect_states=False,
                active=None):
-    """Scan over periods; returns (x, aux_sum, new_caches).
+    """Scan over periods; returns (x, aux_sum, new_caches, moe_counts).
+
+    ``moe_counts`` is int32 ``(n_layers_moe, 3)``, one row per held-experts
+    MoE layer in depth order (:data:`MOE_COUNTS`), or None when the stack
+    has none.
 
     ``active`` (B,) bool marks a one-token slot decode. Then every per-row
     attention ring (:func:`_is_slot_ring`) rides the scan's carry whole,
@@ -575,7 +715,7 @@ def _run_stack(layer_params, pattern, cfg: ModelConfig, x, positions,
         h, aux, rings = carry
         params_slice, cache_slice, i = xs
         rings = list(rings)
-        new_cs = []
+        new_cs, counts = [], []
         ci = 0
         for pos, layer in enumerate(pattern):
             for si, s in enumerate(layer):
@@ -585,29 +725,40 @@ def _run_stack(layer_params, pattern, cfg: ModelConfig, x, positions,
                     c, kw = rings[ci], {"active": active, "layer": i}
                 elif has_cache:
                     c = cache_slice[ci]
-                h, a, nc = sub_fn(params_slice[pos][si], s, cfg, h,
-                                  positions, memory, c, lengths, **kw)
+                elif s.kind == "moe":
+                    kw = {"active": active}
+                h, a, nc, n = sub_fn(params_slice[pos][si], s, cfg, h,
+                                     positions, memory, c, lengths, **kw)
                 aux = aux + a
+                if n is not None:
+                    counts.append(n)
                 if has_cache:
                     if carried[ci]:
                         rings[ci] = nc
                     new_cs.append(None if carried[ci] else nc)
                     ci += 1
-        return (h, aux, tuple(rings)), tuple(new_cs) if decode else ()
+        counts = jnp.stack(counts) if counts else None
+        return (h, aux, tuple(rings)), (tuple(new_cs) if decode else (),
+                                        counts)
+
+    def per_layer(counts):
+        # (n_periods, per period, 3) -> depth order
+        return None if counts is None else counts.reshape(-1, 3)
 
     zero = jnp.zeros((), jnp.float32)
     if not decode:
         fwd_body = lambda c, lp: body(c, (lp, None, None))
-        (x, aux, _), _ = jax.lax.scan(fwd_body, (x, zero, ()), layer_params)
-        return x, aux, None
+        (x, aux, _), (_, counts) = jax.lax.scan(fwd_body, (x, zero, ()),
+                                                layer_params)
+        return x, aux, None, per_layer(counts)
     rings = tuple(c if r else None for c, r in zip(caches, carried))
     through = tuple(None if r else c for c, r in zip(caches, carried))
     n = jax.tree.leaves(layer_params)[0].shape[0]
-    (x, aux, rings), ys = jax.lax.scan(
+    (x, aux, rings), (ys, counts) = jax.lax.scan(
         body, (x, zero, rings),
         (layer_params, through, jnp.arange(n, dtype=jnp.int32)))
     new_caches = tuple(r if c else y for r, y, c in zip(rings, ys, carried))
-    return x, aux, new_caches
+    return x, aux, new_caches, per_layer(counts)
 
 
 def _is_slot_ring(cache) -> bool:
@@ -641,11 +792,11 @@ def forward(params, cfg: ModelConfig, inputs) -> tuple:
         src = inputs["src_embeds"].astype(cfg.compute_dtype)
         sp = jnp.broadcast_to(
             jnp.arange(src.shape[1], dtype=jnp.int32)[None], src.shape[:2])
-        memory, _, _ = _run_stack(params["enc_layers"], cfg.enc_pattern, cfg,
-                                  src, sp)
+        memory, _, _, _ = _run_stack(params["enc_layers"], cfg.enc_pattern,
+                                     cfg, src, sp)
         memory = L.rmsnorm(params["enc_norm"], memory)
-    x, aux, _ = _run_stack(params["layers"], cfg.pattern, cfg, x, positions,
-                           memory)
+    x, aux, _, _ = _run_stack(params["layers"], cfg.pattern, cfg, x,
+                              positions, memory)
     return L.rmsnorm(params["final_norm"], x), aux
 
 
@@ -771,6 +922,14 @@ def init_cache(cfg: ModelConfig, batch: int, max_len: int,
     return tuple(caches)
 
 
+def moe_counted_layers(cfg: ModelConfig) -> int:
+    """Held-experts MoE layers in the stack: the rows of its counts."""
+    if cfg.moe is None or cfg.moe.impl != "grouped":
+        return 0
+    return cfg.n_periods * sum(s.kind == "moe" for layer in cfg.pattern
+                               for s in layer)
+
+
 def cache_layer_kinds(cfg: ModelConfig) -> tuple:
     """Kind of each entry of the caches tuple, in cache order.
 
@@ -808,9 +967,11 @@ def _merge_rows(new_cache, old_cache, active):
 
 
 def decode_step(params, cfg: ModelConfig, inputs, caches, memory=None,
-                active=None):
+                active=None, counts: bool = False):
     """One-token decode. inputs: {'tokens': (B,1)} or {'embeds': (B,1,D)},
-    plus optional 'positions'. Returns (logits (B,V), new_caches).
+    plus optional 'positions'. Returns (logits (B,V), new_caches), and with
+    ``counts`` the held-experts layers' counts as a third value (see
+    :func:`_run_stack`).
 
     ``active`` (per-slot caches only): (B,) bool — rows whose slot currently
     holds an in-flight request. Inactive rows still compute (one jitted step
@@ -822,8 +983,8 @@ def decode_step(params, cfg: ModelConfig, inputs, caches, memory=None,
     intact across decode ticks.
     """
     x, _ = embed_inputs(params, cfg, inputs)
-    x, _, new_caches = _run_stack(params["layers"], cfg.pattern, cfg, x,
-                                  None, memory, caches, active=active)
+    x, _, new_caches, n = _run_stack(params["layers"], cfg.pattern, cfg, x,
+                                     None, memory, caches, active=active)
     x = L.rmsnorm(params["final_norm"], x)
     logits = unembed(params, cfg, x)[:, -1]
     if active is not None:
@@ -831,7 +992,9 @@ def decode_step(params, cfg: ModelConfig, inputs, caches, memory=None,
         new_caches = tuple(
             nc if _is_slot_ring(oc) else _merge_rows(nc, oc, active)
             for nc, oc in zip(new_caches, caches))
-    return logits.astype(jnp.float32), advance_pos_stacked(new_caches, active)
+    out = (logits.astype(jnp.float32),
+           advance_pos_stacked(new_caches, active))
+    return out + (n,) if counts else out
 
 
 def advance_pos_stacked(caches, active=None):
@@ -951,7 +1114,7 @@ def adopt_prefix(caches, row, slot):
 
 
 def prefill_step(params, cfg: ModelConfig, inputs, caches, lengths, active,
-                 resume: bool = False):
+                 resume: bool = False, counts: bool = False):
     """Prefill prompts into per-slot caches (continuous-batching admission).
 
     inputs: {'tokens': (B, Tc)} right-padded prompts; lengths: (B,) int32
@@ -972,9 +1135,11 @@ def prefill_step(params, cfg: ModelConfig, inputs, caches, lengths, active,
     Pad positions ``t >= len`` never leak: attention writes them to ring
     slots the validity mask keeps unreadable (their ``ki`` exceeds the
     row's ``pos``), and the SSM paths mask the state update past ``len``
-    (``lengths``-aware :mod:`repro.models.ssm`). MoE rows may drop
-    differently per bucket length, so admission must bucket and chunk by
-    prompt length deterministically.
+    (``lengths``-aware :mod:`repro.models.ssm`); the held-experts MoE
+    routes no pad. Capacity-dispatch MoE rows may drop differently per
+    bucket length, so admission must bucket and chunk by prompt length
+    deterministically. With ``counts`` the held-experts layers' counts
+    come back as a third value.
     """
     if resume:
         start = caches
@@ -989,8 +1154,8 @@ def prefill_step(params, cfg: ModelConfig, inputs, caches, lengths, active,
              else jax.tree.map(jnp.zeros_like, c))
             for c in caches)
     x, _ = embed_inputs(params, cfg, inputs)
-    x, _, new_caches = _run_stack(params["layers"], cfg.pattern, cfg, x,
-                                  None, None, start, lengths=lengths)
+    x, _, new_caches, n = _run_stack(params["layers"], cfg.pattern, cfg, x,
+                                     None, None, start, lengths=lengths)
     x = L.rmsnorm(params["final_norm"], x)
     idx = jnp.clip(lengths - 1, 0)[:, None, None]
     last = jnp.take_along_axis(x, jnp.broadcast_to(
@@ -1008,7 +1173,7 @@ def prefill_step(params, cfg: ModelConfig, inputs, caches, lengths, active,
             out.append({**new_c, "pos": pos})
         else:
             out.append(new_c)
-    return logits, tuple(out)
+    return (logits, tuple(out)) + ((n,) if counts else ())
 
 
 # --------------------------------------------------------------------------
@@ -1030,7 +1195,8 @@ def supports_speculation(cfg: ModelConfig) -> bool:
             and set(cache_layer_kinds(cfg)) <= {"attn", "mamba", "rwkv"})
 
 
-def verify_forward(params, cfg: ModelConfig, inputs, caches, lengths=None):
+def verify_forward(params, cfg: ModelConfig, inputs, caches, lengths=None,
+                   counts: bool = False):
     """Score a verify call's tokens in ONE pass against per-slot caches.
 
     inputs: {'tokens': (B, T)} — per row, token 0 is the request's last
@@ -1048,14 +1214,16 @@ def verify_forward(params, cfg: ModelConfig, inputs, caches, lengths=None):
     applied (positions not yet advanced) and recurrent leaves carrying a
     per-token checkpoint axis). The raw caches are NOT safe to serve
     from — they contain speculative writes — and must go through
-    :func:`commit_verify_caches` with the accepted lengths.
+    :func:`commit_verify_caches` with the accepted lengths. With
+    ``counts`` the held-experts layers' counts come back as a third value.
     """
     x, _ = embed_inputs(params, cfg, inputs)
-    x, _, raw = _run_stack(params["layers"], cfg.pattern, cfg, x, None,
-                           None, caches, lengths=lengths,
-                           collect_states=True)
+    x, _, raw, n = _run_stack(params["layers"], cfg.pattern, cfg, x, None,
+                              None, caches, lengths=lengths,
+                              collect_states=True)
     x = L.rmsnorm(params["final_norm"], x)
-    return unembed(params, cfg, x).astype(jnp.float32), raw
+    out = (unembed(params, cfg, x).astype(jnp.float32), raw)
+    return out + (n,) if counts else out
 
 
 def commit_verify_caches(raw_caches, old_caches, n_call: int, accept,

@@ -79,6 +79,16 @@ def extract_row(caches, slot):
     return tf.extract_cache_row(caches, slot)
 
 
+def make_reset(cache_sharding):
+    """The slot-reset program. Its output is pinned to the caches'
+    sharding: on multi-device meshes a free-layout reset would let GSPMD
+    re-shard a leaf and the next prefill/decode call would reject its own
+    cache. The caches are donated, as the steps donate theirs, so a reset
+    writes no second copy of them."""
+    return jax.jit(reset_slots, out_shardings=cache_sharding,
+                   donate_argnums=(0,))
+
+
 def _pow2_at_least(n: int, floor: int) -> int:
     b = max(floor, 1)
     while b < n:
@@ -180,7 +190,8 @@ class ServingEngine:
         # exact resume re-prefills prompt+journal under a new chunk plan.
         # Bounded rings are chunk-PLAN-deterministic only, and the
         # capacity-dispatch MoE drops whichever tokens overflow an expert
-        # in a chunk: both restart from the prompt instead
+        # in a chunk: both restart from the prompt instead. The held-experts
+        # layer drops nothing, so its results do not depend on the plan
         self._exact_resume = not self._bounded_ring and not (
             cfg.moe is not None and cfg.moe.impl == "dispatch")
         if draft_headroom is None:
@@ -205,11 +216,7 @@ class ServingEngine:
         self.params = jax.device_put(params, step_fns._named(mesh,
                                                              sh["params"]))
         self._cache_sharding = step_fns._named(mesh, sh["cache"])
-        # out_shardings pinned to the cache specs: on multi-device meshes a
-        # free-layout reset would let GSPMD re-shard a leaf and the next
-        # prefill/decode call would reject its own cache
-        self._reset = jax.jit(reset_slots,
-                              out_shardings=self._cache_sharding)
+        self._reset = make_reset(self._cache_sharding)
         self.caches = None            # allocated per run
         self.stats_reducer = stats_reducer
         self.drafter = drafter
@@ -368,6 +375,15 @@ class EngineSession:
     falls back to the lossy restart-from-prompt: their results depend on
     the chunk PLAN, and a resume necessarily runs a different plan; the
     restart replays the ORIGINAL plan, so streams still come out identical.
+    The held-experts MoE drops nothing and resumes exactly.
+
+    Held-experts counts: on a model with held-experts MoE layers
+    (``tf.moe_counted_layers``), every step also returns its per-layer
+    counts (``tf.MOE_COUNTS``). They come to the host in the same transfer
+    as the next fetched tokens; :attr:`moe_counts` lists those fetched in
+    the last tick as ``(program, int array (layers, 3))``, program
+    ``"prefill"``, ``"decode"`` or ``"verify"``, and the tick's ``stats``
+    span carries them.
 
     Speculative requests are engine-global state (one drafter slot table);
     run several concurrent sessions only with ``spec=None`` requests.
@@ -414,6 +430,8 @@ class EngineSession:
         self.trace_replica = 0
         self._tick_ttfts: list = []
         self._tick_lats: list = []
+        self.moe_counts: list = []       # fetched in the last tick
+        self._moe_pending: list = []     # (program, device counts)
         if self.prefix is not None:
             self.prefix.on_event = self._prefix_event
         if self.prefix is not None and isinstance(engine.drafter,
@@ -528,6 +546,7 @@ class EngineSession:
         sched.trace_replica = rep
         self._tick_ttfts = []
         self._tick_lats = []
+        self.moe_counts = []
 
         with span(tr, "schedule", now, replica=rep):
             # --- SLO hooks: shed hopeless queued work, then evict slots
@@ -643,7 +662,7 @@ class EngineSession:
                 sampled_req = (req.sampling is not None
                                and not req.sampling.greedy
                                and slot not in self._resume_last)
-                tok, self.caches = eng._prefill(
+                tok, *n, self.caches = eng._prefill(
                     eng.params, jnp.asarray(buf), self.caches,
                     jnp.asarray(slot, jnp.int32),
                     jnp.asarray(len(chunk), jnp.int32),
@@ -651,6 +670,7 @@ class EngineSession:
                     sampling_row=({k: jnp.asarray(v[slot])
                                    for k, v in samp.items()}
                                   if sampled_req else None))
+                self._moe_pending.extend(("prefill", c) for c in n)
             req.prefilled += len(chunk)
             chunks_fed += 1
             prompt_tokens += len(chunk)
@@ -679,7 +699,7 @@ class EngineSession:
                     self.last[slot] = self._resume_last.pop(slot)
                     continue
                 with span(tr, "fetch", now, replica=rep, of="prefill"):
-                    tok = int(np.asarray(tok))
+                    tok = int(self._fetch(tok))
                 with span(tr, "commit", now, replica=rep):
                     req.tokens.append(tok)
                     req.t_first = now
@@ -754,19 +774,21 @@ class EngineSession:
                     for slot, d in drafts.items():
                         buf[slot, 1:1 + len(d)] = d
                         n_draft[slot] = len(d)
-                    out, acc, self.caches = eng._get_verify(self.k_run)(
+                    out, acc, *n, self.caches = eng._get_verify(
+                        self.k_run)(
                         eng.params, jnp.asarray(buf), self.caches,
                         jnp.asarray(active), jnp.asarray(n_draft), samp_in)
                 else:
                     # --- decode: one token per busy slot (no proposals)
-                    toks, self.caches = eng._decode(
+                    toks, *n, self.caches = eng._decode(
                         eng.params,
                         {"tokens": jnp.asarray(self.last[:, None])},
                         self.caches, jnp.asarray(active), samp_in)
+                self._moe_pending.extend((step, c) for c in n)
             if drafts:
                 with span(tr, "fetch", now, replica=rep, of="verify"):
-                    out = np.asarray(out).astype(np.int32)
-                    acc = np.asarray(acc).astype(np.int32)
+                    out, acc = self._fetch((out, acc))
+                    out, acc = out.astype(np.int32), acc.astype(np.int32)
                 with span(tr, "commit", now, replica=rep):
                     self._guard(decodable, [out[s, :acc[s]].min(initial=0)
                                             for s in decodable])
@@ -793,7 +815,7 @@ class EngineSession:
                             self._release(slot, req, freed)
             else:
                 with span(tr, "fetch", now, replica=rep, of="decode"):
-                    toks = np.asarray(toks).astype(np.int32)
+                    toks = self._fetch(toks).astype(np.int32)
                 with span(tr, "commit", now, replica=rep):
                     self._guard(decodable, [toks[s] for s in decodable])
                     for slot, req in decodable.items():
@@ -819,7 +841,8 @@ class EngineSession:
         queue_depth = sched.arrived_depth(now)
         with span(tr, "stats", now, replica=rep, queue_depth=queue_depth,
                   active_slots=len(sched.active), prefills=len(admissions),
-                  prompt_tokens=prompt_tokens, new_tokens=new_tokens):
+                  prompt_tokens=prompt_tokens, new_tokens=new_tokens,
+                  **self._moe_attrs()):
             # build the stats row BY NAME through the drift guard: a
             # counter added here but not to STATS_FIELDS (or vice versa)
             # fails on the first tick instead of silently skewing the b=1
@@ -862,6 +885,27 @@ class EngineSession:
                         tr.event("metrics", now, replica=rep, **snap)
         self.now += 1
         return vec
+
+    def _fetch(self, value):
+        """Copy a step's result to the host, and in the same transfer the
+        held-experts counts of every step since the last fetch."""
+        pending, self._moe_pending = self._moe_pending, []
+        value, counts = jax.device_get((value, [c for _, c in pending]))
+        self.moe_counts.extend((prog, np.asarray(c))
+                               for (prog, _), c in zip(pending, counts))
+        return value
+
+    def _moe_attrs(self) -> dict:
+        """The ``stats`` span's held-experts attrs: the programs whose
+        counts this tick fetched (``moe_steps``), and per count a string
+        of each step's per-layer values, steps apart by ``;``."""
+        if not self.moe_counts:
+            return {}
+        out = {"moe_steps": ",".join(p for p, _ in self.moe_counts)}
+        for i, name in enumerate(tf.MOE_COUNTS):
+            out[name] = ";".join(",".join(str(int(v)) for v in c[:, i])
+                                 for _, c in self.moe_counts)
+        return out
 
     def _guard(self, decodable, slot_tokens) -> None:
         """Refuse a tick whose guard flagged non-finite logits: raise with

@@ -296,9 +296,9 @@ class DraftModelDrafter(Drafter):
         active[slot] = True
         toks = np.zeros((self.n_slots, 1), np.int32)
         toks[slot, 0] = tok
-        out, self.caches = self._decode(self.params,
-                                        {"tokens": jnp.asarray(toks)},
-                                        self.caches, jnp.asarray(active))
+        out, *_, self.caches = self._decode(self.params,
+                                            {"tokens": jnp.asarray(toks)},
+                                            self.caches, jnp.asarray(active))
         return int(np.asarray(out)[slot])
 
     # ------------------------------------------------------------ protocol
@@ -322,7 +322,7 @@ class DraftModelDrafter(Drafter):
                      self._bound)
             buf = np.zeros((1, tc), np.int32)
             buf[0, :len(chunk)] = chunk
-            _, self.caches = self._prefill(
+            _, *_, self.caches = self._prefill(
                 self.params, jnp.asarray(buf), self.caches,
                 jnp.asarray(slot, jnp.int32),
                 jnp.asarray(len(chunk), jnp.int32), resume=pos > 0)

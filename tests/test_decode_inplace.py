@@ -56,8 +56,8 @@ def _merged_decode(params, cfg, inputs, caches, active):
     """The formulation the in-place step replaced: every cache through the
     layer scan as xs/ys, then rows merged back where ``active`` is False."""
     x, _ = tf.embed_inputs(params, cfg, inputs)
-    x, _, new = tf._run_stack(params["layers"], cfg.pattern, cfg, x, None,
-                              None, caches)
+    x, _, new, _ = tf._run_stack(params["layers"], cfg.pattern, cfg, x,
+                                 None, None, caches)
     logits = tf.unembed(params, cfg, L.rmsnorm(params["final_norm"], x))
     merged = tf.merge_cache_rows(new, caches, active)
     return logits[:, -1].astype(jnp.float32), tf.advance_pos(merged, active)

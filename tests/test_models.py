@@ -67,9 +67,9 @@ def test_prefill_equals_decode(arch):
     if cfg.n_enc_layers:
         # enc-dec: drive the decoder stack directly with fixed memory
         x, _ = tf.embed_inputs(params, cfg, {"tokens": toks})
-        hs, _, _ = tf._run_stack(params["layers"], cfg.pattern, cfg, x,
-                                 jnp.broadcast_to(jnp.arange(T)[None], (B, T)),
-                                 memory)
+        hs, _, _, _ = tf._run_stack(
+            params["layers"], cfg.pattern, cfg, x,
+            jnp.broadcast_to(jnp.arange(T)[None], (B, T)), memory)
         hs = tf.L.rmsnorm(params["final_norm"], hs)
     else:
         hs, _ = tf.forward(params, cfg, {"tokens": toks})

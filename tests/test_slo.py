@@ -102,6 +102,26 @@ def test_preempt_resume_streams_bit_identical(arch, mode):
             f"{arch}/{mode}: resume must replay the journal"
 
 
+def test_held_experts_moe_resumes_exactly():
+    """The drop-free held-experts layer (Jamba) resumes a preempted stream
+    through its journal, bit-identical to the undisturbed run; capacity
+    dispatch, whose drops depend on the chunk plan, keeps the restart."""
+    cfg, eng = get_engine("jamba_v0_1_52b")
+    assert cfg.moe.impl == "grouped" and eng._exact_resume
+    base = eng.run(_matrix_reqs(cfg, sampled=False, spec=None))
+    slo = eng.run(_matrix_reqs(cfg, sampled=False, spec=None),
+                  policy=SLOPolicy(age_ticks=100))
+    assert slo["preemptions"] >= 1 and slo["resumed_tokens"] > 0
+    assert slo["tokens"] == base["tokens"]
+    capped = tiny_cfg(pattern=(("attn", "moe"),),
+                      moe=tf.MoESettings(n_experts=4, top_k=2))
+    eng = ServingEngine(capped, ParallelConfig(),
+                        make_mesh((1, 1), ("data", "model")),
+                        tf.init_params(jax.random.PRNGKey(0), capped),
+                        n_slots=1, max_len=48)
+    assert not eng._exact_resume
+
+
 def test_preempted_request_metadata():
     """The victim's Request object records the eviction and resumes to
     completion; the interloper's deadline is met."""

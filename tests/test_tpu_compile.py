@@ -117,3 +117,93 @@ def test_slot_decode_step_updates_the_cache_in_place(topo):
     assert mem.alias_size_in_bytes >= cache_bytes
     assert mem.temp_size_in_bytes < cache_bytes / 8, (
         mem.temp_size_in_bytes, cache_bytes)
+
+
+# the usable memory of one v5e as the benchmark counts it
+V5E_BYTES = 15.75e9
+
+
+def _cell_programs(topo, cell):
+    """The decode, largest-bucket prefill and slot-reset programs of a
+    serving cell, compiled at the cell's sizes; and its model config."""
+    import sys
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    if root not in sys.path:
+        sys.path.insert(0, root)
+    from chipbench import harness
+    from chipbench.drivers import serving, serving_hybrid
+    from repro.configs.base import ShapeSuite, get_parallel
+    from repro.launch import step_fns
+    from repro.launch.mesh import make_mesh
+    from repro.models import transformer as tf
+    from repro.serving.engine import make_reset
+    bench = harness.Benchmark()
+    c = bench.cell(cell)
+    config, wl = bench.config(c), bench.workload(c)
+    driver = serving_hybrid if wl["driver"] == "serving_hybrid" else serving
+    cfg = driver.program_config(config)
+    slots, max_len = wl["engine"]["n_slots"], wl["engine"]["max_len"]
+    mesh = make_mesh((1, 1), ("data", "model"), devices=topo.devices[:1])
+    pcfg = get_parallel(config["repro_arch"])
+    suite = ShapeSuite("serve", max_len, slots, "decode")
+    decode, sh = step_fns.make_serve_step(cfg, pcfg, mesh, suite, slots=True)
+    prefill, _ = step_fns.make_prefill_step(cfg, pcfg, mesh, suite,
+                                            into_slots=True)
+
+    def shaped(tree, specs):
+        return jax.tree.map(lambda x, s: jax.ShapeDtypeStruct(
+            x.shape, x.dtype, sharding=NamedSharding(mesh, s)), tree, specs)
+
+    params = shaped(jax.eval_shape(
+        lambda: tf.init_params(jax.random.PRNGKey(0), cfg)), sh["params"])
+    # the engine's caches: its default draft headroom widens no full ring
+    caches = shaped(tf.init_cache(cfg, slots, max_len, abstract=True,
+                                  per_slot=True), sh["cache"])
+    one = NamedSharding(mesh, P())
+    arg = lambda shape, dt: jax.ShapeDtypeStruct(shape, dt, sharding=one)
+    out = {
+        "decode": jax.jit(lambda p, t, c, a: decode(p, {"tokens": t}, c, a),
+                          donate_argnums=(2,)).lower(
+            params, arg((slots, 1), jnp.int32), caches,
+            arg((slots,), jnp.bool_)).compile(),
+        "prefill": jax.jit(prefill, donate_argnums=(2,)).lower(
+            params, arg((1, max_len), jnp.int32), caches, arg((), jnp.int32),
+            arg((), jnp.int32)).compile(),
+        "reset": make_reset(step_fns._named(mesh, sh["cache"])).lower(
+            caches, arg((slots,), jnp.bool_)).compile()}
+    return out, cfg
+
+
+@pytest.mark.parametrize("cell", ["minicpm_2b.docs", "jamba2_mini_ep2.chat"])
+def test_serving_cells_fit_one_v5e(topo, cell):
+    """Every program of the docs and hybrid chat cells fits one v5e with
+    the weights and the caches it holds; a slot reset writes its donated
+    caches in place. The hybrid decode program runs its experts through
+    XLA's grouped-matmul kernels (the ops the benchmark's expert readers
+    match by name) and copies no layer's stacked expert weights."""
+    import re
+    progs, cfg = _cell_programs(topo, cell)
+    for name, c in progs.items():
+        m = c.memory_analysis()
+        total = (m.argument_size_in_bytes + m.output_size_in_bytes
+                 - m.alias_size_in_bytes + m.temp_size_in_bytes)
+        assert total < V5E_BYTES, (cell, name, total)
+    reset = progs["reset"].memory_analysis()
+    # all but the few bytes of the position counters
+    assert reset.alias_size_in_bytes > 0.99 * reset.output_size_in_bytes
+    assert reset.temp_size_in_bytes < reset.output_size_in_bytes / 100
+    if cfg.moe is None:
+        return
+    text = progs["decode"].as_text()
+    assert re.search(r"%ragged-dot-\S* = .*custom-call", text)
+    first, held = cfg.moe.held
+    stacks = [f"[{held},{cfg.d_model},{cfg.d_ff}]",
+              f"[{held},{cfg.d_ff},{cfg.d_model}]"]
+    for line in text.splitlines():
+        head = line.split(" = ", 1)
+        if len(head) < 2 or not any(s in head[1].split(" ")[0]
+                                    for s in stacks):
+            continue
+        op = head[1].split("{", 1)[1].split(" ", 1)[1].split("(")[0] \
+            if "{" in head[1].split(" ")[0] else ""
+        assert op in ("parameter", "bitcast"), line[:200]
