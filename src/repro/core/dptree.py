@@ -24,11 +24,21 @@ share one slice/update plumbing scheme, leaving THREE dynamic slices per step
 * for commutative operators the child0 partial received at a node's A-step is
   *deferred* in a carried register and folded into the B-step's combine, so
   the two child combines plus the local block become ONE three-operand
-  elementwise pass (``kernels.block_combine.combine3`` on TPU — a single HBM
-  round-trip — with a fused-jnp fallback on interpret/CPU), and the root's
-  dual-combine likewise rides that same pass instead of a second one.
+  elementwise pass, which XLA fuses into the block's in-place write, and the
+  root's dual-combine likewise rides that same pass instead of a second one.
 
 (The slice budget is pinned by ``test_fused_engine_hlo_slice_count``.)
+
+Block layout (:func:`_blockify`): the ``b`` blocks and a scratch block lie in
+one buffer with the block index first. A TPU tiles an array's two minor dims
+in (8, 128) tiles, so a 1-D payload cut as ``(b + 1, m/b)`` would put the
+block index among the tiled dims: each one-block slice or update would touch
+eight blocks' tiles, and the vector would enter and leave through relayout
+copies. A 1-D payload under an elementwise operator whose blocks span at
+least one tile is therefore laid out block-major, ``(b + 1, blk/128, 128)``
+with ``blk`` rounded up to whole tiles: one pad in, bitcasts in and out, and
+each take or put touches its own block only. Shorter blocks, 2-D lane-sharded
+and higher-rank payloads, and unknown operators keep ``(b + 1, blk, ...)``.
 
 Non-commutative (merely associative) operators keep the exact seed ordering
 (Algorithm 1's ``t (.) Y`` / lower-root ``Y (.) t`` rules) on a general path.
@@ -85,28 +95,38 @@ def _op_identity(op_name: str, dtype) -> jax.Array:
     return jnp.asarray(info.min if op_name == "max" else info.max, dtype)
 
 
-def _combine3_local(a, b, c, op_name: str) -> jax.Array:
-    """Fused ``op(op(a, b), c)``: one HBM pass via the Pallas kernel on real
-    TPUs (1-D float blocks), fused jnp elsewhere (interpret/CPU, lane-sharded
-    2-D payloads — where GSPMD owns the layout)."""
-    if (jax.default_backend() == "tpu" and a.ndim == 1
-            and a.dtype in (jnp.float32, jnp.bfloat16)):
-        from repro.kernels import block_combine
-        return block_combine.combine3(a, b, c, op=op_name, interpret=False)
-    f = _OPS_BY_NAME[op_name]
-    return f(f(a, b), c)
+# One native TPU tile is (8, 128) 32-bit words: 1024 f32, 2048 bf16 (packed
+# two to a word), 4096 int8 elements.
+_LANES = 128
+_TILE_BYTES = 8 * _LANES * 4
 
 
-def _blockify(x: jax.Array, b: int) -> tuple:
-    """Split dim 0 into b pipeline blocks. x: (m,) or (R, W) — the 2-D form
-    keeps trailing lanes GSPMD-sharded (bucketed gradients use it)."""
+def _blockify(x: jax.Array, b: int, elementwise: bool) -> tuple:
+    """Split dim 0 into ``b`` pipeline blocks plus a zero scratch block row
+    ``b``, from ONE pad of ``x``: ``(Y, m)``, ``Y`` shaped ``(b + 1, ...)``.
+
+    Block-major ``(b + 1, blk / 128, 128)`` for a 1-D payload under an
+    ``elementwise`` operator whose blocks span a tile (module docstring);
+    else ``(b + 1, blk, ...)``: 2-D ``(R, W)`` payloads keep their
+    GSPMD-sharded trailing lanes, an unknown operator sees exactly the
+    blocks it was given, and a block shorter than a tile is not padded to
+    one (that would only add wire bytes)."""
     m = x.shape[0]
     blk = -(-m // b)
-    pad = b * blk - m
-    if pad:
-        x = jnp.concatenate(
-            [x, jnp.zeros((pad,) + x.shape[1:], x.dtype)], axis=0)
-    return x.reshape((b, blk) + x.shape[1:]), m
+    tile = _TILE_BYTES // x.dtype.itemsize
+    block_major = elementwise and x.ndim == 1 and blk >= tile
+    if block_major:
+        blk = -(-blk // tile) * tile
+    x = jnp.pad(x, [(0, (b + 1) * blk - m)] + [(0, 0)] * (x.ndim - 1))
+    if block_major:
+        return x.reshape(b + 1, blk // _LANES, _LANES), m
+    return x.reshape((b + 1, blk) + x.shape[1:]), m
+
+
+def _unblockify(Y: jax.Array, m: int, trail: tuple) -> jax.Array:
+    """The first ``m`` rows of the blocks, scratch row dropped: one slice
+    (after a bitcast in the block-major layout)."""
+    return Y.reshape((-1,) + trail)[:m]
 
 
 def _const(arr: np.ndarray, i: jax.Array) -> jax.Array:
@@ -136,6 +156,14 @@ def _pin_lanes(x: jax.Array, spec=None) -> jax.Array:
     if spec is None:
         spec = _P(*([None] * (x.ndim - 1) + ["model"]))
     return maybe_shard(x, spec)
+
+
+def _lane_pinner(Y: jax.Array, x: jax.Array):
+    """``_pin_lanes`` for blocks that keep ``x``'s trailing dims; a no-op for
+    the block-major layout of a 1-D payload, which has no lanes to pin."""
+    if Y.ndim == x.ndim + 1:
+        return _pin_lanes
+    return lambda v, spec=None: v
 
 
 def _take(Y: jax.Array, idx: jax.Array, b: int) -> jax.Array:
@@ -230,15 +258,13 @@ def _tree_allreduce(x: jax.Array, axis_name: str, topo: TreeTopology,
     if p == 1:
         return x
     b = int(num_blocks)
-    Y, m = _blockify(x, b)
-    blk = Y.shape[1]
     op_rev = op_rev or op
     op_name = _COMMUTATIVE_OPS.get(op) if op_rev is op else None
     fused = op_name is not None
-
     # Scratch block row b: masked writes land here instead of paying a
     # read-modify-write of the current value (two extra dynamic slices).
-    Y = jnp.concatenate([Y, jnp.zeros((1,) + Y.shape[1:], Y.dtype)], axis=0)
+    Y, m = _blockify(x, b, elementwise=fused)
+    pin = _lane_pinner(Y, x)
 
     i = jax.lax.axis_index(axis_name)
     phi = _const(topo.phi, i)
@@ -255,8 +281,8 @@ def _tree_allreduce(x: jax.Array, axis_name: str, topo: TreeTopology,
     in_range = lambda j: (j >= 0) & (j < b)
 
     if fused:
-        ident = jnp.full((blk,) + Y.shape[2:], _op_identity(op_name, Y.dtype),
-                         Y.dtype)
+        f = _OPS_BY_NAME[op_name]
+        ident = jnp.full(Y.shape[1:], _op_identity(op_name, Y.dtype), Y.dtype)
 
     def step_fused(Y, pend, s, e):
         """One edge-class step. A node's roles rotate A->B->C over consecutive
@@ -292,8 +318,9 @@ def _tree_allreduce(x: jax.Array, axis_name: str, topo: TreeTopology,
         # scratch row, so their comb value is discarded.
         validA = amA & in_range(jA)
         cur_b = _take(Y, jB, b)
-        comb = _combine3_local(t_up, pend,
-                               jnp.where(slotB, cur_b, up_out), op_name)
+        # plain jnp: XLA fuses it, with the select below, into the in-place
+        # write of the block (a Pallas combine3 here was slower on the chip)
+        comb = f(f(t_up, pend), jnp.where(slotB, cur_b, up_out))
         new_pend = jnp.where(validA, t_up, ident)
         # --- masked write (scratch row when idle) --------------------------
         jRecv = jC - dep                  # result block index from the parent
@@ -349,10 +376,10 @@ def _tree_allreduce(x: jax.Array, axis_name: str, topo: TreeTopology,
             s0 = 3 * r
             for e in classes:
                 Y, pend = step_fused(Y, pend, s0 + e, e)
-            return (_pin_lanes(Y, carry_spec), _pin_lanes(pend, pend_spec)), ()
+            return (pin(Y, carry_spec), pin(pend, pend_spec)), ()
 
         (Y, _), _ = jax.lax.scan(
-            macro_round, (_varying(_pin_lanes(Y, carry_spec), axis_name),
+            macro_round, (_varying(pin(Y, carry_spec), axis_name),
                           _varying(ident, axis_name)),
             jnp.arange(R, dtype=jnp.int32))
     else:
@@ -360,13 +387,12 @@ def _tree_allreduce(x: jax.Array, axis_name: str, topo: TreeTopology,
             s0 = 3 * r
             for e in classes:
                 Y = step_general(Y, s0 + e, e)
-            return _pin_lanes(Y, carry_spec), ()
+            return pin(Y, carry_spec), ()
 
         Y, _ = jax.lax.scan(macro_round,
-                            _varying(_pin_lanes(Y, carry_spec), axis_name),
+                            _varying(pin(Y, carry_spec), axis_name),
                             jnp.arange(R, dtype=jnp.int32))
-    Y = Y[:b]  # drop the scratch row
-    return Y.reshape((b * Y.shape[1],) + Y.shape[2:])[:m]
+    return _unblockify(Y, m, x.shape[1:])
 
 
 def dptree_allreduce(x: jax.Array, axis_name: str, p: int, *,
@@ -560,9 +586,9 @@ def redbcast_allreduce(x: jax.Array, axis_name: str, p: int, *,
     if p == 1:
         return x
     b = max(1, min(int(num_blocks), x.shape[0]))
-    Y, m = _blockify(x, b)
     # scratch row for masked writes (same trick as the tree engine)
-    Y = jnp.concatenate([Y, jnp.zeros((1,) + Y.shape[1:], Y.dtype)], axis=0)
+    Y, m = _blockify(x, b, elementwise=op in _COMMUTATIVE_OPS)
+    pin = _lane_pinner(Y, x)
 
     i = jax.lax.axis_index(axis_name)
     dmax = topo.max_depth
@@ -607,9 +633,9 @@ def redbcast_allreduce(x: jax.Array, axis_name: str, p: int, *,
         for e in (0, 1):
             if up_cls[e]:
                 Y = rstep(Y, 2 * r + e, e)
-        return _pin_lanes(Y), ()
+        return pin(Y), ()
 
-    Y, _ = jax.lax.scan(rround, _varying(_pin_lanes(Y), axis_name),
+    Y, _ = jax.lax.scan(rround, _varying(pin(Y), axis_name),
                         jnp.arange(R1, dtype=jnp.int32))
 
     # ---------------- broadcast phase (period 2, down-traffic only) ------
@@ -650,12 +676,11 @@ def redbcast_allreduce(x: jax.Array, axis_name: str, p: int, *,
         for e in (0, 1):
             if dn_cls[e]:
                 Y = bstep(Y, 2 * r + e, e)
-        return _pin_lanes(Y), ()
+        return pin(Y), ()
 
-    Y, _ = jax.lax.scan(bround, _varying(_pin_lanes(Y), axis_name),
+    Y, _ = jax.lax.scan(bround, _varying(pin(Y), axis_name),
                         jnp.arange(R2, dtype=jnp.int32))
-    Y = Y[:b]
-    return Y.reshape((b * Y.shape[1],) + Y.shape[2:])[:m]
+    return _unblockify(Y, m, x.shape[1:])
 
 
 # --------------------------------------------------------------------------

@@ -84,12 +84,16 @@ def test_2d_row_pipelined_payloads():
     """)
 
 
-def test_dptree_non_commutative_matches_simulator():
-    run_sub("""
+@pytest.mark.parametrize("n_mats", [12, 1536])
+def test_dptree_non_commutative_matches_simulator(n_mats):
+    """Rank-ordered 2x2 matrix products on the general path; at 1536
+    matrices each of the 3 blocks is 2048 floats, longer than a TPU tile."""
+    run_sub(f"""
         from repro.core.dptree import dptree_allreduce
         from repro.core.simulator import simulate_allreduce
         rng = np.random.default_rng(1)
-        Xm = (rng.standard_normal((p, 12, 2, 2)) * 0.3 + np.eye(2)).astype(np.float32)
+        n = {n_mats}
+        Xm = (rng.standard_normal((p, n, 2, 2)) * 0.3 + np.eye(2)).astype(np.float32)
         def mm_np(a, b):
             return np.einsum('mij,mjk->mik', a, b)
         sim = simulate_allreduce([Xm[i] for i in range(p)], 3, op=mm_np)
@@ -98,13 +102,73 @@ def test_dptree_non_commutative_matches_simulator():
             return jnp.einsum('mij,mjk->mik', A, B).reshape(-1)
         body = lambda x: dptree_allreduce(x[0].reshape(-1), "data", p,
                                           num_blocks=3, op=mm_flat,
-                                          op_rev=mm_flat).reshape(12, 2, 2)[None]
+                                          op_rev=mm_flat).reshape(n, 2, 2)[None]
         sm = shard_map(body, mesh=mesh, in_specs=P("data", None, None, None),
                            out_specs=P("data", None, None, None))
         out = np.asarray(jax.jit(sm)(jnp.asarray(Xm)))
         for r in range(p):
             np.testing.assert_allclose(out[r], sim.outputs[r], rtol=2e-4,
                                        atol=2e-4)
+        print("ok")
+    """)
+
+
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16", "int32"])
+def test_block_major_payloads_match_plain_reduction(dtype):
+    """1-D payloads through dptree, sptree, redbcast and hier's inter-group
+    tree, summed and maxed: blocks long enough for the block-major layout
+    (m a multiple of neither b nor a tile; for bf16 a block of 2056 just
+    over its 2048-element tile), one block (b = 1), and blocks shorter than
+    a tile. Integer-valued inputs keep every order of summation exact, so
+    each result must EQUAL the plain reduction. Also a b = 1 structured
+    reduction whose payload is longer than a tile: its operator must see
+    exactly its own vector, unpadded."""
+    run_sub(f"""
+        from repro.core.collectives import structured_all_reduce
+        from repro.core.dptree import (dptree_allreduce, sptree_allreduce,
+                                       redbcast_allreduce, hier_allreduce)
+        rng = np.random.default_rng(3)
+        dt = jnp.dtype("{dtype}")
+        methods = {{
+            "dptree": dptree_allreduce, "sptree": sptree_allreduce,
+            "redbcast": redbcast_allreduce,
+            "hier": lambda x, a, p, num_blocks, op: hier_allreduce(
+                x, a, p, group_size=2, num_blocks=num_blocks, op=op)}}
+        for m, b in ((5 * 2048 + 37, 5), (3001, 1), (999, 8)):
+            X = rng.integers(-16, 16, (p, m)).astype(dt)
+            for op, ref in ((jnp.add, X.astype(np.float64).sum(0)),
+                            (jnp.maximum, X.astype(np.float64).max(0))):
+                for name, fn in methods.items():
+                    f = lambda x: fn(x[0], "data", p, num_blocks=b,
+                                     op=op)[None]
+                    sm = shard_map(f, mesh=mesh, in_specs=P("data", None),
+                                   out_specs=P("data", None))
+                    out = np.asarray(jax.jit(sm)(jnp.asarray(X)))
+                    assert out.dtype == dt, (name, out.dtype)
+                    for r in range(p):
+                        np.testing.assert_array_equal(
+                            out[r].astype(np.float64), ref,
+                            err_msg=f"{{name}} m={{m}} b={{b}} {{op.__name__}}")
+
+        # affine maps composed: associative, not commutative
+        def comb(a, b):
+            return {{"n": a["n"] * b["n"], "s": a["s"] * b["n"] + b["s"]}}
+        parts = {{"n": jnp.asarray(rng.integers(0, 2, (p, 3000)).astype(dt)),
+                  "s": jnp.asarray(rng.integers(0, 4, (p, 3000)).astype(dt))}}
+        sm = shard_map(lambda t: jax.tree.map(lambda l: l[None],
+                           structured_all_reduce(jax.tree.map(lambda l: l[0], t),
+                                                 "data", p, comb)),
+                       mesh=mesh, in_specs=P("data"), out_specs=P("data"))
+        out = jax.jit(sm)(parts)
+        want = {{k: np.asarray(v[0]).astype(np.float64)
+                 for k, v in parts.items()}}
+        for r in range(1, p):
+            want = comb(want, {{k: np.asarray(v[r]).astype(np.float64)
+                                for k, v in parts.items()}})
+        for k in want:
+            for r in range(p):
+                np.testing.assert_array_equal(
+                    np.asarray(out[k][r]).astype(np.float64), want[k])
         print("ok")
     """)
 

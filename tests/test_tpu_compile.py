@@ -8,6 +8,7 @@ times. The topology is described inside a fixture, never at import.
 """
 
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -60,12 +61,15 @@ def test_bf16_wire_kernels_compile_for_v5e(one_chip):
 
 def test_dptree_kernel_branch_compiles_in_checked_shard_map(topo,
                                                            monkeypatch):
-    """The fused engine's TPU branch (Pallas combine3 inside the scan)
-    compiles inside a 4-chip shard_map that checks varying manual axes."""
+    """The fused engine on the chip's backend compiles inside a 4-chip
+    shard_map that checks varying manual axes, with its blocks block-major
+    and its three-operand combine fused by XLA into the in-place write: no
+    Pallas call (a ``combine3`` over the block was the slower of the two in
+    a gradient sync on a v5e 2x2)."""
     p = 4
     mesh = Mesh(np.array(topo.devices[:p]), ("data",),
                 axis_types=(jax.sharding.AxisType.Auto,))
-    # the engine picks its kernel from the backend; steer it to the chip's
+    # the engine would see the chip's backend there; steer it to it
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
     fn = jax.shard_map(
         lambda x: dptree_allreduce(x[0], "data", p, num_blocks=16)[None],
@@ -73,8 +77,84 @@ def test_dptree_kernel_branch_compiles_in_checked_shard_map(topo,
     x = jax.ShapeDtypeStruct((p, 16 * BLOCK), jnp.float32,
                              sharding=NamedSharding(mesh, P("data")))
     text = _compiled_text(fn, x)
-    assert "tpu_custom_call" in text
+    assert "tpu_custom_call" not in text
     assert "collective-permute" in text
+    assert f"f32[17,{BLOCK // 128},128]" in text
+    assert "dynamic-update-slice_fusion" in text
+
+
+_HEADER = re.compile(r"^(?:ENTRY )?%([\w.\-]+) .*\{$")
+_CALLS = re.compile(r"(?:calls|body|condition|to_apply)=%([\w.\-]+)"
+                    r"|branch_computations=\{([^}]*)\}")
+_BLOCK_OP = re.compile(r"^(?:ROOT )?%\S+ = \w+\[([\d,]*)\]\S* "
+                       r"(pad|reduce|concatenate)\(")
+
+
+def _computations(text: str) -> dict:
+    """``{name: [instruction lines]}`` of a compiled HLO module's text."""
+    comps, cur = {}, None
+    for line in text.splitlines():
+        head = _HEADER.match(line)
+        if head:
+            cur = comps.setdefault(head.group(1), [])
+        elif line.startswith("}"):
+            cur = None
+        elif cur is not None and line.strip():
+            cur.append(line.strip())
+    return comps
+
+
+def _reachable(comps: dict, root: str) -> set:
+    """The computations ``root`` runs, itself and all it calls."""
+    seen, todo = set(), [root]
+    while todo:
+        name = todo.pop()
+        if name in seen or name not in comps:
+            continue
+        seen.add(name)
+        for line in comps[name]:
+            for one, many in _CALLS.findall(line):
+                todo += [one] if one else re.findall(r"%([\w.\-]+)", many)
+    return seen
+
+
+@pytest.mark.parametrize("branch", ["default", "kernel"])
+def test_dptree_blocks_are_block_major_whole_tiles(topo, monkeypatch,
+                                                   branch):
+    """A 1-D f32 payload whose block length is no multiple of a tile is laid
+    out block-major in whole (8, 128) tiles: one loop, whose carry is
+    ``(b + 1, rows, 128)``; no pad, concatenate or reduce of a block inside
+    it (a take or a put of one block touches that block alone, and the
+    combine copies nothing); and temporaries under 1.2x the payload (the
+    vector enters and leaves by bitcast, not through relayout loops). On the
+    kernel branch too, with the engine steered to the chip's backend."""
+    p, b, blk = 4, 16, 1224342       # blocks of the gradient's first bucket
+    m = b * blk
+    if branch == "kernel":
+        monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    mesh = Mesh(np.array(topo.devices[:p]), ("data",),
+                axis_types=(jax.sharding.AxisType.Auto,))
+    fn = jax.shard_map(
+        lambda x: dptree_allreduce(x, "data", p, num_blocks=b),
+        mesh=mesh, in_specs=P("data"), out_specs=P("data"), check_vma=True)
+    x = jax.ShapeDtypeStruct((p * m,), jnp.float32,
+                             sharding=NamedSharding(mesh, P("data")))
+    compiled = jax.jit(fn).lower(x).compile()
+    text = compiled.as_text()
+    loops = re.findall(r" while\(.*?body=%([\w.\-]+)", text)
+    assert len(loops) == 1, loops
+    rows = -(-blk // 1024) * 1024 // 128
+    carry = next(l for l in text.splitlines() if " while(" in l)
+    assert f"f32[{b + 1},{rows},128]" in carry, carry[:300]
+    comps = _computations(text)
+    for name in _reachable(comps, loops[0]):
+        for line in comps[name]:
+            op = _BLOCK_OP.match(line)
+            if op and op.group(1):
+                n = int(np.prod([int(d) for d in op.group(1).split(",")]))
+                assert n < blk, (name, line[:200])
+    temp = compiled.memory_analysis().temp_size_in_bytes
+    assert temp < 1.2 * m * 4, (temp, m * 4)
 
 
 def test_slot_decode_step_updates_the_cache_in_place(topo):
