@@ -23,7 +23,7 @@ SIZES = [10_000, 1_000_000]  # f32 elements
 DEVICES = 8
 GROUP_SIZE = (2, 2)   # 3-level spec: 2-chip ring, 2-node ring, tree over 2
 COMPRESS = True       # also time the bf16 slow-stage wire candidates
-ALGORITHMS = ("dptree", "sptree", "redbcast", "ring", "hier")
+ALGORITHMS = tuple(cm.ALGORITHMS)
 
 
 def _measure_candidates(m_elems: int, cands, devices=DEVICES, reps=3):

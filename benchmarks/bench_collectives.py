@@ -22,17 +22,18 @@ import textwrap
 import numpy as np
 
 from repro.core import cost_model as cm
+from repro.core import collectives
 from repro.core.topology import resolve_levels
 
 SIZES = [1_000, 10_000, 100_000, 1_000_000, 4_000_000]  # f32 elements
-METHODS = ["dptree", "sptree", "redbcast", "ring", "hier", "psum"]
+METHODS = [m for m in collectives.METHODS if m != "auto"]
 # label -> CollectiveConfig kwargs; the hierarchical variants measured next
 # to the flat methods: two-level (4-chip groups), three-level (2-chip ring
 # inside a 2-node ring inside the pod tree), and the bf16 slow-stage wire.
 CASES = ([(m, {"method": m, "group_size": 4 if m == "hier" else None})
           for m in METHODS]
-         + [("hier3", {"method": "hier", "levels": (2, 2)}),
-            ("hier3_bf16", {"method": "hier", "levels": (2, 2),
+         + [("hier3", {"method": "hier", "group_size": (2, 2)}),
+            ("hier3_bf16", {"method": "hier", "group_size": (2, 2),
                             "compress_inter_group": True})])
 
 

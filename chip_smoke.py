@@ -201,11 +201,12 @@ def collective_cases() -> tuple:
         ("sptree", CollectiveConfig(method="sptree"), exact),
         ("redbcast", CollectiveConfig(method="redbcast"), exact),
         ("ring", CollectiveConfig(method="ring"), exact),
-        ("hier(2,2)", CollectiveConfig(method="hier", levels=(2, 2)), exact),
+        ("hier(2,2)", CollectiveConfig(method="hier", group_size=(2, 2)),
+         exact),
         # levels (2, 2) leave one group on four chips, so the slow-stage
         # tree and its bf16 wire only run with two groups of two
-        ("hier(2)", CollectiveConfig(method="hier", levels=(2,)), exact),
-        ("hier(2)+bf16", CollectiveConfig(method="hier", levels=(2,),
+        ("hier(2)", CollectiveConfig(method="hier", group_size=(2,)), exact),
+        ("hier(2)+bf16", CollectiveConfig(method="hier", group_size=(2,),
                                           compress_inter_group=True), bf16),
     )
 

@@ -23,7 +23,8 @@ import jax.numpy as jnp  # noqa: E402
 import numpy as np  # noqa: E402
 from jax.sharding import PartitionSpec as P  # noqa: E402
 
-from repro.core.collectives import CollectiveConfig, all_reduce  # noqa: E402
+from repro.core.collectives import (METHODS, CollectiveConfig,  # noqa: E402
+                                    all_reduce)
 from repro.core.cost_model import TPU_V5E, optimal_blocks  # noqa: E402
 
 
@@ -42,11 +43,11 @@ def main():
               f"{optimal_blocks(256, m * 4, TPU_V5E, 'dptree')})")
         cases = [(m_, CollectiveConfig(method=m_, group_size=4
                                        if m_ == "hier" else None))
-                 for m_ in ("dptree", "sptree", "redbcast", "ring", "hier",
-                            "psum")]
-        cases += [("hier3", CollectiveConfig(method="hier", levels=(2, 2))),
+                 for m_ in METHODS if m_ != "auto"]
+        cases += [("hier3", CollectiveConfig(method="hier",
+                                             group_size=(2, 2))),
                   ("hier3+bf16", CollectiveConfig(method="hier",
-                                                  levels=(2, 2),
+                                                  group_size=(2, 2),
                                                   compress_inter_group=True))]
         for name, cfg in cases:
             body = lambda x: all_reduce(x[0], "data", p, cfg)[None]

@@ -53,16 +53,12 @@ __all__ = [
     "set_cache_path",
 ]
 
-_ALGORITHMS = ("dptree", "sptree", "redbcast", "ring")
-
-# Every algorithm a cache entry may legitimately name (the tunable set plus
-# the hierarchical composition). Entries outside this set — or with a
-# non-positive block count or a non-finite time — are treated as cache
-# MISSES by :meth:`AutotuneCache.get`: a corrupted cache file must degrade
-# to the analytic cost-model switch, never crash a consumer at trace time
-# (the degrade-never-raise contract, exercised by
+# A cache entry naming an algorithm outside ``cost_model.ALGORITHMS`` — or
+# with a non-positive block count or a non-finite time — is a cache MISS in
+# :meth:`AutotuneCache.get`: a corrupted cache file must degrade to the
+# analytic cost-model switch, never crash a consumer at trace time (the
+# degrade-never-raise contract, exercised by
 # :func:`repro.runtime.chaos.corrupt_autotune_cache`).
-_VALID_ALGORITHMS = frozenset(_ALGORITHMS) | {"hier"}
 
 # Block-count multipliers probed around the analytic optimum.
 _BLOCK_SWEEP = (0.5, 1.0, 2.0)
@@ -207,7 +203,7 @@ class AutotuneCache:
         except (KeyError, TypeError, ValueError):
             return None
         # semantic validation: corrupted entries are misses, not winners
-        if res.algorithm not in _VALID_ALGORITHMS or res.num_blocks < 1 \
+        if res.algorithm not in cm.ALGORITHMS or res.num_blocks < 1 \
                 or not (0.0 <= res.time_s < 1e18):
             return None
         return res
@@ -254,7 +250,7 @@ COMPRESSED_SUFFIX = "+bf16"
 
 
 def candidate_settings(p: int, nbytes: int, model: cm.CommModel,
-                       algorithms: Sequence[str] = _ALGORITHMS,
+                       algorithms: Sequence[str] = cm.FLAT_ALGORITHMS,
                        group_size=None,
                        compress_inter_group: bool = False) -> list:
     """``(algorithm, num_blocks)`` candidates around the analytic optimum.
@@ -275,15 +271,16 @@ def candidate_settings(p: int, nbytes: int, model: cm.CommModel,
             out.append((algo, b))
 
     for algo in algorithms:
-        if algo == "ring":
-            add("ring", 1)
+        entry = cm.ALGORITHMS[algo]
+        if entry.form is None:          # not pipelined: one candidate
+            add(algo, 1)
             continue
         b0 = cm.optimal_blocks(p, float(max(nbytes, 1)), model, algo,
                                group_size=group_size)
         for mult in _BLOCK_SWEEP:
             add(algo, round(b0 * mult))
-        if algo == "hier" and compress_inter_group:
-            bc = cm.optimal_blocks(p, float(max(nbytes, 1)), model, "hier",
+        if entry.stage is not None and compress_inter_group:
+            bc = cm.optimal_blocks(p, float(max(nbytes, 1)), model, algo,
                                    group_size=group_size, compression="bf16")
             for mult in _BLOCK_SWEEP:
                 add(algo + COMPRESSED_SUFFIX, round(bc * mult))
@@ -292,7 +289,7 @@ def candidate_settings(p: int, nbytes: int, model: cm.CommModel,
 
 def tune(runner: Callable[[str, int], float], p: int, nbytes: int,
          dtype: str, topology: str, model: cm.CommModel,
-         algorithms: Sequence[str] = _ALGORITHMS,
+         algorithms: Sequence[str] = cm.FLAT_ALGORITHMS,
          group_size=None,
          compress_inter_group: bool = False,
          cache: AutotuneCache | None = None,
@@ -313,10 +310,8 @@ def tune(runner: Callable[[str, int], float], p: int, nbytes: int,
     cache = get_cache() if cache is None else cache
     # Resolve the shape hier actually runs with BEFORE measuring, so the
     # recorded TuneResult names the exact configuration that was timed.
-    from repro.core.topology import as_levels, default_group_size
-    hier_lv = as_levels(group_size)
-    if hier_lv is None:
-        hier_lv = as_levels(default_group_size(p))
+    from repro.core.topology import as_levels
+    hier_lv = as_levels(group_size, p)
     best: TuneResult | None = None
     errors = []
     for algo, b in candidate_settings(p, nbytes, model, algorithms,
@@ -332,7 +327,8 @@ def tune(runner: Callable[[str, int], float], p: int, nbytes: int,
         if best is None or t < best.time_s:
             base = algo.removesuffix(COMPRESSED_SUFFIX)
             best = TuneResult(base, b, t,
-                              hier_lv if base == "hier" else None,
+                              hier_lv if cm.ALGORITHMS[base].stage is not None
+                              else None,
                               compressed=algo.endswith(COMPRESSED_SUFFIX),
                               axis=axis)
     if best is None:

@@ -13,31 +13,86 @@ Must be called inside a ``shard_map`` that is manual over ``axis_name``.
 from __future__ import annotations
 
 import dataclasses
-import functools
 from typing import Any, Callable
 
 import jax
 import jax.numpy as jnp
-import numpy as np
 
 from repro.core import autotune
 from repro.core import cost_model as cm
 from repro.core.dptree import (_COMMUTATIVE_OPS, dptree_allreduce,
                                hier_allreduce, redbcast_allreduce,
                                ring_allreduce, sptree_allreduce)
-from repro.core.topology import build_dual_tree
 from repro.obs import probe as _obs_probe
 
 __all__ = [
     "CollectiveConfig",
+    "Plan",
+    "plan",
     "all_reduce",
     "bucketed_all_reduce",
     "structured_all_reduce",
-    "all_reduce_mean",
     "bucket_sizes",
 ]
 
-METHODS = ("auto", "dptree", "sptree", "redbcast", "ring", "hier", "psum")
+
+@dataclasses.dataclass(frozen=True)
+class _Method:
+    """One runnable method: ``run(x, axis_name, p, plan, op, carry_spec)``
+    and the operators it accepts (None: any associative op). An explicit
+    request with another op raises in :func:`plan` only when ``strict``."""
+
+    run: Callable[..., jax.Array]
+    ops: Any = None
+    strict: bool = False
+
+
+# XLA primitive equivalent per supported elementwise op (psum-family).
+_PRIMITIVE_REDUCE = {jnp.add: jax.lax.psum, jnp.maximum: jax.lax.pmax,
+                     jnp.minimum: jax.lax.pmin}
+
+
+def _psum(x, axis_name, p, pl, op, carry_spec):
+    # route through the matching primitive: psum with op=max would silently
+    # sum.
+    try:
+        prim = _PRIMITIVE_REDUCE[op]
+    except KeyError:
+        raise ValueError(
+            "method='psum' supports only jnp.add/maximum/minimum ops; "
+            "use a schedule-based method for custom operators") from None
+    return prim(x, axis_name)
+
+
+# THE table of runnable methods. A method is added or removed here and in
+# ``cost_model.ALGORITHMS`` (which prices every method but psum).
+_RUNNERS = {
+    "dptree": _Method(lambda x, ax, p, pl, op, carry_spec: dptree_allreduce(
+        x, ax, p, num_blocks=pl.num_blocks, op=op, carry_spec=carry_spec)),
+    "sptree": _Method(lambda x, ax, p, pl, op, carry_spec: sptree_allreduce(
+        x, ax, p, num_blocks=pl.num_blocks, op=op, carry_spec=carry_spec)),
+    "redbcast": _Method(
+        lambda x, ax, p, pl, op, carry_spec: redbcast_allreduce(
+            x, ax, p, num_blocks=pl.num_blocks, op=op)),
+    "ring": _Method(lambda x, ax, p, pl, op, carry_spec: ring_allreduce(
+        x, ax, p, op=op), _COMMUTATIVE_OPS),
+    "hier": _Method(lambda x, ax, p, pl, op, carry_spec: hier_allreduce(
+        x, ax, p, group_size=pl.group_size, num_blocks=pl.num_blocks, op=op,
+        carry_spec=carry_spec, compress_inter_group=pl.compress),
+        _COMMUTATIVE_OPS, strict=True),
+    "psum": _Method(_psum, _PRIMITIVE_REDUCE),
+}
+
+
+def _hierarchical(algo: str) -> bool:
+    """A hierarchy, as its pricing says (it pipelines a stage): it replays an
+    autotune hit's level spec, may ride the bf16 inter-group wire and notes
+    its own probe sample."""
+    pricing = cm.ALGORITHMS.get(algo)
+    return pricing is not None and pricing.stage is not None
+
+
+METHODS = ("auto", *_RUNNERS)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -65,9 +120,6 @@ class CollectiveConfig:
                      ``(4, 2)`` = chip ring, node ring, dual tree over pods);
                      None = 4, then 2, then flat. Also gates whether 'hier'
                      competes in the ``auto`` switch.
-    ``levels``       alias for an N-level ``group_size`` spec; when set it
-                     takes precedence (kept separate so call sites that
-                     pass a plain int group size keep reading naturally).
     ``intra_model``  alpha-beta constants for the intra-group fast links
                      (every intra level; the cost model also accepts
                      per-level models, see ``cost_model.hier_time``).
@@ -86,7 +138,6 @@ class CollectiveConfig:
     comm_model: cm.CommModel = cm.TPU_V5E
     group_size: int | tuple | None = None
     intra_model: cm.CommModel = cm.TPU_V5E
-    levels: tuple | None = None
     compress_inter_group: bool = False
 
     def __post_init__(self):
@@ -94,71 +145,35 @@ class CollectiveConfig:
             raise ValueError(f"unknown method {self.method!r}; want {METHODS}")
         if self.compression not in (None, "bf16"):
             raise ValueError(f"unknown compression {self.compression!r}")
-        if self.levels is not None:
-            object.__setattr__(self, "levels", tuple(int(s)
-                                                     for s in self.levels))
         if isinstance(self.group_size, (list, tuple)):
             object.__setattr__(self, "group_size",
                                tuple(int(s) for s in self.group_size))
 
-    @property
-    def hier_spec(self):
-        """The hierarchy spec hier/auto paths consume: ``levels`` if set,
-        else ``group_size`` (int, tuple, or None)."""
-        return self.levels if self.levels is not None else self.group_size
+
+@dataclasses.dataclass(frozen=True)
+class Plan:
+    """How one reduction runs: the algorithm, its pipeline block count, the
+    hierarchy spec handed to a hierarchical runner (int, level tuple or
+    None), and whether its slow inter-group stage rides the bf16 wire."""
+
+    algorithm: str
+    num_blocks: int
+    group_size: int | tuple | None
+    compress: bool
 
 
-_RUNNABLE = ("dptree", "sptree", "redbcast", "ring", "hier", "psum")
-
-# XLA primitive equivalent per supported elementwise op (psum-family).
-_PRIMITIVE_REDUCE = {jnp.add: jax.lax.psum, jnp.maximum: jax.lax.pmax,
-                     jnp.minimum: jax.lax.pmin}
-
-
-def _degrade_for_op(algo: str, op, method: str) -> str:
-    """Reroute an algorithm pick that cannot run this operator.
-
-    ring/hier reduce in ring order (commutative ops only) and psum only has
-    primitive equivalents for add/max/min. Under ``auto`` every such pick
-    silently degrades to the rank-ordered dptree — auto must never raise on
-    an op/model/cache combination. An EXPLICIT hier request raises (a new
-    API, so a loud contract); explicit ring/psum keep their documented
-    behavior and error paths.
-    """
-    unsupported = ((algo in ("ring", "hier") and op not in _COMMUTATIVE_OPS)
-                   or (algo == "psum" and op not in _PRIMITIVE_REDUCE))
-    if not unsupported:
-        return algo
-    if method == "auto":
-        return "dptree"
-    if algo == "hier":
-        raise ValueError(
-            "method='hier' requires a commutative op (jnp.add/maximum/"
-            "minimum/multiply); use dptree for merely-associative ops")
-    return algo
-
-
-def _pick(method: str, p: int, nbytes: int, config: "CollectiveConfig",
-          dtype, axis_name: str | None = None) -> tuple:
-    """(algorithm, measured_num_blocks | None, hier_spec | None, compress).
-
-    ``hier_spec`` is the hierarchy level spec (int or tuple) the hier path
-    should execute with; ``compress`` is whether the slow inter-group stage
-    rides the bf16 wire. ``axis_name`` scopes the autotune probe to that
-    mesh axis's measurements (TP reductions vs gradient buckets vs stats
-    trees never replay onto each other — legacy axis-less entries still
-    match any axis).
-    """
-    if method != "auto":
-        return method, None, config.hier_spec, config.compress_inter_group
+def _auto_choice(config: CollectiveConfig, p: int, nbytes: int, dtype,
+                 axis_name: str | None) -> tuple:
+    """``auto``'s ``(algorithm, measured_num_blocks | None, group_size,
+    compress)``: a replayable autotune hit, else the cost-model switch."""
     # Empirical closed loop first: a measured (algorithm, blocks) for this
     # exact (p, bytes, dtype, fabric, axis) beats any model prediction — but
     # only if the recorded setting is actually runnable here ('auto' must
     # degrade, never raise, on a stale or foreign cache entry).
     hit = autotune.lookup(p, int(max(nbytes, 1)), str(dtype),
                           config.comm_model.name, axis=axis_name)
-    if hit is not None and hit.algorithm in _RUNNABLE:
-        if hit.algorithm != "hier":
+    if hit is not None and hit.algorithm in _RUNNERS:
+        if not _hierarchical(hit.algorithm):
             return hit.algorithm, max(1, int(hit.num_blocks)), None, False
         # Replay ONLY the configuration the entry was measured with: the
         # exact group shape, and compression only if (a) it was timed
@@ -172,25 +187,58 @@ def _pick(method: str, p: int, nbytes: int, config: "CollectiveConfig",
               if hit.group_size is not None else None)
         if lv is not None and (not hit.compressed
                                or config.compress_inter_group):
-            return "hier", max(1, int(hit.num_blocks)), lv, hit.compressed
+            return hit.algorithm, max(1, int(hit.num_blocks)), lv, \
+                hit.compressed
     # psum is XLA's own allreduce; we only auto-pick among algorithms whose
     # cost we model. The paper's point stands: never let the library guess.
     algo = cm.best_algorithm(p, float(max(nbytes, 1)), config.comm_model,
-                             group_size=config.hier_spec,
+                             group_size=config.group_size,
                              intra_model=config.intra_model)
-    return (algo, None, config.hier_spec,
-            algo == "hier" and config.compress_inter_group)
+    return (algo, None, config.group_size,
+            _hierarchical(algo) and config.compress_inter_group)
 
 
-def _nblocks(num_blocks, p, nbytes, model, algorithm, group_size=None,
-             compression=None):
-    if num_blocks is not None:
-        return int(num_blocks)
-    if algorithm in ("dptree", "sptree", "redbcast", "hier"):
-        return cm.optimal_blocks(p, float(max(nbytes, 1)), model, algorithm,
-                                 group_size=group_size,
-                                 compression=compression)
-    return 1
+def plan(config: CollectiveConfig, p: int, nbytes: int, dtype,
+         op: Callable = jnp.add, axis_name: str | None = None) -> Plan:
+    """How :func:`all_reduce` runs one ``nbytes`` reduction of ``dtype``
+    over ``p`` ranks under ``op``, resolved on the host in three steps:
+
+    1. the method: an explicit one as given; under ``auto`` an autotune hit
+       for ``(p, nbytes, dtype, fabric, axis_name)`` runnable here, else
+       ``cost_model.best_algorithm``. ``axis_name`` keeps TP, gradient and
+       stats tunings apart (axis-less entries match any axis);
+    2. the operator: a pick that cannot run ``op`` degrades under ``auto``
+       to the rank-ordered dptree and drops its measured block count (auto
+       never raises); an explicit strict method raises;
+    3. the block count: the config's, else the measured one, else the
+       Pipelining-Lemma optimum for a pipelined algorithm, else 1.
+    """
+    if config.method != "auto":
+        algo, measured = config.method, None
+        spec, compress = config.group_size, config.compress_inter_group
+    else:
+        algo, measured, spec, compress = _auto_choice(config, p, nbytes,
+                                                      dtype, axis_name)
+    method = _RUNNERS[algo]
+    if method.ops is not None and op not in method.ops:
+        if config.method == "auto":
+            algo, measured = "dptree", None
+        elif method.strict:
+            raise ValueError(
+                f"method={algo!r} requires a commutative op (jnp.add/"
+                "maximum/minimum/multiply); use dptree for merely-"
+                "associative ops")
+    if config.num_blocks is not None:
+        nb = int(config.num_blocks)
+    elif measured is not None:
+        nb = measured
+    elif algo in cm.ALGORITHMS and cm.ALGORITHMS[algo].form is not None:
+        nb = cm.optimal_blocks(p, float(max(nbytes, 1)), config.comm_model,
+                               algo, group_size=spec,
+                               compression="bf16" if compress else None)
+    else:
+        nb = 1
+    return Plan(algo, nb, spec, compress)
 
 
 def _lane_shard(x: jax.Array) -> jax.Array:
@@ -212,10 +260,10 @@ def all_reduce(x: jax.Array, axis_name: str, p: int,
 
     Must be called inside a ``shard_map`` manual over ``axis_name``. The
     algorithm, pipeline block count, hierarchy shape, and compression all
-    come from ``config`` (see :class:`CollectiveConfig`); ``op`` must be
-    associative, and the ring-order methods (``ring``/``hier``) additionally
-    require commutativity — under ``auto`` unsupported picks silently
-    degrade to the rank-ordered dptree, explicit requests raise.
+    come from ``config`` through :func:`plan`; ``op`` must be associative,
+    and the ring-order methods (``ring``/``hier``) additionally require
+    commutativity — under ``auto`` unsupported picks silently degrade to the
+    rank-ordered dptree, an explicit ``hier`` raises.
 
     Payload layout: 1-D payloads pipeline directly; 2-D ``(rows, lanes)``
     payloads pipeline over rows with the lane dim left to GSPMD (the
@@ -242,57 +290,19 @@ def all_reduce(x: jax.Array, axis_name: str, p: int,
     if config.compression == "bf16" and flat.dtype == jnp.float32:
         flat = flat.astype(jnp.bfloat16)
     nbytes = flat.size * flat.dtype.itemsize
-    algo, nb_measured, hier_spec, hier_compress = _pick(
-        config.method, p, nbytes, config, flat.dtype, axis_name)
-    new_algo = _degrade_for_op(algo, op, config.method)
-    if new_algo != algo:
-        algo, nb_measured = new_algo, None
-    nb = (nb_measured if config.num_blocks is None and nb_measured is not None
-          else _nblocks(config.num_blocks, p, nbytes, config.comm_model,
-                        algo, hier_spec,
-                        "bf16" if hier_compress else None))
+    pl = plan(config, p, nbytes, flat.dtype, op, axis_name)
     probe = _obs_probe.active()
-    if probe is not None and algo != "hier":
+    if probe is not None and not _hierarchical(pl.algorithm):
         # Trace-time note: this Python body runs once per compilation, so
         # the sample records WHAT was picked (algorithm, blocks, shape) —
         # wall time comes from host-boundary timed samples (repro.obs.probe).
-        # hier defers to hier_allreduce's own note (resolved level spec).
-        probe.note(algo, p, nbytes, nb, dtype=str(flat.dtype),
-                   kind="trace", levels=hier_spec, axis=axis_name)
-    if algo == "psum":
-        # route through the matching primitive: psum with op=max would
-        # silently sum.
-        try:
-            prim = _PRIMITIVE_REDUCE[op]
-        except KeyError:
-            raise ValueError(
-                "method='psum' supports only jnp.add/maximum/minimum ops; "
-                "use a schedule-based method for custom operators") from None
-        out = prim(flat, axis_name)
-    elif algo == "dptree":
-        out = dptree_allreduce(flat, axis_name, p, num_blocks=nb, op=op,
-                               carry_spec=carry_spec)
-    elif algo == "sptree":
-        out = sptree_allreduce(flat, axis_name, p, num_blocks=nb, op=op,
-                               carry_spec=carry_spec)
-    elif algo == "redbcast":
-        out = redbcast_allreduce(flat, axis_name, p, num_blocks=nb, op=op)
-    elif algo == "ring":
-        out = ring_allreduce(flat, axis_name, p, op=op)
-    elif algo == "hier":
-        out = hier_allreduce(flat, axis_name, p, group_size=hier_spec,
-                             num_blocks=nb, op=op, carry_spec=carry_spec,
-                             compress_inter_group=hier_compress)
-    else:  # pragma: no cover
-        raise AssertionError(algo)
+        probe.note(pl.algorithm, p, nbytes, pl.num_blocks,
+                   dtype=str(flat.dtype), kind="trace", levels=pl.group_size,
+                   axis=axis_name)
+    out = _RUNNERS[pl.algorithm].run(flat, axis_name, p, pl, op, carry_spec)
     if out.ndim == 2:
         out = _lane_shard(out)
     return out.astype(dtype).reshape(shape)
-
-
-def all_reduce_mean(x: jax.Array, axis_name: str, p: int,
-                    config: CollectiveConfig = CollectiveConfig()) -> jax.Array:
-    return all_reduce(x, axis_name, p, config) / p
 
 
 def bucketed_all_reduce(tree: Any, axis_name: str, p: int,
@@ -470,7 +480,8 @@ def structured_all_reduce(tree: Any, axis_name: str, p: int,
     out) triples). Uses a single pipeline block (b=1), where the dual-root tree
     is the log-latency optimum — the regime the paper's algorithm targets.
 
-    ``combine(a, b)`` takes and returns pytrees shaped like ``tree``.
+    ``combine(a, b)`` takes and returns pytrees shaped like ``tree``;
+    ``method`` must be one that runs any associative operator.
     """
     if p == 1:
         return tree
@@ -495,6 +506,10 @@ def structured_all_reduce(tree: Any, axis_name: str, p: int,
     def op(a, b):
         return pack(combine(unpack(a), unpack(b)))
 
-    fn = {"dptree": dptree_allreduce, "sptree": sptree_allreduce}[method]
-    red = fn(flat, axis_name, p, num_blocks=1, op=op, op_rev=op)
+    runner = _RUNNERS[method]
+    if runner.ops is not None:
+        raise ValueError("structured_all_reduce needs a method that runs "
+                         "any associative combine")
+    red = runner.run(flat, axis_name, p, Plan(method, 1, None, False), op,
+                     None)
     return unpack(red)

@@ -19,6 +19,7 @@ from __future__ import annotations
 import dataclasses
 import functools
 import math
+from typing import Callable
 
 import numpy as np
 
@@ -36,6 +37,8 @@ __all__ = [
     "hier_time",
     "tp_time",
     "COMPRESS_FACTOR",
+    "ALGORITHMS",
+    "FLAT_ALGORITHMS",
     "optimal_blocks",
     "best_algorithm",
 ]
@@ -64,10 +67,6 @@ TPU_V5E_INTERPOD = CommModel(alpha=10e-6, beta=1.0 / 25e9, gamma=3.0 / 819e9,
 # alpha ~ 16.75us MPI_Allreduce at count=1; per-int time from the large-count
 # column: ~56.2ms at 8.4M ints over p=288 -> beta ~ 1.6ns/B effective.
 PAPER_HYDRA = CommModel(alpha=8e-6, beta=1.6e-9, gamma=0.2e-9, name="paper_hydra")
-
-
-def _dual_tree_height(p: int) -> int:
-    return build_dual_tree(p).max_depth
 
 
 def _single_tree_height(p: int) -> int:
@@ -214,68 +213,99 @@ def hier_time(p: int, m_bytes: float, b: int, model: CommModel,
     return t + dptree_time(g, cur * COMPRESS_FACTOR[compression], b, model)
 
 
+def _hier_stage(p: int, m_bytes: float, group_size=None,
+                compression: str | None = None) -> tuple:
+    """``(ranks, bytes)`` of the dual-root tree a hierarchy pipelines: the
+    inter-group stage over ``p // prod(levels)`` ranks moving the (possibly
+    compressed) shard stripes. ``None`` resolves as ``hier_allreduce`` does
+    (4, then 2, then flat); a degenerate shape pipelines all ``p`` ranks."""
+    from repro.core.topology import as_levels
+    try:
+        levels = as_levels(group_size, p)
+    except (TypeError, ValueError):
+        levels = as_levels(None, p)
+    S = int(np.prod(levels)) if levels else 1
+    if S <= 1 or p % S or p // S == 1:
+        return p, m_bytes
+    return p // S, m_bytes / S * COMPRESS_FACTOR[compression]
+
+
+def _dual_tree_form(p: int) -> tuple:
+    # steps(b) ~ c*b + lat with lat = steps(1) - c; lat == 0 (p=2, the bare
+    # dual exchange) means pipelining buys nothing: b* = 1.
+    topo = build_dual_tree(p)
+    c = float(max(1, len(topo.active_classes())))
+    return _tree_steps(topo, 1) - c, c
+
+
+def _flat(time_fn):
+    """``time_fn(p, m, b, model)`` under the table's uniform signature."""
+    return lambda p, m, b, model, **hier_kw: time_fn(p, m, b, model)
+
+
+@dataclasses.dataclass(frozen=True)
+class Pricing:
+    """One modelled algorithm: ``time(p, m_bytes, b, model, **hier_kw)``
+    (flat ones ignore the hierarchy keywords, ``ring`` also ``b``); its
+    Pipelining-Lemma form ``form(p) -> (lat, c)``, ``steps(b) ~ lat + c*b``,
+    or None when not pipelined; and, for a hierarchy only, ``stage(p,
+    m_bytes, group_size, compression)`` (:func:`_hier_stage`). A hierarchy
+    competes in :func:`best_algorithm` only under a feasible spec."""
+
+    time: Callable[..., float]
+    form: Callable[[int], tuple] | None = None
+    stage: Callable[..., tuple] | None = None
+
+
+# THE table of modelled algorithms. A method is added, repriced or removed
+# here; the autotuner, the auto switch, the probes and the fits read it.
+ALGORITHMS = {
+    "dptree": Pricing(_flat(dptree_time), _dual_tree_form),
+    "sptree": Pricing(_flat(sptree_time),
+                      lambda p: (4 * (_single_tree_height(p) + 1) - 3, 3.0)),
+    "redbcast": Pricing(_flat(redbcast_time),
+                        lambda p: (4 * (_single_tree_height(p) + 1) - 4, 4.0)),
+    "ring": Pricing(lambda p, m, b, model, **hier_kw: ring_time(p, m, model)),
+    "hier": Pricing(hier_time, _dual_tree_form, _hier_stage),
+}
+# The algorithms over all p ranks at once: the autotuner's default
+# candidates and the flat (alpha, beta) fit's methods.
+FLAT_ALGORITHMS = tuple(a for a, e in ALGORITHMS.items() if e.stage is None)
+
+
 @functools.lru_cache(maxsize=4096)
 def optimal_blocks(p: int, m_bytes: float, model: CommModel,
                    algorithm: str = "dptree",
                    group_size=None,
                    compression: str | None = None) -> int:
-    """Pipelining-Lemma block count: balance the +3b alpha term vs beta*m/b.
+    """Pipelining-Lemma block count: balance the +c*b alpha term vs beta*m/b.
 
-    For ``T(b) = (L + c*b)(alpha + beta*m/b)``, the optimum is
+    For ``T(b) = (L + c*b)(alpha + beta*m/b)``, with ``(L, c)`` the
+    algorithm's form in :data:`ALGORITHMS`, the optimum is
     ``b* = sqrt(L * beta * m / (c * alpha))``, refined by the local descent of
     :func:`_refine_blocks` (integer macro-round effects). Clamped to
     [1, m_bytes/64] so a block never goes below 64 bytes (one cache line /
     lane group). ``model`` prices the fabric the pipelined stage runs on —
-    for ``algorithm='hier'`` that is the slow inter-group fabric; the block
-    count is re-derived for the shard-stripe dptree the hierarchy actually
-    pipelines (``p // prod(levels)`` ranks, ``m / prod(levels)`` bytes,
-    halved again under ``compression='bf16'``), NOT reused from the flat
-    optimum — per-level traffic, per-level block count.
+    for a hierarchy that is the slow inter-group fabric; the block count is
+    re-derived for the shard-stripe dptree the hierarchy actually pipelines
+    (its ``stage``), NOT reused from the flat optimum — per-level traffic,
+    per-level block count.
     """
     if p == 1 or m_bytes <= 0:
         return 1
-    if algorithm == "hier":
-        # blocks pipeline the slowest stage: a dptree over num_groups ranks
-        # moving the m/prod(levels)-byte (possibly compressed) shard stripes.
-        # group_size=None resolves the same way hier_allreduce resolves it
-        # (4, then 2, then flat) so the block count matches the shape that
-        # actually executes.
-        from repro.core.topology import as_levels, default_group_size
-        try:
-            levels = as_levels(group_size)
-        except (TypeError, ValueError):
-            levels = None
-        if levels is None:
-            levels = as_levels(default_group_size(p))
-        S = int(np.prod(levels)) if levels else 1
-        if S <= 1 or p % S or p // S == 1:
-            return optimal_blocks(p, m_bytes, model, "dptree")
-        return optimal_blocks(p // S, m_bytes / S * COMPRESS_FACTOR[compression],
-                              model, "dptree")
-    if algorithm == "dptree":
-        topo = build_dual_tree(p)
-        c = float(max(1, len(topo.active_classes())))
-        # steps(b) ~ c*b + lat with lat = steps(1) - c; lat == 0 (p=2, the
-        # bare dual exchange) means pipelining buys nothing: b* = 1.
-        lat = _tree_steps(topo, 1) - c
-        if lat <= 0:
-            return 1
-    elif algorithm == "sptree":
-        h = _single_tree_height(p) + 1
-        lat, c = 4 * h - 3, 3.0
-    elif algorithm == "redbcast":
-        h = _single_tree_height(p) + 1
-        lat, c = 4 * h - 4, 4.0
-    else:
+    entry = ALGORITHMS.get(algorithm)
+    if entry is None or entry.form is None:
         raise ValueError(f"no pipelined form for {algorithm!r}")
-    lat = max(lat, 1)
+    if entry.stage is not None:
+        p_stage, m_stage = entry.stage(p, m_bytes, group_size, compression)
+        return optimal_blocks(p_stage, m_stage, model, "dptree")
+    lat, c = entry.form(p)
+    if lat <= 0:
+        return 1
     beta_eff = model.beta + model.gamma
     b = math.sqrt(lat * beta_eff * m_bytes / (c * model.alpha))
     b = int(max(1, min(b, m_bytes / 64)))
     return _refine_blocks(max(1, b), p, m_bytes, model, algorithm)
-
-
-_TIME_FNS = {}  # populated below; algorithm -> T(p, m_bytes, b, model)
 
 
 def _refine_blocks(b: int, p: int, m_bytes: float, model: CommModel,
@@ -288,7 +318,7 @@ def _refine_blocks(b: int, p: int, m_bytes: float, model: CommModel,
     and +-1 until no neighbor is faster — at termination ``T(b) <= T(b//2)``
     and ``T(b) <= T(2b)`` hold by construction.
     """
-    time_fn = _TIME_FNS[algorithm]
+    time_fn = ALGORITHMS[algorithm].time
     best, t_best = b, time_fn(p, m_bytes, b, model)
     for _ in range(40):
         moved = False
@@ -303,22 +333,16 @@ def _refine_blocks(b: int, p: int, m_bytes: float, model: CommModel,
     return best
 
 
-_TIME_FNS.update({
-    "dptree": dptree_time,
-    "sptree": sptree_time,
-    "redbcast": redbcast_time,
-})
-
-
 def best_algorithm(p: int, m_bytes: float, model: CommModel,
                    group_size=None,
                    intra_model: CommModel | None = None,
                    level_models=None) -> str:
     """Size-adaptive switch (what OpenMPI got wrong in the paper's Table 2).
 
-    Evaluates every implemented algorithm at its own best block size and picks
-    the fastest. Small m -> tree (log-latency); huge m -> ring (bandwidth).
-    With a feasible ``group_size`` hierarchy spec (int or level tuple, see
+    Evaluates every algorithm of :data:`ALGORITHMS` at its own best block
+    size and picks the fastest (the first in table order on a tie). Small
+    m -> tree (log-latency); huge m -> ring (bandwidth). With a feasible
+    ``group_size`` hierarchy spec (int or level tuple, see
     :func:`repro.core.topology.resolve_levels`) the hierarchical composition
     also competes — it wins on heterogeneous fabrics where ``model`` prices
     slow inter-group links and ``intra_model``/``level_models`` fast intra
@@ -326,19 +350,17 @@ def best_algorithm(p: int, m_bytes: float, model: CommModel,
     an explicit ``CollectiveConfig(compress_inter_group=True)`` (via the
     autotuner's extra candidates) opts into it.
     """
-    cands = {
-        "dptree": dptree_time(p, m_bytes, optimal_blocks(p, m_bytes, model, "dptree"), model),
-        "sptree": sptree_time(p, m_bytes, optimal_blocks(p, m_bytes, model, "sptree"), model),
-        "redbcast": redbcast_time(p, m_bytes, optimal_blocks(p, m_bytes, model, "redbcast"), model),
-        "ring": ring_time(p, m_bytes, model),
-    }
     from repro.core.topology import resolve_levels
     lv = resolve_levels(p, group_size) if group_size else None
-    if lv is not None:
-        b = optimal_blocks(p, m_bytes, model, "hier", group_size=lv)
-        cands["hier"] = hier_time(p, m_bytes, b, model, group_size=lv,
-                                  intra_model=intra_model,
-                                  level_models=level_models)
+    cands = {}
+    for name, entry in ALGORITHMS.items():
+        if entry.stage is not None and lv is None:
+            continue
+        b = (optimal_blocks(p, m_bytes, model, name, group_size=lv)
+             if entry.form is not None else 1)
+        cands[name] = entry.time(p, m_bytes, b, model, group_size=lv,
+                                 intra_model=intra_model,
+                                 level_models=level_models)
     return min(cands, key=cands.get)
 
 
@@ -354,11 +376,6 @@ def predicted_table(p: int, sizes_bytes, model: CommModel, b_elems: int = 16000,
     blk_bytes = b_elems * elem_bytes
     for m in sizes_bytes:
         b = max(1, int(math.ceil(m / blk_bytes)))
-        rows.append((
-            m,
-            dptree_time(p, m, b, model),
-            sptree_time(p, m, b, model),
-            redbcast_time(p, m, b, model),
-            ring_time(p, m, model),
-        ))
+        rows.append((m, *(ALGORITHMS[a].time(p, m, b, model)
+                          for a in FLAT_ALGORITHMS)))
     return np.array(rows)

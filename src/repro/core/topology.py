@@ -41,7 +41,6 @@ __all__ = [
     "validate_topology",
     "as_levels",
     "resolve_levels",
-    "resolve_group_size",
     "default_group_size",
 ]
 
@@ -307,16 +306,17 @@ def default_group_size(p: int) -> int:
     return 1
 
 
-def as_levels(spec) -> tuple | None:
+def as_levels(spec, p: int | None = None) -> tuple | None:
     """Normalize a hierarchy spec to a level tuple (or None for 'default').
 
     Accepted forms, all meaning "ring sizes of the intra levels, innermost
-    first": ``None`` (caller resolves a default), an ``int`` (the classic
+    first": ``None`` (the default: :func:`default_group_size` of ``p`` when
+    ``p`` is given, else left to the caller), an ``int`` (the classic
     two-level group size), or a sequence of ints (N-level). Size-1 levels are
     dropped — a one-rank ring is a no-op stage.
     """
     if spec is None:
-        return None
+        return None if p is None else as_levels(default_group_size(p))
     if isinstance(spec, (int, np.integer)):
         spec = (int(spec),)
     lv = tuple(int(s) for s in spec)
@@ -332,20 +332,11 @@ def resolve_levels(p: int, spec=None) -> tuple | None:
     THE single feasibility rule — the auto switch, the cost model, and the
     benches must all consult this."""
     try:
-        lv = as_levels(spec)
+        lv = as_levels(spec, p)
     except (TypeError, ValueError):
         return None
-    if lv is None:
-        lv = as_levels(default_group_size(p))
     S = int(np.prod(lv)) if lv else 1
     return lv if (S > 1 and p % S == 0 and p // S >= 2) else None
-
-
-def resolve_group_size(p: int, group_size=None) -> int | None:
-    """Two-level compatibility wrapper over :func:`resolve_levels`: the ranks
-    per top-level group the hierarchy would execute with, or None."""
-    lv = resolve_levels(p, group_size)
-    return int(np.prod(lv)) if lv else None
 
 
 def _level_ring(p: int, size: int, stride: int) -> tuple:
@@ -371,10 +362,7 @@ def build_hierarchy(p: int, group_size=None) -> HierarchicalTopology:
     """
     if p < 1:
         raise ValueError(f"p must be >= 1, got {p}")
-    lv = as_levels(group_size)
-    if lv is None:
-        lv = as_levels(default_group_size(p))
-    return _build_hierarchy_cached(p, lv)
+    return _build_hierarchy_cached(p, as_levels(group_size, p))
 
 
 @functools.lru_cache(maxsize=512)

@@ -73,6 +73,7 @@ def autotune_warmup(mesh, pcfg, params, leaf_specs=None, *, reps: int = 3,
     from jax.sharding import PartitionSpec as _P
 
     from repro.core import autotune, collectives
+    from repro.core import cost_model as cm
     from repro.core.topology import resolve_levels
 
     cfg = pcfg.collective
@@ -92,9 +93,9 @@ def autotune_warmup(mesh, pcfg, params, leaf_specs=None, *, reps: int = 3,
         sel[pos] = slice(None)
         axis_devs = mesh.devices[tuple(sel)]
         tune_mesh = make_mesh((p,), (ax,), devices=axis_devs)
-        algorithms = autotune._ALGORITHMS
-        if resolve_levels(p, cfg.hier_spec) is not None:
-            algorithms = algorithms + ("hier",)
+        algorithms = (tuple(cm.ALGORITHMS)
+                      if resolve_levels(p, cfg.group_size) is not None
+                      else cm.FLAT_ALGORITHMS)
         for n, dtype in sizes:
             nbytes = n * dtype.itemsize
             X = jnp.zeros((n,), dtype)
@@ -121,7 +122,7 @@ def autotune_warmup(mesh, pcfg, params, leaf_specs=None, *, reps: int = 3,
             res = autotune.tune(
                 runner, p, nbytes, str(jnp.dtype(dtype)),
                 cfg.comm_model.name, cfg.comm_model,
-                algorithms=algorithms, group_size=cfg.hier_spec,
+                algorithms=algorithms, group_size=cfg.group_size,
                 compress_inter_group=cfg.compress_inter_group)
             results.append((ax, nbytes, res))
             if verbose:

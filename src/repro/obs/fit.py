@@ -38,18 +38,10 @@ _UNIT_ALPHA = cm.CommModel(alpha=1.0, beta=0.0, gamma=0.0, name="unit_alpha")
 _UNIT_BETA = cm.CommModel(alpha=0.0, beta=1.0, gamma=0.0, name="unit_beta")
 _ZERO = cm.CommModel(alpha=0.0, beta=0.0, gamma=0.0, name="zero")
 
-# Flat algorithms with a pipelined closed form; ring is handled explicitly
-# (its time ignores the block count).
-_FLAT = ("dptree", "sptree", "redbcast", "ring")
-
-
 def flat_coeffs(method: str, p: int, m_bytes: float, b: int) -> tuple:
     """``(c_alpha, c_beta)`` such that ``T = c_alpha*alpha + c_beta*beta``
     for a flat algorithm at shape ``(p, m_bytes, b)``."""
-    if method == "ring":
-        return (cm.ring_time(p, m_bytes, _UNIT_ALPHA),
-                cm.ring_time(p, m_bytes, _UNIT_BETA))
-    fn = cm._TIME_FNS[method]
+    fn = cm.ALGORITHMS[method].time
     return (fn(p, m_bytes, b, _UNIT_ALPHA), fn(p, m_bytes, b, _UNIT_BETA))
 
 
@@ -95,7 +87,8 @@ def _diag(A, y, x) -> tuple:
     return tuple(float(r) for r in resid), float(rel.max())
 
 
-def fit_alpha_beta(samples, *, methods=_FLAT) -> FitResult:
+def fit_alpha_beta(samples, *,
+                   methods=cm.FLAT_ALGORITHMS) -> FitResult:
     """Fit one ``(alpha, beta)`` pair from timed flat-algorithm samples.
 
     ``samples`` is any iterable of :class:`~repro.obs.probe.ProbeSample`;

@@ -17,7 +17,7 @@ Two sample kinds, because jax runs Python twice:
   the stats reducer (:func:`repro.serving.telemetry.make_stats_reducer`)
   wraps its jitted reduction in ``perf_counter`` + ``block_until_ready``
   when a probe is active, and resolves the method/blocks host-side
-  through the same ``_pick`` the traced code used. Every b=1 stats
+  through the same ``plan`` the traced code used. Every b=1 stats
   reduction in an instrumented run lands one timed sample.
 
 Samples go into a bounded ring buffer (``collections.deque(maxlen=...)``)
@@ -75,19 +75,11 @@ def predict_time(method: str, p: int, nbytes: int, num_blocks: int,
                  intra_model: cm.CommModel | None = None) -> float | None:
     """The cost model's prediction for one collective shape, or None for
     methods it has no closed form for (psum — XLA's own schedule)."""
-    m, b = float(max(nbytes, 1)), max(1, int(num_blocks))
-    if method == "dptree":
-        return cm.dptree_time(p, m, b, model)
-    if method == "sptree":
-        return cm.sptree_time(p, m, b, model)
-    if method == "redbcast":
-        return cm.redbcast_time(p, m, b, model)
-    if method == "ring":
-        return cm.ring_time(p, m, model)
-    if method == "hier":
-        return cm.hier_time(p, m, b, model, group_size=levels,
-                            intra_model=intra_model)
-    return None
+    entry = cm.ALGORITHMS.get(method)
+    if entry is None:
+        return None
+    return entry.time(p, float(max(nbytes, 1)), max(1, int(num_blocks)),
+                      model, group_size=levels, intra_model=intra_model)
 
 
 class CollectiveProbe:

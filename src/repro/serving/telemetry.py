@@ -21,7 +21,7 @@ import dataclasses
 
 import numpy as np
 
-from repro.core.collectives import CollectiveConfig, _pick, all_reduce
+from repro.core.collectives import CollectiveConfig, all_reduce, plan
 from repro.obs import probe as _obs_probe
 
 # field order of the per-tick stats vector (summed across replicas):
@@ -143,21 +143,19 @@ def make_stats_reducer(mesh, axis: str = "data",
         # Timed sample at the host boundary: the jitted body only runs
         # Python at trace time, so wall clocks must bracket the whole
         # dispatch+execute here (block_until_ready pins completion). The
-        # method/blocks are re-resolved host-side through the same _pick
-        # the traced code used, so the sample labels what actually ran.
+        # method/blocks are re-resolved host-side through the same plan the
+        # traced code used, so the sample labels what actually ran.
         import time
 
         import jax
         nbytes = arr.shape[1] * 4
-        algo, nb, hier_spec, _ = _pick(collective.method, p, nbytes,
-                                       collective, np.dtype(np.float32),
-                                       axis)
+        pl = plan(collective, p, nbytes, np.dtype(np.float32),
+                  axis_name=axis)
         t0 = time.perf_counter()
         out = jax.block_until_ready(fn(arr))
         wall = time.perf_counter() - t0
-        probe.note(algo, p, nbytes,
-                   nb if nb is not None else collective.num_blocks or 1,
-                   kind="timed", wall_s=wall, levels=hier_spec, axis=axis)
+        probe.note(pl.algorithm, p, nbytes, pl.num_blocks, kind="timed",
+                   wall_s=wall, levels=pl.group_size, axis=axis)
         return np.asarray(out)
 
     return reduce

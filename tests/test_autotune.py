@@ -117,15 +117,18 @@ def test_auto_method_uses_measured_hit(tmp_cache):
 
     p, nbytes = 8, 1000 * 4
     cfg = co.CollectiveConfig(method="auto")
-    algo0, nb0, _, _ = co._pick("auto", p, nbytes, cfg, "float32")
-    assert nb0 is None  # no cache entry yet: analytic pick
+    pl0 = co.plan(cfg, p, nbytes, "float32")
+    # no cache entry yet: analytic pick
+    assert pl0.algorithm == cm.best_algorithm(p, float(nbytes),
+                                              cfg.comm_model)
+    model2 = co.plan(cfg, p, nbytes * 2, "float32")
     at.get_cache().put(p, nbytes, "float32", cfg.comm_model.name,
                        at.TuneResult("sptree", 11, 3.3e-5))
-    algo, nb, _, _ = co._pick("auto", p, nbytes, cfg, "float32")
-    assert (algo, nb) == ("sptree", 11)
+    pl = co.plan(cfg, p, nbytes, "float32")
+    assert (pl.algorithm, pl.num_blocks) == ("sptree", 11)
     # other sizes still fall through to the model
-    algo2, nb2, _, _ = co._pick("auto", p, nbytes * 2, cfg, "float32")
-    assert nb2 is None and algo2 in ("dptree", "sptree", "redbcast", "ring")
+    assert co.plan(cfg, p, nbytes * 2, "float32") == model2
+    assert model2.algorithm in cm.FLAT_ALGORITHMS
 
 
 def test_auto_degrades_on_stale_or_infeasible_hit(tmp_cache):
@@ -136,26 +139,26 @@ def test_auto_degrades_on_stale_or_infeasible_hit(tmp_cache):
 
     p, nbytes = 8, 2048
     cfg = co.CollectiveConfig(method="auto")
+    model = co.plan(cfg, p, nbytes, "float32")      # empty cache
+    assert model.algorithm != "hier"
     # hier measured with a group shape that can't run at p=8
     at.get_cache().put(p, nbytes, "float32", cfg.comm_model.name,
                        at.TuneResult("hier", 4, 1e-5, group_size=5))
-    algo, nb, gs, _ = co._pick("auto", p, nbytes, cfg, "float32")
-    assert algo != "hier" and nb is None
+    assert co.plan(cfg, p, nbytes, "float32") == model
     # malformed entry naming 'auto' itself
     at.get_cache().put(p, nbytes, "float32", cfg.comm_model.name,
                        at.TuneResult("auto", 1, 1e-5))
-    algo, nb, _, _ = co._pick("auto", p, nbytes, cfg, "float32")
-    assert algo in ("dptree", "sptree", "redbcast", "ring")
+    assert co.plan(cfg, p, nbytes, "float32") == model
     # feasible hier hit replays ITS measured group size (as a level spec)
     at.get_cache().put(p, nbytes, "float32", cfg.comm_model.name,
                        at.TuneResult("hier", 2, 1e-5, group_size=2))
-    algo, nb, gs, compress = co._pick("auto", p, nbytes, cfg, "float32")
-    assert (algo, nb, gs, compress) == ("hier", 2, (2,), False)
+    assert co.plan(cfg, p, nbytes, "float32") == co.Plan("hier", 2, (2,),
+                                                         False)
     # N-level hit replays its measured level tuple
     at.get_cache().put(p, nbytes, "float32", cfg.comm_model.name,
                        at.TuneResult("hier", 2, 1e-5, group_size=(2, 2)))
-    algo, nb, gs, compress = co._pick("auto", p, nbytes, cfg, "float32")
-    assert (algo, nb, gs, compress) == ("hier", 2, (2, 2), False)
+    assert co.plan(cfg, p, nbytes, "float32") == co.Plan("hier", 2, (2, 2),
+                                                         False)
 
 
 def test_compressed_hit_needs_local_opt_in(tmp_cache):
@@ -169,11 +172,11 @@ def test_compressed_hit_needs_local_opt_in(tmp_cache):
                        at.TuneResult("hier", 3, 1e-5, group_size=(2, 2),
                                      compressed=True))
     plain = co.CollectiveConfig(method="auto")
-    algo, nb, gs, compress = co._pick("auto", p, nbytes, plain, "float32")
-    assert algo != "hier" and not compress  # falls through to the model
+    pl = co.plan(plain, p, nbytes, "float32")
+    assert pl.algorithm != "hier" and not pl.compress  # the model's pick
     opted = co.CollectiveConfig(method="auto", compress_inter_group=True)
-    algo, nb, gs, compress = co._pick("auto", p, nbytes, opted, "float32")
-    assert (algo, nb, gs, compress) == ("hier", 3, (2, 2), True)
+    assert co.plan(opted, p, nbytes, "float32") == co.Plan("hier", 3, (2, 2),
+                                                           True)
 
 
 def test_compressed_candidates_and_tune_roundtrip(tmp_cache):
@@ -220,32 +223,53 @@ def test_hier_rejects_non_commutative_op(tmp_cache):
         co.all_reduce(jnp.ones((16,)), "data", 8, cfg, op=custom)
 
 
-def test_degrade_for_op_gating():
+def test_degrade_for_op_gating(tmp_cache, monkeypatch):
     """Under 'auto', every pick that cannot run the operator falls back to
     the rank-ordered dptree; explicit requests keep/raise their contracts."""
-    import jax
     import jax.numpy as jnp
     import pytest as _pytest
-    from repro.core.collectives import _degrade_for_op
+    from repro.core import collectives as co
 
     def custom(a, b):
         return a + b
 
+    p, nbytes = 8, 2048
+    auto = co.CollectiveConfig(method="auto", group_size=2)
+
+    def algo(config, op, algorithm=None):
+        # an autotune hit makes 'auto' pick ``algorithm`` before the degrade
+        if algorithm is not None:
+            at.get_cache().put(p, nbytes, "float32", config.comm_model.name,
+                               at.TuneResult(algorithm, 3, 1e-5,
+                                             group_size=2))
+        return co.plan(config, p, nbytes, "float32", op).algorithm
+
     # auto: degrade, never raise
-    assert _degrade_for_op("ring", custom, "auto") == "dptree"
-    assert _degrade_for_op("hier", custom, "auto") == "dptree"
-    assert _degrade_for_op("psum", custom, "auto") == "dptree"
-    assert _degrade_for_op("psum", jnp.multiply, "auto") == "dptree"
+    assert algo(auto, custom, "ring") == "dptree"
+    assert algo(auto, custom, "hier") == "dptree"
+    # psum is never an auto pick: the cost model does not price it, and a
+    # cache entry naming it is a miss
+    at.get_cache().put(p, nbytes, "float32", auto.comm_model.name,
+                       at.TuneResult("psum", 3, 1e-5))
+    assert at.lookup(p, nbytes, "float32", auto.comm_model.name) is None
+    # ... and a psum pick that reached 'auto' anyway degrades on an op XLA
+    # has no primitive for
+    with monkeypatch.context() as m:
+        m.setattr(co.autotune, "lookup",
+                  lambda *a, **k: at.TuneResult("psum", 3, 1e-5))
+        assert algo(auto, custom) == "dptree"
+        assert algo(auto, jnp.multiply) == "dptree"
+        assert algo(auto, jnp.minimum) == "psum"
     # supported combinations pass through untouched
-    assert _degrade_for_op("ring", jnp.maximum, "auto") == "ring"
-    assert _degrade_for_op("hier", jnp.add, "auto") == "hier"
-    assert _degrade_for_op("psum", jnp.minimum, "psum") == "psum"
-    assert _degrade_for_op("dptree", custom, "dptree") == "dptree"
+    assert algo(auto, jnp.maximum, "ring") == "ring"
+    assert algo(auto, jnp.add, "hier") == "hier"
+    assert algo(co.CollectiveConfig(method="psum"), jnp.minimum) == "psum"
+    assert algo(co.CollectiveConfig(method="dptree"), custom) == "dptree"
     # explicit hier with an unknown op is a loud error
     with _pytest.raises(ValueError, match="commutative"):
-        _degrade_for_op("hier", custom, "hier")
+        algo(co.CollectiveConfig(method="hier"), custom)
     # explicit ring keeps its documented (commutative-ops) behavior
-    assert _degrade_for_op("ring", custom, "ring") == "ring"
+    assert algo(co.CollectiveConfig(method="ring"), custom) == "ring"
 
 
 def test_lookup_respects_disable_env(tmp_cache, monkeypatch):

@@ -313,25 +313,24 @@ def test_steal_queued_preserves_fifo():
 
 def test_corrupt_autotune_entry_degrades_to_miss(tmp_path):
     from repro.core import autotune as at
-    from repro.core.collectives import CollectiveConfig, _pick
+    from repro.core.collectives import CollectiveConfig, plan
     path = str(tmp_path / "autotune.json")
     try:
         at.set_cache_path(path)
         cfg = CollectiveConfig(method="auto")
+        model = plan(cfg, 8, 4096, "float32")         # empty cache
         at.get_cache().put(8, 4096, "float32", cfg.comm_model.name,
                            at.TuneResult("sptree", 4, 1e-6))
         at.get_cache().save()
-        algo, blocks, _, _ = _pick("auto", 8, 4096, cfg, "float32")
-        assert (algo, blocks) == ("sptree", 4)        # measured winner
+        pl = plan(cfg, 8, 4096, "float32")
+        assert (pl.algorithm, pl.num_blocks) == ("sptree", 4)  # measured
         victim = corrupt_autotune_cache(path, seed=0)
         assert victim.startswith("p=8/nbytes=4096/dtype=float32/")
         at.reset_cache()                              # drop the stale handle
         at.set_cache_path(path)
         assert at.lookup(8, 4096, "float32", cfg.comm_model.name) is None
         # the corrupted entry degrades to the analytic cost-model switch
-        algo, blocks, _, _ = _pick("auto", 8, 4096, cfg, "float32")
-        assert algo in ("dptree", "sptree", "redbcast", "ring", "hier")
-        assert blocks is None                         # model pick, not cache
+        assert plan(cfg, 8, 4096, "float32") == model  # model, not cache
     finally:
         at.set_cache_path(None)
 

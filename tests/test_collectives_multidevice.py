@@ -155,20 +155,24 @@ def test_block_major_payloads_match_plain_reduction(dtype):
             return {{"n": a["n"] * b["n"], "s": a["s"] * b["n"] + b["s"]}}
         parts = {{"n": jnp.asarray(rng.integers(0, 2, (p, 3000)).astype(dt)),
                   "s": jnp.asarray(rng.integers(0, 4, (p, 3000)).astype(dt))}}
-        sm = shard_map(lambda t: jax.tree.map(lambda l: l[None],
-                           structured_all_reduce(jax.tree.map(lambda l: l[0], t),
-                                                 "data", p, comb)),
-                       mesh=mesh, in_specs=P("data"), out_specs=P("data"))
-        out = jax.jit(sm)(parts)
         want = {{k: np.asarray(v[0]).astype(np.float64)
                  for k, v in parts.items()}}
         for r in range(1, p):
             want = comb(want, {{k: np.asarray(v[r]).astype(np.float64)
                                 for k, v in parts.items()}})
-        for k in want:
-            for r in range(p):
-                np.testing.assert_array_equal(
-                    np.asarray(out[k][r]).astype(np.float64), want[k])
+        # every method the collective layer runs under any associative op
+        for method in ("dptree", "sptree", "redbcast"):
+            sm = shard_map(lambda t: jax.tree.map(lambda l: l[None],
+                               structured_all_reduce(
+                                   jax.tree.map(lambda l: l[0], t),
+                                   "data", p, comb, method=method)),
+                           mesh=mesh, in_specs=P("data"), out_specs=P("data"))
+            out = jax.jit(sm)(parts)
+            for k in want:
+                for r in range(p):
+                    np.testing.assert_array_equal(
+                        np.asarray(out[k][r]).astype(np.float64), want[k],
+                        err_msg=method)
         print("ok")
     """)
 
@@ -308,12 +312,12 @@ def test_hier3_matches_reference_all_ops():
 
 
 def test_hier3_via_collective_config_and_2d_payload():
-    """levels= spec through the public all_reduce, incl. a 2-D lanes payload
-    (the gradient-bucket layout)."""
+    """A level-tuple group_size through the public all_reduce, incl. a 2-D
+    lanes payload (the gradient-bucket layout)."""
     run_sub("""
         from repro.core.collectives import CollectiveConfig, all_reduce
         rng = np.random.default_rng(12)
-        cfg = CollectiveConfig(method="hier", levels=(2, 2))
+        cfg = CollectiveConfig(method="hier", group_size=(2, 2))
         for shape in ((257,), (37, 8)):
             X = rng.standard_normal((p,) + shape).astype(np.float32)
             spec = P("data", *([None] * len(shape)))

@@ -286,7 +286,7 @@ def test_autotune_tune_threads_axis_into_result(tmp_path):
 
 
 def test_collectives_pick_consults_axis_scoped_entry(tmp_path, monkeypatch):
-    """``_pick`` under method='auto' probes the cache with the reduction's
+    """``plan`` under method='auto' probes the cache with the reduction's
     own axis name, so a 'tp' winner is replayed on the tp axis and ignored
     on 'data'."""
     monkeypatch.setenv("REPRO_AUTOTUNE_CACHE", str(tmp_path / "at.json"))
@@ -297,11 +297,11 @@ def test_collectives_pick_consults_axis_scoped_entry(tmp_path, monkeypatch):
         at.get_cache().put(4, 4096, "float32", "tpu_v5e_ici",
                            at.TuneResult("redbcast", 3, 1e-6, axis="tp"))
         cfg = C.CollectiveConfig(method="auto")
-        algo_tp, nb_tp, _, _ = C._pick("auto", 4, 4096, cfg, "float32", "tp")
-        assert (algo_tp, nb_tp) == ("redbcast", 3)
-        algo_dp, nb_dp, _, _ = C._pick("auto", 4, 4096, cfg, "float32",
-                                       "data")
-        assert nb_dp is None                     # model fallback, not replay
+        model = C.plan(cfg, 4, 4096, "float32")  # no axis: legacy key only
+        tp = C.plan(cfg, 4, 4096, "float32", axis_name="tp")
+        assert (tp.algorithm, tp.num_blocks) == ("redbcast", 3)
+        # model fallback, not replay
+        assert C.plan(cfg, 4, 4096, "float32", axis_name="data") == model
     finally:
         at.reset_cache()
 
@@ -378,14 +378,26 @@ _PRELUDE = """
     from repro.serving.sampling import SamplingParams
     from repro.serving.speculative import SpecParams
 
-    picks = []                 # (method, p, nbytes, axis, algo, num_blocks)
-    _orig_pick = C._pick
-    def _rec(method, p, nbytes, config, dtype, axis_name=None):
-        out = _orig_pick(method, p, nbytes, config, dtype, axis_name)
-        picks.append((method, int(p), int(nbytes), axis_name, out[0],
-                      out[1]))
+    # (method, p, nbytes, axis, algo, num_blocks, hits): ``hits`` are the
+    # autotune cache probes made while planning that reduction which
+    # returned an entry, as ((p, nbytes, dtype, fabric, axis), entry).
+    picks = []
+    lookups = []
+    _orig_lookup = at.lookup
+    def _rec_lookup(p, nbytes, dtype, topology, axis=None):
+        out = _orig_lookup(p, nbytes, dtype, topology, axis)
+        lookups.append(((p, nbytes, dtype, topology, axis), out))
         return out
-    C._pick = _rec
+    C.autotune.lookup = _rec_lookup
+    _orig_plan = C.plan
+    def _rec(config, p, nbytes, dtype, op=jnp.add, axis_name=None):
+        n = len(lookups)
+        out = _orig_plan(config, p, nbytes, dtype, op, axis_name)
+        hits = [(k, e) for k, e in lookups[n:] if e is not None]
+        picks.append((config.method, int(p), int(nbytes), axis_name,
+                      out.algorithm, out.num_blocks, hits))
+        return out
+    C.plan = _rec
 
     def run_engine(cfg, tp, reqs, collective=None, drafter=True, seed=1):
         if tp == 1:
@@ -445,11 +457,17 @@ def test_tp_streams_bit_identical_arch_sampling_spec_matrix(tmp_path):
             psum = run_engine(cfg, 2, reqs,
                               collective=CollectiveConfig(method="psum"))
             assert psum == ref, (arch, ref, psum)
-            # the seeded decode-payload entry was replayed as dptree
+            # the seeded decode-payload entry was replayed as dptree: every
+            # such pick found the entry in the cache (a cost-model pick of
+            # the same setting would not) and ran what it holds
             hits = [pk for pk in picks
                     if pk[3] == "tp" and pk[2] == nb and pk[0] == "auto"]
-            assert hits and all(a == "dptree" and b == 1
-                                for (_, _, _, _, a, b) in hits), (arch, hits)
+            key = (2, nb, "float32", "tpu_v5e_ici", "tp")
+            assert hits and all(
+                a == "dptree" and b == 1 and [k for k, _ in found] == [key]
+                and (found[0][1].algorithm, found[0][1].num_blocks)
+                == ("dptree", 1)
+                for (_, _, _, _, a, b, found) in hits), (arch, hits)
             picks.clear()
             print("ARCH-OK", arch)
         print("MATRIX OK")
