@@ -22,7 +22,8 @@ from repro.core import autotune
 from repro.core import cost_model as cm
 from repro.core.dptree import (_COMMUTATIVE_OPS, dptree_allreduce,
                                hier_allreduce, redbcast_allreduce,
-                               ring_allreduce, sptree_allreduce)
+                               ring_allreduce, sptree_allreduce, tree_rounds)
+from repro.core.topology import build_dual_tree, build_single_tree
 from repro.obs import probe as _obs_probe
 
 __all__ = [
@@ -40,11 +41,13 @@ __all__ = [
 class _Method:
     """One runnable method: ``run(x, axis_name, p, plan, op, carry_spec)``
     and the operators it accepts (None: any associative op). An explicit
-    request with another op raises in :func:`plan` only when ``strict``."""
+    request with another op raises in :func:`plan` only when ``strict``.
+    ``tree(p)`` is the topology a tree-engine method runs over."""
 
     run: Callable[..., jax.Array]
     ops: Any = None
     strict: bool = False
+    tree: Callable[[int], Any] | None = None
 
 
 # XLA primitive equivalent per supported elementwise op (psum-family).
@@ -68,9 +71,11 @@ def _psum(x, axis_name, p, pl, op, carry_spec):
 # ``cost_model.ALGORITHMS`` (which prices every method but psum).
 _RUNNERS = {
     "dptree": _Method(lambda x, ax, p, pl, op, carry_spec: dptree_allreduce(
-        x, ax, p, num_blocks=pl.num_blocks, op=op, carry_spec=carry_spec)),
+        x, ax, p, num_blocks=pl.num_blocks, op=op, carry_spec=carry_spec),
+        tree=build_dual_tree),
     "sptree": _Method(lambda x, ax, p, pl, op, carry_spec: sptree_allreduce(
-        x, ax, p, num_blocks=pl.num_blocks, op=op, carry_spec=carry_spec)),
+        x, ax, p, num_blocks=pl.num_blocks, op=op, carry_spec=carry_spec),
+        tree=build_single_tree),
     "redbcast": _Method(
         lambda x, ax, p, pl, op, carry_spec: redbcast_allreduce(
             x, ax, p, num_blocks=pl.num_blocks, op=op)),
@@ -296,9 +301,12 @@ def all_reduce(x: jax.Array, axis_name: str, p: int,
         # Trace-time note: this Python body runs once per compilation, so
         # the sample records WHAT was picked (algorithm, blocks, shape) —
         # wall time comes from host-boundary timed samples (repro.obs.probe).
+        tree = _RUNNERS[pl.algorithm].tree
+        rounds = None if tree is None else tree_rounds(
+            tree(p), max(1, min(pl.num_blocks, flat.shape[0])), op)
         probe.note(pl.algorithm, p, nbytes, pl.num_blocks,
                    dtype=str(flat.dtype), kind="trace", levels=pl.group_size,
-                   axis=axis_name)
+                   axis=axis_name, rounds=rounds)
     out = _RUNNERS[pl.algorithm].run(flat, axis_name, p, pl, op, carry_spec)
     if out.ndim == 2:
         out = _lane_shard(out)

@@ -3,31 +3,29 @@
 All functions here must be called *inside* a ``jax.shard_map`` region that is
 manual over ``axis_name``. Device-varying control is expressed with
 ``axis_index`` + gathers from host-built topology constants; the three
-static edge classes become three pairs of ``ppermute`` permutations executed
-per macro-round inside a ``lax.scan``.
+static edge classes become up to three pairs of ``ppermute`` permutations
+inside a ``lax.scan``.
 
-Cost shape (matching the paper's model): each macro-round moves one pipeline
-block per active edge *in both directions at once* — the up-permutation carries
-partial blocks toward the roots while the down-permutation carries finished
-result blocks toward the leaves, i.e. the "telephone-like" bidirectional
-exchange realized on full-duplex ICI links.
+The tree engine has two schedules, chosen by the operator:
 
-The shared tree engine is *fused*: the three edge-class steps of a macro-round
-share one slice/update plumbing scheme, leaving THREE dynamic slices per step
-(``up_out``, ``down_out``, ``cur_b``) where the seed engine traced five —
+* commutative operators (add, max, min, mul) run *concurrent rounds*
+  (:func:`_rounds_allreduce`): each round issues every edge class's up and
+  down permutations together, each carrying a block finished in an earlier
+  round, so a node's links to its children, its parent and the other root
+  all move at once; the blocks that arrived in the previous round are folded
+  in while these are on the wire, the children's partials and the node's own
+  block in one elementwise pass that XLA fuses into the block's in-place
+  write. About ``b + 4 * max_depth`` rounds
+  (:meth:`~repro.core.topology.TreeTopology.num_rounds`).
+* merely associative operators keep the paper's one-ported schedule in its
+  exact seed order (Algorithm 1's ``t (.) Y`` / lower-root ``Y (.) t``
+  rules): per macro-round one step per active edge class, each step's
+  transfer waiting on the previous step's write.
 
-* one ``take(jC)`` feeds both the C-role up-send and the root's dual-combine
-  (the seed engine materialized that dynamic slice twice per step);
-* masked writes land in a scratch block row instead of read-modify-writing
-  the current value, removing the read of the overwritten block (the seed's
-  fifth slice) — idle steps write garbage to row ``b``, which is dropped;
-* for commutative operators the child0 partial received at a node's A-step is
-  *deferred* in a carried register and folded into the B-step's combine, so
-  the two child combines plus the local block become ONE three-operand
-  elementwise pass, which XLA fuses into the block's in-place write, and the
-  root's dual-combine likewise rides that same pass instead of a second one.
-
-(The slice budget is pinned by ``test_fused_engine_hlo_slice_count``.)
+Both mask their writes into a scratch block row ``b`` instead of
+read-modifying the current value, so an idle write costs no extra read.
+(The concurrent round's slice budget is pinned by
+``test_fused_engine_hlo_slice_count``.)
 
 Block layout (:func:`_blockify`): the ``b`` blocks and a scratch block lie in
 one buffer with the block index first. A TPU tiles an array's two minor dims
@@ -39,9 +37,6 @@ least one tile is therefore laid out block-major, ``(b + 1, blk/128, 128)``
 with ``blk`` rounded up to whole tiles: one pad in, bitcasts in and out, and
 each take or put touches its own block only. Shorter blocks, 2-D lane-sharded
 and higher-rank payloads, and unknown operators keep ``(b + 1, blk, ...)``.
-
-Non-commutative (merely associative) operators keep the exact seed ordering
-(Algorithm 1's ``t (.) Y`` / lower-root ``Y (.) t`` rules) on a general path.
 
 Implemented algorithms:
 
@@ -64,9 +59,10 @@ import numpy as np
 
 from repro.core.topology import (NO_NODE, HierarchicalTopology, TreeTopology,
                                  build_dual_tree, build_hierarchy,
-                                 build_single_tree)
+                                 build_single_tree, mirror_upper_trees)
 
 __all__ = [
+    "tree_rounds",
     "dptree_allreduce",
     "sptree_allreduce",
     "redbcast_allreduce",
@@ -76,10 +72,9 @@ __all__ = [
 
 Op = Callable[[jax.Array, jax.Array], jax.Array]
 
-# Operators the fused engine may reassociate/commute, by kernel name.
+# Operators the concurrent rounds may reassociate and commute, by kernel name.
 _COMMUTATIVE_OPS = {jnp.add: "add", jnp.maximum: "max", jnp.minimum: "min",
                     jnp.multiply: "mul"}
-_OPS_BY_NAME = {v: k for k, v in _COMMUTATIVE_OPS.items()}
 
 
 def _op_identity(op_name: str, dtype) -> jax.Array:
@@ -250,20 +245,28 @@ def _ring_all_gather(H, axis_name, idx, size, perm, sg, carry_spec=None):
     return H
 
 
+def _commutes(op: Op, op_rev: Op | None = None) -> bool:
+    """Whether the tree engine may run the concurrent rounds for ``op``: an
+    operator it knows to commute, with no separate reversed form."""
+    return (op_rev or op) is op and op in _COMMUTATIVE_OPS
+
+
 def _tree_allreduce(x: jax.Array, axis_name: str, topo: TreeTopology,
                     num_blocks: int, op: Op, op_rev: Op | None,
                     carry_spec=None) -> jax.Array:
-    """Shared fused engine for the dual-root and single-tree variants."""
+    """Shared engine for the dual-root and single-tree variants: the
+    concurrent rounds for commutative operators, else the seed-ordered
+    steps."""
     p = topo.p
     if p == 1:
         return x
     b = int(num_blocks)
+    if _commutes(op, op_rev):
+        return _rounds_allreduce(x, axis_name, topo, b, op, carry_spec)
     op_rev = op_rev or op
-    op_name = _COMMUTATIVE_OPS.get(op) if op_rev is op else None
-    fused = op_name is not None
     # Scratch block row b: masked writes land here instead of paying a
     # read-modify-write of the current value (two extra dynamic slices).
-    Y, m = _blockify(x, b, elementwise=fused)
+    Y, m = _blockify(x, b, elementwise=False)
     pin = _lane_pinner(Y, x)
 
     i = jax.lax.axis_index(axis_name)
@@ -279,58 +282,6 @@ def _tree_allreduce(x: jax.Array, axis_name: str, topo: TreeTopology,
     classes = topo.active_classes()
     R = topo.num_macro_rounds(b)
     in_range = lambda j: (j >= 0) & (j < b)
-
-    if fused:
-        f = _OPS_BY_NAME[op_name]
-        ident = jnp.full(Y.shape[1:], _op_identity(op_name, Y.dtype), Y.dtype)
-
-    def step_fused(Y, pend, s, e):
-        """One edge-class step. A node's roles rotate A->B->C over consecutive
-        global steps (residue of ``phi`` mod 3), so the child0 partial it
-        receives at its A-step can be deferred in the carried ``pend`` and
-        folded into the NEXT step — its B-slot, same block index — making the
-        two child combines plus the local block a single three-operand pass
-        that the root's dual-combine also rides (one HBM pass, not two)."""
-        rel = s - phi
-        mod = jnp.mod(rel, 3)
-        jA = jnp.floor_divide(rel, 3)
-        jB = jnp.floor_divide(rel - 1, 3)
-        jC = jnp.floor_divide(rel - 2, 3)
-        slotB = mod == 1
-        amA = (mod == 0) & has_c0
-        amC_par = (mod == 2) & has_par
-        amC_root = (mod == 2) & is_root & dual_active
-        jAB = jnp.where(mod == 0, jA, jB)
-
-        # --- payloads (one slice each; up_out doubles as the root's block) --
-        up_out = _take(Y, jC, b)          # C-role: partial block up / dual
-        down_out = _take(Y, jAB - dep - 1, b)  # A/B-role: result block down
-        # --- the bidirectional exchange (one full-duplex step) -------------
-        t_up = jax.lax.ppermute(up_out, axis_name, topo.up_pairs[e])
-        t_down = (jax.lax.ppermute(down_out, axis_name, topo.down_pairs[e])
-                  if topo.down_pairs[e] else jnp.zeros_like(down_out))
-        # --- one fused combine pass ----------------------------------------
-        # No operand masking: wherever the write below lands in a REAL row,
-        # t_up is a genuine partial (a parent's in-range jA/jB coincides with
-        # its child's in-range jC send on the shared edge, and the dual roots
-        # share phi), and pend is identity except at the B-slot by
-        # construction. Writes that would see stale t_up are masked to the
-        # scratch row, so their comb value is discarded.
-        validA = amA & in_range(jA)
-        cur_b = _take(Y, jB, b)
-        # plain jnp: XLA fuses it, with the select below, into the in-place
-        # write of the block (a Pallas combine3 here was slower on the chip)
-        comb = f(f(t_up, pend), jnp.where(slotB, cur_b, up_out))
-        new_pend = jnp.where(validA, t_up, ident)
-        # --- masked write (scratch row when idle) --------------------------
-        jRecv = jC - dep                  # result block index from the parent
-        upd_val = jnp.where(amC_par, t_down, comb)
-        upd_idx = jnp.where(slotB, jB, jnp.where(amC_root, jC, jRecv))
-        do_upd = ((slotB & has_c1 & in_range(jB))
-                  | (amC_root & in_range(jC))
-                  | (amC_par & in_range(jRecv)))
-        row = jnp.where(do_upd, jnp.clip(upd_idx, 0, b - 1), b)
-        return _put(Y, upd_val, row), new_pend
 
     def step_general(Y, s, e):
         """Seed-ordered path for non-commutative operators (Alg. 1 rules)."""
@@ -365,34 +316,140 @@ def _tree_allreduce(x: jax.Array, axis_name: str, topo: TreeTopology,
         row = jnp.where(do_upd, jnp.clip(upd_idx, 0, b - 1), b)
         return _put(Y, upd_val, row)
 
-    pend_spec = None
+    def macro_round(Y, r):
+        s0 = 3 * r
+        for e in classes:
+            Y = step_general(Y, s0 + e, e)
+        return pin(Y, carry_spec), ()
+
+    Y, _ = jax.lax.scan(macro_round,
+                        _varying(pin(Y, carry_spec), axis_name),
+                        jnp.arange(R, dtype=jnp.int32))
+    return _unblockify(Y, m, x.shape[1:])
+
+
+def _rounds_allreduce(x: jax.Array, axis_name: str, topo: TreeTopology,
+                      b: int, op: Op, carry_spec=None) -> jax.Array:
+    """The tree engine for commutative operators: one transfer round per
+    pipeline block, every edge class at once (module docstring).
+
+    Round ``r`` sends blocks finished in earlier rounds, at the offsets of
+    :meth:`TreeTopology.round_offsets`: each node's partial up (the roots'
+    to each other) and its result down, through one ``ppermute`` per edge
+    class and direction, none waiting on another. While they are on the
+    wire, the blocks that arrived in round ``r - 1`` are folded in: the
+    children's partials into the node's own block (one pass, fused by XLA
+    into the block's in-place write), and the other root's partial into a
+    root's block, or the parent's result block written in place.
+
+    The upper trees are placed in reverse (:func:`mirror_upper_trees`), so
+    the roots are rank neighbours: on a ring-ordered TPU axis their exchange
+    then takes one hop of its own instead of sharing a link with a root's
+    down send, which all-at-once rounds would otherwise wait on."""
+    topo = mirror_upper_trees(topo)
+    p = topo.p
+    op_name = _COMMUTATIVE_OPS[op]
+    Y, m = _blockify(x, b, elementwise=True)
+    pin = _lane_pinner(Y, x)
+    blk_spec = None
     if carry_spec is not None:
         from jax.sharding import PartitionSpec as _P
-        pend_spec = _P(*tuple(carry_spec)[1:])  # carry_spec covers (b, ...)
+        blk_spec = _P(*tuple(carry_spec)[1:])  # carry_spec covers (b, ...)
+    ident = jnp.full(Y.shape[1:], _op_identity(op_name, Y.dtype), Y.dtype)
 
-    if fused:
-        def macro_round(carry, r):
-            Y, pend = carry
-            s0 = 3 * r
-            for e in classes:
-                Y, pend = step_fused(Y, pend, s0 + e, e)
-            return (pin(Y, carry_spec), pin(pend, pend_spec)), ()
+    # Who receives what in each permutation: a child's partial or the other
+    # root's partial (both ride the up-permutations), the parent's result.
+    up = [e for e in range(3) if topo.up_pairs[e]]
+    down = [e for e in range(3) if topo.down_pairs[e]]
+    recv = {}
+    for e in up:
+        for src, dst in topo.up_pairs[e]:
+            kind = "child" if topo.parent[src] == dst else "partner"
+            recv.setdefault(f"{kind}{e}", np.zeros(p, bool))[dst] = True
+    for e in down:
+        for src, dst in topo.down_pairs[e]:
+            recv.setdefault(f"parent{e}", np.zeros(p, bool))[dst] = True
+    of = lambda kind: [e for e in range(3) if f"{kind}{e}" in recv]
 
-        (Y, _), _ = jax.lax.scan(
-            macro_round, (_varying(pin(Y, carry_spec), axis_name),
-                          _varying(ident, axis_name)),
-            jnp.arange(R, dtype=jnp.int32))
-    else:
-        def macro_round(Y, r):
-            s0 = 3 * r
-            for e in classes:
-                Y = step_general(Y, s0 + e, e)
-            return pin(Y, carry_spec), ()
+    # Per-node constants, one lookup: the block each round sends up
+    # (r - lift) and down (r - drop), and the blocks it folds in from the
+    # children (r - lift + 1) and from the other root or the parent
+    # (r - off_d).
+    lift, drop = topo.round_offsets()
+    is_root = topo.parent == NO_NODE
+    cols = {"lift": lift, "drop": drop,
+            "fold_c": np.any([np.zeros(p, bool)]
+                             + [recv[f"child{e}"] for e in of("child")], 0),
+            "off_d": np.where(is_root, lift + 1, drop - 1),
+            "fold_d": ~is_root | topo.exchanges,
+            "root": is_root, **recv}
+    table = np.stack([np.asarray(v, np.int32) for v in cols.values()], 1)
+    row = _const(table, jax.lax.axis_index(axis_name))
+    c = {k: row[n] for n, k in enumerate(cols)}
+    on = lambda k: c[k] != 0
+    in_range = lambda j: (j >= 0) & (j < b)
 
-        Y, _ = jax.lax.scan(macro_round,
-                            _varying(pin(Y, carry_spec), axis_name),
-                            jnp.arange(R, dtype=jnp.int32))
-    return _unblockify(Y, m, x.shape[1:])
+    def pick(kind, vals):
+        """The value from the one permutation a node receives it in."""
+        es = of(kind)
+        out = vals[es[-1]]
+        for e in es[:-1]:
+            out = jnp.where(on(f"{kind}{e}"), vals[e], out)
+        return out
+
+    def fold(Y, got, r):
+        """Write what arrived in round ``r - 1`` into its blocks."""
+        t_up, t_down = got
+        jc = r - c["lift"] + 1
+        acc = _take(Y, jc, b)
+        for e in of("child"):
+            acc = op(acc, jnp.where(on(f"child{e}"), t_up[e], ident))
+        do = on("fold_c") & in_range(jc)
+        Y = _put(Y, acc, jnp.where(do, jnp.clip(jc, 0, b - 1), b))
+
+        jd = r - c["off_d"]
+        vals = []
+        if of("partner"):
+            vals.append(op(_take(Y, jd, b), pick("partner", t_up)))
+        if of("parent"):
+            vals.append(pick("parent", t_down))
+        val = jnp.where(on("root"), *vals) if len(vals) == 2 else vals[0]
+        do = on("fold_d") & in_range(jd)
+        return _put(Y, val, jnp.where(do, jnp.clip(jd, 0, b - 1), b))
+
+    def pins(got):
+        return jax.tree.map(lambda v: pin(v, blk_spec), got)
+
+    def round_(carry, r):
+        Y, got = carry
+        # The barrier keeps XLA from fusing these takes into the fold's
+        # in-place writes, which would hold the permutes back behind them.
+        up_out, down_out, Y = jax.lax.optimization_barrier(
+            (_take(Y, r - c["lift"], b), _take(Y, r - c["drop"], b), Y))
+        new = ({e: jax.lax.ppermute(up_out, axis_name, topo.up_pairs[e])
+                for e in up},
+               {e: jax.lax.ppermute(down_out, axis_name, topo.down_pairs[e])
+                for e in down})
+        return (pin(fold(Y, got, r), carry_spec), pins(new)), ()
+
+    blank = _varying(jnp.zeros_like(ident), axis_name)
+    T = topo.num_rounds(b)
+    (Y, got), _ = jax.lax.scan(
+        round_, (_varying(pin(Y, carry_spec), axis_name),
+                 pins(({e: blank for e in up}, {e: blank for e in down}))),
+        jnp.arange(T, dtype=jnp.int32))
+    return _unblockify(fold(Y, got, jnp.int32(T)), m, x.shape[1:])
+
+
+def tree_rounds(topo: TreeTopology, num_blocks: int, op: Op) -> int:
+    """Transfer rounds :func:`_tree_allreduce` runs for ``op`` over ``b``
+    blocks: the concurrent rounds for a commutative operator, else one
+    one-ported step per active edge class per macro-round."""
+    if topo.p == 1:
+        return 0
+    if _commutes(op):
+        return topo.num_rounds(num_blocks)
+    return topo.num_macro_rounds(num_blocks) * len(topo.active_classes())
 
 
 def dptree_allreduce(x: jax.Array, axis_name: str, p: int, *,

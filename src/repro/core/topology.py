@@ -21,6 +21,8 @@ SPMD implementation needs:
 * 3 static *edge classes*: every edge is active only at global steps with a fixed
   residue ``(phi[child]+2) mod 3``, so the full edge set partitions into three
   static ``ppermute`` permutations — the key to an SPMD realization.
+* ``round_offsets()`` — the concurrent schedule commutative operators run
+  instead, every class at once: a node's rounds to send a block up and down.
 """
 
 from __future__ import annotations
@@ -38,6 +40,7 @@ __all__ = [
     "build_single_tree",
     "build_hierarchy",
     "expand_tree_over_stripes",
+    "mirror_upper_trees",
     "validate_topology",
     "as_levels",
     "resolve_levels",
@@ -116,6 +119,42 @@ class TreeTopology:
     def active_classes(self) -> tuple:
         """Residue classes that actually carry an edge (e.g. p=2 has one)."""
         return tuple(e for e in range(3) if self.up_pairs[e] or self.down_pairs[e])
+
+    @property
+    def exchanges(self) -> bool:
+        """Whether two roots exchange their partials (a dual tree, p >= 2)."""
+        return self.dual and len(self.roots) == 2
+
+    def round_offsets(self) -> tuple:
+        """``(lift, drop)``: per-node round offsets of the concurrent schedule
+        that commutative operators run, where every edge moves one block in
+        each direction in every round.
+
+        Node ``i`` sends its partial of block ``j`` up (a root: to the other
+        root) in round ``lift[i] + j``, and result block ``j`` down to its
+        children in round ``drop[i] + j``. What arrives in a round is folded
+        in during the next, so a block can be sent on two rounds after it
+        arrived: ``lift`` falls by 2 a level below the roots' ``2 * H``
+        (``H`` = ``max_depth``), and ``drop`` rises by 2 a level below the
+        roots', whose results are ready at once (one root) or two rounds
+        later, after the dual exchange and its combine."""
+        h = self.max_depth
+        ready = 2 * h + (2 if self.exchanges else 0)
+        return 2 * (h - self.depth), ready + 2 * self.depth
+
+    def num_rounds(self, num_blocks: int) -> int:
+        """Transfer rounds of the concurrent schedule: through the last
+        block's last arrival, a result block at the deepest node (``b + 4H``
+        for two roots, ``b + 4H - 2`` for one) or, with no node below the
+        roots (``p = 2``), the dual exchange (``b``)."""
+        if self.p == 1:
+            return 0
+        lift, drop = self.round_offsets()
+        root = self.parent == NO_NODE
+        last = [int(np.max(drop[~root])) - 2] if (~root).any() else []
+        if self.exchanges:
+            last.append(int(np.max(lift[root])))
+        return max(last) + num_blocks
 
 
 def _edge_classes(p: int, parent: np.ndarray, phi: np.ndarray,
@@ -296,6 +335,57 @@ def expand_tree_over_stripes(gt: TreeTopology, s: int) -> TreeTopology:
         tree_id=val_map(gt.tree_id),
         up_pairs=expand_pairs(gt.up_pairs),
         down_pairs=expand_pairs(gt.down_pairs))
+
+
+def mirror_upper_trees(topo: TreeTopology) -> TreeTopology:
+    """The same trees with each upper tree placed on its ranks in reverse
+    order, so the two roots of a dual tree are rank neighbours.
+
+    A 1-D mesh axis over a TPU slice puts consecutive ranks on neighbouring
+    chips (``jax.make_mesh`` lays a 2x2 out as a ring, the order the ring
+    methods rely on too). The post-order upper tree has its root at its top
+    rank, ``p - 1``, across the axis from the lower root ``p0 - 1``; placed
+    in reverse, it has it at ``p0``, so the root exchange takes one hop and
+    shares no link with a tree edge. Every other edge keeps its length. A
+    stripe-expanded tree (:func:`expand_tree_over_stripes`) is mirrored
+    within each stripe: a stripe is one connected tree pair. Only for
+    operators that commute: the post-order numbering is what orders a
+    non-commutative reduction. Like a stripe expansion, the result is an
+    engine schedule, not a paper tree (:func:`validate_topology` does not
+    apply)."""
+    if not topo.exchanges:
+        return topo
+    p = topo.p
+    stripe = np.arange(p)
+
+    def find(i):
+        while stripe[i] != i:
+            i = stripe[i]
+        return i
+
+    for pairs in topo.up_pairs:
+        for src, dst in pairs:
+            stripe[find(src)] = find(dst)
+    to = np.arange(p)
+    for s in {find(i) for i in range(p)}:
+        upper = [i for i in range(p) if find(i) == s and topo.tree_id[i] == 1]
+        to[upper] = upper[::-1]
+    at = np.argsort(to)                  # the node that lands on each rank
+
+    def nodes(arr):
+        moved = arr[at]
+        return np.where(moved == NO_NODE, NO_NODE,
+                        to[np.maximum(moved, 0)]).astype(np.int32)
+
+    pairs = lambda classes: tuple(
+        tuple((int(to[a]), int(to[c])) for a, c in cls) for cls in classes)
+    return TreeTopology(
+        p=p, dual=topo.dual, parent=nodes(topo.parent),
+        child0=nodes(topo.child0), child1=nodes(topo.child1),
+        depth=topo.depth[at], phi=topo.phi[at],
+        roots=tuple(int(to[r]) for r in topo.roots),
+        tree_id=topo.tree_id[at], up_pairs=pairs(topo.up_pairs),
+        down_pairs=pairs(topo.down_pairs))
 
 
 def default_group_size(p: int) -> int:

@@ -49,6 +49,9 @@ class ProbeSample:
     ``axis`` the mesh axis name when known. ``predicted_s`` is the
     alpha-beta model's time for the same ``(p, nbytes, blocks)`` under
     ``model`` (None when the method has no closed form, e.g. psum).
+    ``rounds`` is the number of transfer rounds the tree engine's schedule
+    runs for a trace-time ``dptree``/``sptree`` sample (None otherwise):
+    it says which schedule ran.
     """
 
     p: int
@@ -61,6 +64,7 @@ class ProbeSample:
     kind: str = "timed"
     levels: tuple | None = None
     axis: str | None = None
+    rounds: int | None = None
 
     def to_dict(self) -> dict:
         d = dataclasses.asdict(self)
@@ -103,7 +107,8 @@ class CollectiveProbe:
 
     def note(self, method: str, p: int, nbytes: int, num_blocks: int, *,
              dtype: str = "float32", kind: str = "trace",
-             wall_s: float = 0.0, levels=None, axis=None) -> ProbeSample:
+             wall_s: float = 0.0, levels=None, axis=None,
+             rounds: int | None = None) -> ProbeSample:
         """Build + record one sample, filling ``predicted_s`` from the
         probe's cost model. Returns the recorded sample."""
         s = ProbeSample(
@@ -116,7 +121,7 @@ class CollectiveProbe:
             kind=kind,
             levels=tuple(levels) if levels is not None
             and not isinstance(levels, int) else levels,
-            axis=axis)
+            axis=axis, rounds=rounds)
         self.record(s)
         return s
 

@@ -230,9 +230,9 @@ def test_bucketed_and_structured_api():
 
 
 def test_fused_max_allreduce_with_infinities():
-    """The fused engine's deferred-combine identity must be a true infinity:
-    max-allreduce over payloads containing -inf (masked logits) has to return
-    -inf, not finfo.min."""
+    """The identity the concurrent rounds fold in where a node received
+    nothing must be a true infinity: max-allreduce over payloads containing
+    -inf (masked logits) has to return -inf, not finfo.min."""
     run_sub("""
         from repro.core.dptree import dptree_allreduce
         rng = np.random.default_rng(5)
@@ -377,6 +377,28 @@ def test_compress_inter_group_bound_and_exact_off():
         off_i = run(lambda x: hier_allreduce(x, "data", p, group_size=(2, 2),
                                              num_blocks=4), data=Xi)
         assert (on_i == off_i).all() and (on_i[0] == Xi.sum(0)).all()
+
+        # the inter-group tree over 4 groups runs the concurrent rounds
+        # under a plain sum, and the seed-ordered one-ported steps under
+        # the bf16 wire's combine, which is no operator the rounds know
+        from repro.core.topology import build_hierarchy
+        def scan_lengths(jaxpr, out):
+            out += [eqn.params["length"] for eqn in jaxpr.eqns
+                    if eqn.primitive.name == "scan"]
+            for sub in jax.core.subjaxprs(jaxpr):
+                scan_lengths(sub, out)
+            return out
+        inter = build_hierarchy(p, (2,)).inter_topo
+        for compress, want in ((False, inter.num_rounds(4)),
+                               (True, inter.num_macro_rounds(4))):
+            f = shard_map(lambda x: hier_allreduce(
+                x[0], "data", p, group_size=(2,), num_blocks=4,
+                compress_inter_group=compress)[None], mesh=mesh,
+                in_specs=P("data", None), out_specs=P("data", None))
+            got = scan_lengths(jax.make_jaxpr(f)(X).jaxpr, [])
+            trees = [n for n in got if n > 1]     # ring stages: 1 step
+            assert trees == [want], (compress, got, want)
+        assert inter.num_rounds(4) != inter.num_macro_rounds(4)
         print("ok")
     """)
 
@@ -420,10 +442,69 @@ def test_ring_odd_chunk_and_odd_p():
         """, devices=d)
 
 
+@pytest.mark.parametrize("p", [2, 3, 4, 5, 7, 8, 16])
+def test_concurrent_rounds_match_plain_reduction(p):
+    """The concurrent rounds (every edge class's permutes in one round, what
+    arrived folded in during the next) against a plain reduction, over dual
+    and single trees of every shape up to 16 ranks: add, max, min and mul;
+    float32 and bfloat16; one block, one block per element, and more
+    blocks asked for than there are elements; 1-D payloads with blocks
+    shorter than a tile and block-major ones, and 2-D row-pipelined ones.
+    The inputs keep every order of reduction exact (small integers; signed
+    powers of two for mul), so each result must EQUAL the plain one."""
+    run_sub("""
+        from repro.core.dptree import dptree_allreduce, sptree_allreduce
+        rng = np.random.default_rng(p)
+        ops = {"add": (jnp.add, lambda X: X.sum(0)),
+               "max": (jnp.maximum, lambda X: X.max(0)),
+               "min": (jnp.minimum, lambda X: X.min(0)),
+               "mul": (jnp.multiply, lambda X: X.prod(0))}
+
+        def check(fn, X, b, op, ref, what):
+            spec = P("data", *([None] * (X.ndim - 1)))
+            sm = shard_map(lambda x: fn(x[0], "data", p, num_blocks=b,
+                                        op=op)[None],
+                           mesh=mesh, in_specs=spec, out_specs=spec)
+            out = np.asarray(jax.jit(sm)(jnp.asarray(X)))
+            want = ref(X.astype(np.float64))
+            for r in range(p):
+                np.testing.assert_array_equal(out[r].astype(np.float64),
+                                              want, err_msg=what)
+
+        def draw(name, shape, dt):
+            if name == "mul":
+                return rng.choice([-2., -1., 0.5, 1., 2.],
+                                  (p,) + shape).astype(dt)
+            return rng.integers(-8, 9, (p,) + shape).astype(dt)
+
+        k = 0
+        for fn in (dptree_allreduce, sptree_allreduce):
+            for name, (op, ref) in ops.items():
+                for dt in (np.float32, jnp.bfloat16):
+                    # one block, one block per element, more than that
+                    m = 29
+                    b = (1, m, 3 * m)[k % 3]
+                    k += 1
+                    check(fn, draw(name, (m,), dt), b, op, ref,
+                          f"{fn.__name__} {name} {dt} m={m} b={b}")
+                    if name in ("add", "max"):
+                        # block-major: blocks of 2061, over a bf16 tile
+                        check(fn, draw(name, (3 * 2048 + 37,), dt), 3, op,
+                              ref, f"{fn.__name__} {name} {dt} block-major")
+                        check(fn, draw(name, (19, 8), dt), 4, op, ref,
+                              f"{fn.__name__} {name} {dt} 2-D")
+        print("ok")
+    """, devices=p)
+
+
 def test_fused_engine_hlo_slice_count():
-    """The fused engine's scan body holds 3 dynamic slices per edge-class step
-    (the seed's step had 5: the jC slice was materialized twice and every
-    masked write paid a read-modify-write slice)."""
+    """A concurrent round holds 4 dynamic slices and 2 in-place writes,
+    whatever the number of edge classes: the two blocks it sends (up and
+    down, each fed to every class's permutation) and the two it folds into
+    (the children's partials; the other root's partial or the parent's
+    result), each write landing in the scratch row when idle. The
+    one-ported engine it replaces traced 3 slices and 1 write per edge
+    class per macro-round, 9 and 3 here."""
     run_sub("""
         from repro.core.dptree import dptree_allreduce
         X = jnp.ones((p, 999), jnp.float32)
@@ -434,11 +515,10 @@ def test_fused_engine_hlo_slice_count():
         txt = jax.jit(sm).lower(X).as_text()
         n_slice = txt.count("stablehlo.dynamic_slice")
         n_upd = txt.count("stablehlo.dynamic_update_slice")
-        # fused: 3 classes x 3 takes in the scan body + 6 one-time topology
-        # constant lookups = 15. The seed engine traced 3 x 5 takes (jC
-        # twice + a read-modify-write slice per masked update) + 6 = 21.
-        assert 0 < n_slice <= 15, (n_slice, n_upd)
-        assert n_upd <= 3, n_upd
+        # 4 slices in the round + 2 in the fold after the last round + the
+        # one lookup of the per-node constants + the scan's round index = 8;
+        # 2 writes in the round + 2 after it = 4
+        assert (n_slice, n_upd) == (8, 4), (n_slice, n_upd)
         print("ok", n_slice, n_upd)
     """)
 

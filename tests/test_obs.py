@@ -292,6 +292,48 @@ def test_probe_note_fills_prediction():
     assert s.to_dict()["method"] == "sptree"
 
 
+@pytest.mark.parametrize("p", [2, 4, 5, 8])
+def test_probe_sample_counts_the_tree_rounds(p):
+    """A trace-time dptree/sptree sample carries the transfer rounds its
+    schedule runs. Under a commutative operator those are the concurrent
+    rounds: one a block plus the trees' skew, ``b + 4H`` with two roots
+    (``H`` the deepest node's depth; ``b`` alone at p = 2, no node below the
+    roots) and ``b + 4H - 2`` with one. That is fewer than the one-ported
+    schedule's step per active edge class per macro-round wherever more
+    than one class is active; a merely associative operator still runs
+    those."""
+    import jax
+    import jax.numpy as jnp
+    from jax.sharding import AbstractMesh, PartitionSpec as P
+    from repro.core import topology as tp
+    from repro.core.collectives import CollectiveConfig, all_reduce
+    from repro.core.dptree import tree_rounds
+    mesh = AbstractMesh((p,), ("data",))
+    m = 600
+    for method, build, extra in (("dptree", tp.build_dual_tree, 0),
+                                 ("sptree", tp.build_single_tree, -2)):
+        topo = build(p)
+        H = topo.max_depth
+        for b in (1, 7, 231, m + 5):
+            cfg = CollectiveConfig(method=method, num_blocks=b)
+            with probing() as pr:
+                f = jax.shard_map(lambda x: all_reduce(x, "data", p, cfg),
+                                  mesh=mesh, in_specs=P("data"),
+                                  out_specs=P("data"))
+                jax.make_jaxpr(f)(jax.ShapeDtypeStruct((p * m,),
+                                                       jnp.float32))
+            (s,) = pr.traced()
+            nb = min(b, m)
+            want = nb if H == 0 else nb + 4 * H + extra
+            assert s.rounds == want, (method, p, b, s.rounds, want)
+            seed = topo.num_macro_rounds(nb) * len(topo.active_classes())
+            assert tree_rounds(topo, nb, lambda a, c: a + c) == seed
+            if len(topo.active_classes()) > 1:
+                assert s.rounds < seed, (method, p, b, s.rounds, seed)
+    # the probe's samples of other methods carry no rounds
+    assert pr.note("ring", p, 64, 1).rounds is None
+
+
 # ==========================================================================
 # fit: alpha-beta recovery, hier per-level recovery, diagnostics
 # ==========================================================================

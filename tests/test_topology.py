@@ -167,3 +167,39 @@ def test_p1_p2_degenerate():
     assert t2.active_classes() == tuple(
         e for e in range(3) if t2.up_pairs[e])
     assert sum(len(c) for c in t2.up_pairs) == 2  # the dual exchange only
+
+
+@settings(max_examples=40, deadline=None)
+@given(g=st.integers(min_value=2, max_value=40),
+       s=st.sampled_from([1, 2, 4]))
+def test_mirrored_upper_trees_put_the_roots_side_by_side(g, s):
+    """The concurrent rounds' placement: within each stripe the upper tree
+    lies on its ranks in reverse, so the two roots are neighbours among the
+    stripe's ranks (``s`` apart), no stripe meets another, every other edge
+    keeps its length, and each class is still a matching."""
+    from repro.core.topology import build_hierarchy, mirror_upper_trees
+    if s == 1:
+        topo = build_dual_tree(g)
+    else:
+        topo = build_hierarchy(g * s, s).inter_topo
+    mir = mirror_upper_trees(topo)
+    p0 = (g + 1) // 2
+    roots = np.flatnonzero(mir.parent == NO_NODE)
+    assert len(roots) == 2 * s
+    for j in range(s):
+        assert {int(r) for r in roots if r % s == j} == \
+            {(p0 - 1) * s + j, p0 * s + j}
+    lengths = lambda t: sorted(abs(a - c) for cls in t.up_pairs + t.down_pairs
+                               for a, c in cls if t.parent[a] == c
+                               or t.parent[c] == a)
+    assert lengths(mir) == lengths(topo)
+    for pairs in mir.up_pairs + mir.down_pairs:
+        assert all(a % s == c % s for a, c in pairs)
+        assert len({a for a, _ in pairs}) == len({c for _, c in pairs}) \
+            == len(pairs)
+    for i in range(g * s):
+        if mir.parent[i] != NO_NODE:
+            assert mir.depth[mir.parent[i]] == mir.depth[i] - 1
+            assert mir.tree_id[mir.parent[i]] == mir.tree_id[i]
+    assert sorted(mir.depth) == sorted(topo.depth)
+    assert mir.num_rounds(7) == topo.num_rounds(7)

@@ -157,6 +157,54 @@ def test_dptree_blocks_are_block_major_whole_tiles(topo, monkeypatch,
     assert temp < 1.2 * m * 4, (temp, m * 4)
 
 
+def test_grad_sync_rounds_issue_every_class_at_once(topo):
+    """The gradient reduction of the grad_sync cell, compiled as
+    ``chipbench/aot.py`` builds it: in each bucket's loop, the schedule
+    issues all three permutations of a round (leaf-root up and down, root
+    to root) before it waits on any, and the round's in-place writes of
+    what arrived in the round before sit between the starts and the
+    waits, under the wire. Temporaries stay those of the one-ported
+    engine, 2.269 GB (the carried blocks are a few 5 MB blocks)."""
+    import sys
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    if root not in sys.path:
+        sys.path.insert(0, root)
+    from chipbench import harness
+    from chipbench.drivers.allreduce import grad_spec, reducer
+    from repro.configs.base import get_parallel
+    from repro.launch.mesh import make_mesh
+    bench = harness.Benchmark()
+    cell = bench.cell("minicpm_2b_dp4.grad_sync")
+    config, wl = bench.config(cell), bench.workload(cell)
+    p = wl["devices"]
+    mesh = make_mesh((p,), ("data",), devices=topo.devices[:p])
+    rows = NamedSharding(mesh, P("data"))
+    spec, _ = grad_spec(config)
+    x = {k: jax.ShapeDtypeStruct((p,) + s, np.dtype(d), sharding=rows)
+         for k, (s, d) in spec.items()}
+    compiled = reducer(mesh, p, get_parallel(config["repro_arch"]).collective
+                       ).lower(x).compile()
+    text = compiled.as_text()
+    assert "is_scheduled=true" in text.split("\n", 1)[0]
+    comps = _computations(text)
+    loops = re.findall(r" while\(.*?body=%([\w.\-]+)", text)
+    assert len(loops) == 3, loops              # one a bucket
+    for body in loops:
+        lines = comps[body]
+        at = lambda key: [n for n, line in enumerate(lines) if key in line]
+        starts = at("collective-permute-start(")
+        waits = at("collective-permute-done(")
+        writes = [n for n, line in enumerate(lines)
+                  if re.match(r"%\S*dynamic-update-slice\S* = .* fusion\(",
+                              line)]
+        assert len(starts) == len(waits) == 3, (body, starts, waits)
+        assert max(starts) < min(waits), (body, starts, waits)
+        assert len(writes) == 2 and all(
+            max(starts) < n < min(waits) for n in writes), (body, writes)
+    temp = compiled.memory_analysis().temp_size_in_bytes
+    assert abs(temp - 2.269e9) < 0.1e9, temp
+
+
 def test_slot_decode_step_updates_the_cache_in_place(topo):
     """The serving decode step, its caches donated, writes each row's new
     token into the stacked K/V ring in place: its temporaries hold no
